@@ -15,53 +15,30 @@ honest single-chip comparison is chip vs chip. MFU is measured FLOP/s
 (XLA cost analysis of the compiled train step) over the chip's peak
 bf16 FLOP/s.
 
-Resilience: the default invocation is an ORCHESTRATOR that runs the
-measurement in a fresh subprocess (BENCH_INNER=1), retrying with backoff
-when the TPU backend is unavailable (the sandbox's known stuck-chip-claim
-failure mode — BENCH_r01 died on first touch with rc=1). If every TPU
-attempt fails it falls back — in order of usefulness — to (a) the most
-recent committed REAL-TPU artifact for this metric in bench_results/
-(reprinted with "stale": true + capture timestamp), then (b) a small
-honest CPU run (platform=cpu + error note), so the driver ALWAYS gets a
-parseable line. The whole orchestration is budgeted to finish inside
-~16 minutes by default: round 3's lesson (BENCH_r03 rc=124) is that a
-budget sized for "eventually get a TPU number" (70 min) can outlive the
-DRIVER's own timeout during a backend outage, recording a hang instead
-of a number. The budget must lose to the driver's clock, never the
-other way around.
+The measurement runs in the invoking process, once. No accelerator is
+an error (exit 1), never a smaller CPU number under the same metric
+name: ``BENCH_PLATFORM=cpu`` is how a CPU run is asked for, and its line
+says ``"platform": "cpu"`` and carries no MFU.
 
-Env knobs: BENCH_BATCH (default 256 — measured-best MXU utilization on
-the v5e-class chip; the reference harness defaults to 32, which here
-leaves ~15% throughput on the table), BENCH_ITERS, BENCH_WARMUP,
-BENCH_PLATFORM=cpu to force the host platform, BENCH_ATTEMPTS,
-BENCH_ATTEMPT_TIMEOUT (s, per attempt — capped by the budget),
-BENCH_TOTAL_BUDGET (s, whole-orchestration cap, default 900: attempts
-start only while a window plus fallback headroom fits), BENCH_STALE=0
-to disable the stale-artifact fallback, BENCH_PEAK_TFLOPS to override
-the MFU denominator.
+Env knobs: BENCH_MODEL, BENCH_BATCH (default 256 — measured-best MXU
+utilization on the v5e-class chip; the reference harness defaults to 32,
+which here leaves ~15% throughput on the table), BENCH_ITERS,
+BENCH_WARMUP, BENCH_STEM, BENCH_VIT_FLASHPAD, BENCH_PLATFORM.
 """
 
 import json
 import os
-import subprocess
-import sys
 import time
 
+from _benchlib import aot_compile as _aot_compile
+from _benchlib import mfu_fields as _mfu_fields
+from _benchlib import require_accelerator as _require_accelerator
 from _benchlib import stamp as _stamp
 
 P100_FP32_IMG_PER_SEC = 219.0
 
-from _benchlib import aot_compile as _aot_compile  # noqa: E402
-from _benchlib import mfu_fields as _mfu_fields  # noqa: E402
-
 
 def inner_main():
-    if os.environ.get("BENCH_FAIL_INNER"):
-        # Test hook: simulate a backend-unavailable attempt instantly so
-        # the orchestrator's fallback ladder is testable without a real
-        # 20-minute chip-claim failure.
-        print("simulated backend failure (BENCH_FAIL_INNER)", file=sys.stderr)
-        raise SystemExit(3)
     model_name = os.environ.get("BENCH_MODEL", "resnet50")
     batch = int(os.environ.get("BENCH_BATCH", "256"))
     n_iters = int(os.environ.get("BENCH_ITERS", "20"))
@@ -69,8 +46,7 @@ def inner_main():
 
     import jax
 
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
+    device = _require_accelerator()
 
     import jax.numpy as jnp
     import numpy as np
@@ -111,7 +87,7 @@ def inner_main():
     else:
         raise SystemExit(f"unknown BENCH_MODEL {model_name!r}")
 
-    platform = jax.devices()[0].platform
+    platform = device.platform
     rng = jax.random.PRNGKey(0)
     images = jnp.asarray(
         np.random.default_rng(0).uniform(
@@ -168,7 +144,6 @@ def inner_main():
             params, batch_stats, opt_state, images, labels
         )
     if loss is not None:
-        # host transfer: the only trustworthy sync (see _benchlib)
         _sync(loss)
 
     t0 = time.perf_counter()
@@ -188,65 +163,27 @@ def inner_main():
         "unit": "img/s",
         "vs_baseline": round(img_per_sec / P100_FP32_IMG_PER_SEC, 3),
         "platform": platform,
+        "device_kind": device.device_kind,
         "batch": batch,
-        # capture-time stamp: the stale-artifact fallback trusts this
-        # over file mtime (which a fresh checkout rewrites)
         "captured_at": datetime.datetime.now(datetime.timezone.utc).strftime(
             "%Y-%m-%dT%H:%M:%SZ"
         ),
     }
     if model_name.startswith("resnet"):
-        # config provenance: the stale-artifact fallback must not
-        # substitute a stem-variant probe for the default config
         result["stem"] = stem
     if model_name == "vit_b16":
         # flash-pad engages on TPU under the auto default (r04: the
         # padded kernels made ViT's 197 tokens tileable via 200+lengths)
         result["attn"] = _vit_attn_mode(platform)
     result.update(
-        _mfu_fields(flops, n_iters, dt, platform, step_bytes=step_bytes)
+        _mfu_fields(flops, n_iters, dt, step_bytes=step_bytes)
     )
     print(json.dumps(_stamp(result)))
 
 
-def _spawn(env, timeout):
-    try:
-        return subprocess.run(
-            [sys.executable, os.path.abspath(__file__)],
-            env=env,
-            cwd=os.path.dirname(os.path.abspath(__file__)),
-            stdout=subprocess.PIPE,
-            stderr=subprocess.PIPE,
-            text=True,
-            timeout=timeout,
-        )
-    except subprocess.TimeoutExpired as e:
-        def _txt(v):
-            return v.decode(errors="replace") if isinstance(v, bytes) else (
-                v or "")
-
-        return subprocess.CompletedProcess(
-            e.cmd, 124, _txt(e.stdout),
-            _txt(e.stderr) + f"\n[timeout after {timeout}s]",
-        )
-
-
-def _extract_json(stdout):
-    for line in reversed(stdout.strip().splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            try:
-                return json.loads(line)
-            except json.JSONDecodeError:
-                continue
-    return None
-
-
 def _vit_attn_mode(platform: str) -> str:
     """ViT attention-engine provenance (the artifact's "attn" field):
-    ONE predicate shared by inner_main's stamp and the stale-gate's
-    expectation so the two can't drift (ADVICE r4). flash_pad engages
-    only on TPU under the auto default."""
+    flash_pad engages only on TPU under the auto default."""
     if os.environ.get("BENCH_VIT_FLASHPAD", "auto") in (
         "0", "false", "off"
     ):
@@ -254,256 +191,5 @@ def _vit_attn_mode(platform: str) -> str:
     return "flash_pad" if platform == "tpu" else "dense"
 
 
-def _stale_artifact(metric, config=None):
-    """Most recent committed REAL-TPU measurement for `metric` (and
-    matching `config` fields) under bench_results/. Returns
-    (parsed_dict, path, when) or None.
-
-    This is the outage insurance VERDICT r3 asked for: when the backend
-    is down for the driver's end-of-round capture but a same-metric TPU
-    artifact was captured earlier (the nohup capture loops run all
-    round), the round's official line is that number marked stale —
-    not a timeout, and not a CPU number pretending nothing happened.
-
-    `config` maps field name -> (required value, default when the
-    artifact omits the field): exploratory probes (space_to_depth stem,
-    nonstandard batch) share the metric name, and an outage reprint
-    must never silently substitute one configuration for another.
-    """
-    import glob
-
-    results_dir = os.environ.get("BENCH_RESULTS_DIR") or os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "bench_results"
-    )
-    best = None
-    for path in glob.glob(os.path.join(results_dir, "*.json")):
-        if os.path.basename(path).startswith("sim_"):
-            continue  # CPU-simulation artifacts are logic-validation only
-        try:
-            with open(path) as f:
-                lines = f.read().splitlines()
-        except OSError:
-            continue
-        for line in lines:
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                d = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if (
-                d.get("metric") == metric
-                and d.get("platform") == "tpu"
-                and d.get("value")
-                and not d.get("stale")  # never re-launder a reprint
-                and all(
-                    d.get(k, dflt) == want
-                    for k, (want, dflt) in (config or {}).items()
-                )
-            ):
-                import datetime
-
-                # Prefer the measurement's own capture timestamp
-                # (inner_main stamps one); file mtime is checkout time
-                # after a fresh clone, not capture time — so ANY
-                # embedded stamp outranks ANY mtime-derived one.
-                stamped = "captured_at" in d
-                when = d.get("captured_at") or datetime.datetime.fromtimestamp(
-                    os.path.getmtime(path), datetime.timezone.utc
-                ).strftime("%Y-%m-%dT%H:%M:%SZ")
-                rank = (stamped, when)
-                if best is None or rank > best[3]:
-                    best = (d, path, when, rank)
-    return best[:3] if best else None
-
-
-def orchestrate():
-    attempts = int(os.environ.get("BENCH_ATTEMPTS", "4"))
-    # Per-attempt patience. A legitimate run needs ~2-3 min (compile +
-    # measure); a claim against a DOWN backend takes ~20-25 min to
-    # report UNAVAILABLE. We no longer wait that out here: the
-    # kill-wedges-the-queue theory was tested and DISPROVEN
-    # (2026-07-30, docs/perf.md), so truncating a doomed claim only
-    # costs this client its queue slot — which is exactly right when
-    # the alternative is the driver timing US out (BENCH_r03 rc=124).
-    timeout = float(os.environ.get("BENCH_ATTEMPT_TIMEOUT", "600"))
-    forced = os.environ.get("BENCH_PLATFORM")
-    metric = os.environ.get("BENCH_MODEL", "resnet50") + "_synth_img_per_sec"
-
-    base_env = dict(os.environ)
-    base_env["BENCH_INNER"] = "1"
-
-    if forced:
-        attempts = 1  # platform is explicit; no TPU-retry dance
-
-    # Total-time budget (BENCH_TOTAL_BUDGET, s): the WHOLE orchestration
-    # — attempts, fallbacks, everything — must finish comfortably inside
-    # the driver's own timeout. Round 3 proved the failure mode: a
-    # 4200s budget optimized for "eventually get a TPU number" outlived
-    # the driver's patience during a backend outage and the official
-    # artifact recorded rc=124/parsed=null. Rules:
-    # * default 900s; `timeout 1200 python bench.py` must ALWAYS print
-    #   a parseable line (that invocation is the acceptance test);
-    # * further attempts start only when a full window plus fallback
-    #   headroom still fits; the check runs BEFORE the backoff sleep;
-    # * attempt 0 always runs (floored at 120s), so tiny budgets still
-    #   get one real try;
-    # * fallback headroom is small when a stale TPU artifact can be
-    #   reprinted (instant) and ~330s when the CPU run is the only
-    #   fallback left;
-    # * CAVEAT: the floors mean a budget below ~450s can be EXCEEDED by
-    #   up to ~420s (120s attempt floor + 300s CPU-fallback floor) —
-    #   size any outer watchdog to BENCH_TOTAL_BUDGET + 450s. At the
-    #   900s default the whole ladder fits `timeout 1200`.
-    total_budget = float(os.environ.get("BENCH_TOTAL_BUDGET", "900"))
-    stale_ok = os.environ.get("BENCH_STALE", "1") not in ("0", "false")
-    # Config provenance: an outage reprint must match the live run's
-    # configuration, not just its metric name (the chipwork probes
-    # write stem/batch variants under the same metric).
-    stale_config = {
-        "batch": (int(os.environ.get("BENCH_BATCH", "256")), 256),
-    }
-    if os.environ.get("BENCH_MODEL", "resnet50").startswith("resnet"):
-        # Only resnets have a stem variant, and inner_main only stamps
-        # "stem" on resnet artifacts — gating every model on it would
-        # reject valid ViT/Inception/VGG artifacts (which omit the key)
-        # against the conv7 omission-default. Artifacts predating the
-        # stem field were conv7 captures.
-        stale_config["stem"] = (
-            os.environ.get("BENCH_STEM", "space_to_depth"),
-            "conv7",
-        )
-    if os.environ.get("BENCH_MODEL") == "vit_b16":
-        # same provenance rule for ViT's attention engine (artifacts
-        # predating the attn field were dense captures)
-        # stale candidates are platform-filtered to "tpu" inside
-        # _stale_artifact, so the expectation evaluates the shared
-        # predicate at platform="tpu"
-        stale_config["attn"] = (_vit_attn_mode("tpu"), "dense")
-
-    def _find_stale():
-        if not stale_ok or forced:
-            return None
-        return _stale_artifact(metric, config=stale_config)
-
-    # Probe once up front only to size the fallback headroom; re-resolve
-    # at the fallback point — the nohup capture loops run all round and
-    # may land a FRESHER artifact while our attempts sit in the claim
-    # queue.
-    stale = _find_stale()
-    cpu_headroom = 60.0 if stale else 330.0
-    t_start = time.monotonic()
-
-    def _remaining() -> float:
-        return total_budget - (time.monotonic() - t_start)
-
-    last_err = ""
-    for i in range(attempts):
-        delay = 120.0 * i  # backoff for THIS attempt (0 for the first)
-        # Gate on the TRUNCATED window the attempt would actually get:
-        # a retry is worth starting whenever a floored 120s window (the
-        # "legitimate run needs ~2 min" bound) still fits after the
-        # backoff — gating on the full untruncated timeout would make
-        # the ladder unreachable at the default 900/600 settings.
-        if not forced and i > 0 and (
-            _remaining() - cpu_headroom - delay < 120.0
-        ):
-            print(
-                f"bench: {total_budget - _remaining():.0f}s spent of "
-                f"{total_budget:.0f}s budget; no attempt window fits — "
-                "moving to the fallback ladder",
-                file=sys.stderr,
-            )
-            break
-        if i > 0:
-            print(
-                f"bench: attempt {i} failed, retrying in {delay:.0f}s "
-                f"(TPU backend may be recovering a stale chip claim)",
-                file=sys.stderr,
-            )
-            time.sleep(delay)
-        attempt_timeout = timeout
-        if not forced:
-            attempt_timeout = min(
-                timeout, max(_remaining() - cpu_headroom, 120.0)
-            )
-        proc = _spawn(base_env, attempt_timeout)
-        parsed = _extract_json(proc.stdout or "")
-        if proc.returncode == 0 and parsed is not None:
-            print(json.dumps(parsed))
-            return 0
-        last_err = (proc.stderr or "")[-1500:] or (proc.stdout or "")[-1500:]
-
-    stale = _find_stale()
-    if stale is not None:
-        parsed, path, when = stale
-        parsed = dict(parsed)
-        parsed["stale"] = True
-        parsed["captured_at"] = when
-        parsed["source"] = os.path.relpath(
-            path, os.path.dirname(os.path.abspath(__file__))
-        )
-        parsed["error"] = (
-            "tpu backend unavailable for the live capture; reprinting "
-            "the most recent committed real-TPU artifact. last error: "
-            + last_err[-300:]
-        )
-        print(json.dumps(parsed))
-        return 0
-
-    cpu_err = ""
-    if not forced:
-        # All TPU attempts failed: fall back to a small honest CPU run
-        # so the round still records a parseable measurement. Skipped
-        # when the caller forced a platform — overriding an explicit
-        # choice would mask a hard requirement.
-        from _hermetic import hermetic_cpu_env
-
-        cpu_env = hermetic_cpu_env(base=base_env)
-        cpu_env["BENCH_PLATFORM"] = "cpu"
-        cpu_env["BENCH_BATCH"] = os.environ.get("BENCH_CPU_BATCH", "32")
-        cpu_env["BENCH_ITERS"] = os.environ.get("BENCH_CPU_ITERS", "3")
-        cpu_env["BENCH_WARMUP"] = "1"
-        # cap by what's left of the budget, but always leave enough to
-        # actually emit a line (~5 min compile+run at the small batch);
-        # the 300s floor must hold even when BENCH_ATTEMPT_TIMEOUT is
-        # tuned below it — the attempt timeout governs TPU claims, not
-        # this last honest rung
-        proc = _spawn(cpu_env, max(min(timeout, _remaining()), 300.0))
-        parsed = _extract_json(proc.stdout or "")
-        if proc.returncode == 0 and parsed is not None:
-            parsed["error"] = (
-                "tpu backend unavailable after "
-                f"{attempts} attempts; CPU fallback. last error: "
-                + last_err[-400:]
-            )
-            print(json.dumps(parsed))
-            return 0
-        cpu_err = (proc.stderr or "")[-400:]
-
-    # Emit a diagnostic line the driver can still parse.
-    print(
-        json.dumps(
-            {
-                "metric": metric,
-                "value": 0.0,
-                "unit": "img/s",
-                "vs_baseline": 0.0,
-                "error": (
-                    f"all attempts failed (platform="
-                    f"{forced or 'tpu'}). last error: " + last_err[-400:]
-                    + (" | cpu fallback error: " + cpu_err
-                       if cpu_err else "")
-                ),
-            }
-        )
-    )
-    return 1
-
-
 if __name__ == "__main__":
-    if os.environ.get("BENCH_INNER") == "1":
-        inner_main()
-    else:
-        sys.exit(orchestrate())
+    inner_main()

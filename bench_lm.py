@@ -305,8 +305,9 @@ def main():
         return run_ab_local_sgd()
     import jax
 
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
+    from _benchlib import require_accelerator
+
+    device = require_accelerator()
 
     import jax.numpy as jnp
     import optax
@@ -371,10 +372,13 @@ def main():
     params = jax.jit(
         lambda: model.init(jax.random.PRNGKey(0), tokens, train=False)
     )()
+    # mesh-placed before the first call, so step 2 runs the program
+    # step 1 compiled (chip_smoke.py checks exactly this)
+    params = hvd.broadcast_parameters(params)
     opt = hvd.DistributedOptimizer(
         optax.sgd(0.01, momentum=0.9), op=reduce_op
     )
-    opt_state = opt.init(params)
+    opt_state = hvd.broadcast_optimizer_state(opt.init(params))
 
     # Chunked fused linear-cross-entropy (ops/fused_xent.py): never
     # materializes the (batch·seq, vocab) logits — the step's largest
@@ -480,11 +484,18 @@ def main():
     step = jax.jit(train_step)
     rng = np.random.default_rng(0)
     world = hvd.size()
-    toks = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, size=(world, batch, seq)), jnp.int32
+    rank_major = hvd.rank_sharding(mesh)
+    toks = jax.device_put(
+        rng.integers(0, cfg.vocab_size, size=(world, batch, seq)).astype(
+            np.int32
+        ),
+        rank_major,
     )
-    labels = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, size=(world, batch, seq)), jnp.int32
+    labels = jax.device_put(
+        rng.integers(0, cfg.vocab_size, size=(world, batch, seq)).astype(
+            np.int32
+        ),
+        rank_major,
     )
 
     from _benchlib import aot_compile, bytes_accessed, mfu_fields
@@ -507,7 +518,7 @@ def main():
     from _benchlib import sync as _sync
 
     params, opt_state, loss = step(params, opt_state, toks, labels)
-    _sync(loss)  # warm; host transfer is the only trustworthy sync
+    _sync(loss)  # warm
     t0 = time.perf_counter()
     for _ in range(iters):
         params, opt_state, loss = step(params, opt_state, toks, labels)
@@ -536,10 +547,10 @@ def main():
         "flash_block": (
             _effective_block(seq, cfg) if cfg.uses_flash(seq=seq) else None
         ),
-        "platform": jax.devices()[0].platform,
+        "platform": device.platform,
+        "device_kind": device.device_kind,
     }
-    result.update(mfu_fields(flops, iters, dt, jax.devices()[0].platform,
-                             step_bytes=step_bytes))
+    result.update(mfu_fields(flops, iters, dt, step_bytes=step_bytes))
     if flops_note:
         result["flops_note"] = flops_note
     print(json.dumps(_stamp(result)))

@@ -97,7 +97,7 @@ def main():
     import horovod_tpu as hvd
     from _benchlib import sync as _sync
     from horovod_tpu.common.autotune import shared_capacity_tuner
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
     from horovod_tpu.common.metrics import publish_moe
     from horovod_tpu.common.topology import hierarchical_stage_groups
     from horovod_tpu.parallel.moe import (

@@ -14,6 +14,10 @@ import subprocess
 import tempfile
 from typing import List, Optional
 
+from ..common.logging import get_logger
+
+_log = get_logger("native")
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _CSRC = os.path.normpath(os.path.join(_HERE, "..", "..", "csrc"))
 _LIB = os.path.join(_HERE, "libhvd_native.so")
@@ -68,10 +72,22 @@ def _build(sources: List[str], out: str, extra: List[str]) -> Optional[str]:
         )
         os.replace(tmp, out)
         return out
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
         if os.path.exists(tmp):
             os.unlink(tmp)
-        return out if os.path.exists(out) else None
+        stale = os.path.exists(out)
+        # a missing compiler (FileNotFoundError) or a failed compile is
+        # said out loud: the callers carry on with Python fallbacks, and
+        # nobody should have to guess which half they are running
+        stderr = getattr(e, "stderr", None) or b""
+        _log.warning(
+            "building %s with %r failed: %s %s — %s",
+            os.path.basename(out), cmd[0], e,
+            stderr.decode(errors="replace")[-800:],
+            "loading the stale copy" if stale
+            else "the pure-Python fallbacks serve",
+        )
+        return out if stale else None
 
 
 def lib_path() -> Optional[str]:

@@ -36,7 +36,13 @@ def _load_once(name: str, load) -> Optional[Any]:
             return _cache[name]
         try:
             _cache[name] = load()
-        except (ImportError, OSError):
+        except (ImportError, OSError) as e:
+            from ..common.logging import get_logger
+
+            get_logger("native").warning(
+                "native %s failed to load (%s); the pure-Python "
+                "fallbacks serve", name, e,
+            )
             _cache[name] = None
         return _cache[name]
 

@@ -120,6 +120,9 @@ def init(process_sets: Optional[Sequence[ProcessSet]] = None) -> None:
         from .metrics import registry as _metrics
 
         _metrics.configure_export()  # HOROVOD_METRICS_FILE, if set
+        from . import compile_cache
+
+        compile_cache.ensure()  # before anything below can compile
         _maybe_init_jax_distributed(cfg)
         topology = topo_mod.discover(cfg)
         if cfg.rendezvous_addr:

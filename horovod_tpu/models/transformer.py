@@ -20,7 +20,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..common.logging import get_logger
+from ..common.metrics import registry as _metrics
 from ..ops.flash_attention import DEFAULT_BLOCK as _DEFAULT_FLASH_BLOCK
+
+_log = get_logger("models.transformer")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,39 +96,58 @@ class TransformerConfig:
     # FLOPs at GPT-2 scale).
     head_mixed_precision: bool = True
 
+    def wants_flash(self) -> bool:
+        """The configuration half of the flash gate: ``True``/``False``
+        as given, ``"auto"`` = on a TPU backend (off it the interpret-
+        mode kernel would only be overhead)."""
+        if self.flash_attention == "auto":
+            return jax.default_backend() == "tpu"
+        return bool(self.flash_attention)
+
+    def flash_decline_reason(self, mask=None, seq=None) -> Optional[str]:
+        """The shape half: why this call cannot ride the Pallas flash
+        kernels, or None. Pass ``seq`` when known."""
+        if mask is not None:
+            return (
+                "an arbitrary padding mask was passed (the kernels mask "
+                "causal and lengths= only; pass lengths for right-padded "
+                "batches)"
+            )
+        if seq is None:
+            return None
+        from ..ops.flash_attention import (
+            bwd_vmem_bytes,
+            fits_vmem,
+            supports_seq,
+        )
+
+        if not supports_seq(seq, self.flash_block_q, self.flash_block_k):
+            # untileable lengths (e.g. ViT's 197 tokens) would fail
+            # Mosaic's block constraints
+            return f"seq {seq} tiles no 8-aligned block"
+        head_dim = self.d_model // self.num_heads
+        group = self.num_heads // (self.num_kv_heads or self.num_heads)
+        itemsize = jnp.dtype(self.dtype).itemsize
+        if not fits_vmem(seq, head_dim, group, itemsize, self.flash_block_k):
+            # the backward dK/dV kernel stages the whole q-head group
+            # whole-sequence
+            est = bwd_vmem_bytes(
+                seq, head_dim, group, itemsize, self.flash_block_k
+            )
+            return (
+                f"the dK/dV backward would stage ~{est / 2**20:.0f} MiB "
+                f"(seq {seq}, head_dim {head_dim}, {group} q heads per kv "
+                "head), over the VMEM budget"
+            )
+        return None
+
     def uses_flash(self, mask=None, seq=None) -> bool:
         """THE gating rule for the Pallas flash path — single source
-        of truth for the model and for bench_lm's FLOPs correction.
-        Pass ``seq`` when known: untileable lengths (e.g. ViT's 197
-        tokens — no power-of-two block divides them) take the dense
-        path rather than failing Mosaic's block constraints."""
-        if mask is not None:
-            return False
-        if seq is not None:
-            from ..ops.flash_attention import fits_vmem, supports_seq
-
-            if not supports_seq(
-                seq, self.flash_block_q, self.flash_block_k
-            ):
-                return False
-            # The backward dK/dV kernel stages the whole q-head group
-            # whole-sequence; past the VMEM budget the dense path is
-            # the one that compiles (ADVICE r4).
-            import numpy as _np
-
-            if not fits_vmem(
-                seq,
-                self.d_model // self.num_heads,
-                self.num_heads // (self.num_kv_heads or self.num_heads),
-                _np.dtype(self.dtype).itemsize,
-                self.flash_block_k,
-            ):
-                return False
-        if self.flash_attention == "auto":
-            import jax as _jax
-
-            return _jax.default_backend() == "tpu"
-        return bool(self.flash_attention)
+        of truth for the model and for bench_lm's FLOPs correction."""
+        return (
+            self.wants_flash()
+            and self.flash_decline_reason(mask, seq) is None
+        )
 
     @staticmethod
     def gpt2_medium() -> "TransformerConfig":
@@ -284,22 +307,21 @@ class MultiHeadAttention(nn.Module):
                                           paged_attn=paged_attn)
         # lengths (right-padding) stays on the flash path — the kernels
         # take it natively; only ARBITRARY masks force dense.
-        use_flash = cfg.uses_flash(mask, seq=x.shape[1])
-        if cfg.flash_attention and cfg.flash_attention != "auto" and (
-            mask is not None
-        ):
-            # Explicit True + arbitrary mask: the flash kernel
-            # implements only causal + right-padding masking, so this
-            # degrades to the dense path. Loud, not silent.
-            import warnings
-
-            warnings.warn(
-                "flash_attention=True but a padding mask was passed; "
-                "falling back to dense attention (the flash path "
-                "supports causal and lengths= masking only — pass "
-                "lengths for right-padded batches)",
-                stacklevel=2,
+        wanted = cfg.wants_flash()
+        declined = (
+            cfg.flash_decline_reason(mask, seq=x.shape[1]) if wanted else None
+        )
+        use_flash = wanted and declined is None
+        if wanted and not use_flash:
+            # the shape dispatch stays, but never silently: a model
+            # that was meant to run the kernels and runs dense attention
+            # says so and is counted (like serve.paged_attn_fallbacks)
+            _log.warning(
+                "flash attention is on (flash_attention=%r) but %s; "
+                "this call runs dense attention",
+                cfg.flash_attention, declined,
             )
+            _metrics.counter("flash.dense_fallbacks")
         if use_flash:
             from ..ops.flash_attention import flash_attention
 

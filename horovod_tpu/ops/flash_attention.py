@@ -37,14 +37,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _lens_spec():
-    """BlockSpec for the per-program (bh, 1) valid-length scalars.
-    They live in SMEM: a (1, 1) VMEM tile would violate Mosaic's
-    sublane rule (module header), and the value drives loop bounds —
-    scalar memory is where the official TPU flash kernels keep
-    sequence lengths."""
-    return pl.BlockSpec(
-        (1, 1), lambda b, i: (b, 0), memory_space=pltpu.SMEM
-    )
+    """Spec for the per-row valid lengths, a ``(rows,)`` int32 array
+    held whole in SMEM; each program reads its own entry at
+    ``program_id(0)``. The value drives loop bounds, and scalar memory
+    is where the official TPU flash kernels keep sequence lengths. (A
+    blocked ``(1, 1)`` SMEM spec is refused by the Pallas TPU lowering:
+    the last two block dims must be 8x128-divisible or the full array
+    dims.)"""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _interpret() -> bool:
@@ -132,7 +132,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                 block_q, block_k, padded=False, window=None):
     if padded:
         len_ref, o_ref, lse_ref = rest
-        kv_len = len_ref[0, 0]
+        kv_len = len_ref[pl.program_id(0)]
     else:
         o_ref, lse_ref = rest
         kv_len = None
@@ -193,7 +193,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
                window=None):
     if padded:
         len_ref, dq_ref = rest
-        kv_len = len_ref[0, 0]
+        kv_len = len_ref[pl.program_id(0)]
     else:
         (dq_ref,) = rest
         kv_len = None
@@ -271,7 +271,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     small)."""
     if padded:
         len_ref, dk_ref, dv_ref = rest
-        kv_len = len_ref[0, 0]
+        kv_len = len_ref[pl.program_id(0)]
     else:
         dk_ref, dv_ref = rest
         kv_len = None
@@ -395,7 +395,13 @@ def supports_seq(
     return ok(_pick_block(t, block_q)) and ok(_pick_block(t, block_k))
 
 
-_VMEM_BUDGET_DEFAULT = 12 * 2**20  # headroom under a v5e core's ~16 MiB
+# A guess made before the chip could be asked, and conservative: on the
+# v5e (scripts/chip_roster.py --vmem-sweep, d=128 bf16, PR 21) the dK/dV
+# kernel compiled and ran at every shape up to an ESTIMATE of 48.8 MiB
+# (r=8 t=8192, r=4 t=16384) and ran out of VMEM only at 97 MiB (r=8
+# t=16384). The gate stays here until a sweep at head_dim 64 too (lane
+# padding doubles the real footprint there) replaces it — ROADMAP A6.
+_VMEM_BUDGET_DEFAULT = 12 * 2**20
 
 
 def _vmem_budget() -> int:
@@ -419,7 +425,8 @@ def bwd_vmem_bytes(
     blocks for q/do/o plus an (r, seq, lanes) fp32 lse), so the
     footprint grows r-fold on top of the whole-sequence staging the
     module header documents (ADVICE r4). e.g. r=8, seq=4096, d=128,
-    bf16: ~25 MiB — past a v5e core's ~16 MiB."""
+    bf16: ~25 MiB. An estimate of what is staged, not of what Mosaic
+    allocates: see the budget's note for where the chip really stops."""
     lanes = _interchange_lanes()
     bk = _pick_block(seq, block_k if block_k else DEFAULT_BLOCK)
     stage = h_per_kv * seq * (3 * d * itemsize + 4 * lanes)  # q/do/o+lse
@@ -436,7 +443,7 @@ def fits_vmem(
 ) -> bool:
     """Whether the backward kernels' per-program staging fits the
     per-core VMEM budget (HOROVOD_FLASH_VMEM_BUDGET bytes, default
-    12 MiB of a v5e core's ~16). TransformerConfig.uses_flash and the
+    12 MiB). TransformerConfig.uses_flash and the
     ulysses/ring auto-gates fall back to the dense engines when this
     fails; direct ``flash_attention``/``ring_flash_attention`` callers
     get a warning rather than an error (forward-only use stages ~3x
@@ -476,7 +483,7 @@ def _flash_bhtd(q, k, v, causal, block_q, block_k, window):
     jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7)
 )
 def _flash_bhtd_padded(q, k, v, lens, causal, block_q, block_k, window):
-    """Padded variant: ``lens`` is a (bh, 1) int32 of valid key/query
+    """Padded variant: ``lens`` is a (bh,) int32 of valid key/query
     lengths. Separate custom_vjp so the unpadded path's compiled
     artifacts are untouched."""
     o, _ = _flash_fwd(
@@ -525,6 +532,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
             jax.ShapeDtypeStruct((bh, seq, lanes), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*operands)
     return o, lse
 
@@ -629,6 +637,7 @@ def _flash_bwd_impl(
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_interpret(),
+        name="flash_dq",
     )(*dq_operands)
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -647,6 +656,7 @@ def _flash_bwd_impl(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=_interpret(),
+        name="flash_dkv",
     )(*dkv_operands)
     return dq, dk, dv
 
@@ -809,7 +819,7 @@ def flash_attention(
         raise ValueError(
             f"lengths must be [batch]=({b},), got {lens.shape}"
         )
-    lens_bh = jnp.repeat(lens, h)[:, None]  # (bh, 1)
+    lens_bh = jnp.repeat(lens, h)  # (bh,)
     if h_per_kv == 1:
         out = _flash_bhtd_padded(
             to_bhtd(q), to_bhtd(k), to_bhtd(v), lens_bh,

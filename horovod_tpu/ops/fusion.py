@@ -96,7 +96,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..common.compat import shard_map
+from jax import shard_map
 from ..common.topology import WORLD_AXIS
 from ..common.process_sets import ProcessSet
 from ..common.logging import get_logger

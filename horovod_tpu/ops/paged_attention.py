@@ -47,8 +47,8 @@ Layout/contract (the `ops/flash_attention.py` mold):
 Interpret mode runs the same kernel on CPU (tests + the dryrun bench
 leg exercise the real code path). Callers gate through
 :func:`unsupported_reason` — the backward-compatible fallback ladder
-(non-dividing head dims, oversized pages vs the VMEM budget, missing
-Pallas lowering) falls back LOUDLY to the gather path and is counted
+(non-dividing head dims, oversized pages vs the VMEM budget) falls
+back LOUDLY to the gather path and is counted
 (``serve.paged_attn_fallbacks``).
 """
 
@@ -61,15 +61,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # the "missing Pallas support" rung of the fallback ladder
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS = True
-except ImportError:  # pragma: no cover - baked-in jax ships pallas
-    pl = None
-    pltpu = None
-    _PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _NEG_INF, _STATS_LANES, _interpret, _vmem_budget
 
@@ -110,8 +103,6 @@ def unsupported_reason(
     """The fallback ladder, one rung per return: None means the kernel
     path is usable for this geometry; a string names the rung (callers
     log it loudly and count ``serve.paged_attn_fallbacks``)."""
-    if not _PALLAS:
-        return "Pallas is unavailable in this jax build"
     backend = backend or jax.default_backend()
     if backend == "tpu":
         # Mosaic layout floors apply only on real hardware — interpret
@@ -156,7 +147,7 @@ def _kernel(
 ):
     b = pl.program_id(0)
     j = pl.program_id(2)
-    rows = t * r
+    rows = q_ref.shape[2]  # t * r, padded up to whole sublanes
     start = lens_ref[b]
     kv_len = start + t
     n_live = (kv_len + page_tokens - 1) // page_tokens
@@ -169,10 +160,9 @@ def _kernel(
 
     @pl.when(j < n_live)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)  # [t, r, d]
-        q = q.reshape(rows, q.shape[-1])
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page_tokens, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # [rows, d]
+        k = k_ref[0].astype(jnp.float32)  # [page_tokens, d]
+        v = v_ref[0].astype(jnp.float32)
         # same op order as the dense oracle: fp32 score matmul, THEN
         # the / sqrt(head_dim) — scaling q first would round differently
         s = jax.lax.dot_general(
@@ -205,8 +195,7 @@ def _kernel(
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         l_safe = jnp.maximum(l_ref[:, :1], 1e-30)
-        out = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        o_ref[0] = out.reshape(t, r, out.shape[-1])
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -242,11 +231,6 @@ def paged_attention(
 
     Returns ``[batch, t, num_heads, head_dim]`` in q's dtype.
     """
-    if not _PALLAS:
-        raise RuntimeError(
-            "paged_attention requires Pallas; gate calls through "
-            "unsupported_reason()"
-        )
     b, t, h, d = q.shape
     num_pages, page_tokens, kvh, dk = k_pool.shape
     if v_pool.shape != k_pool.shape:
@@ -267,7 +251,21 @@ def paged_attention(
         raise ValueError(
             f"page_table rows ({page_table.shape[0]}) != batch ({b})"
         )
-    rows = t * r
+    # Mosaic wants the last two dims of every block 8x128-divisible or
+    # equal to the array's, and a block may not pick one head out of
+    # the second-minor dim. So the kernel sees 2-D tiles: q/out ride
+    # ``[b, kv_heads, t*r, d]`` (a transpose of the small operand; rows
+    # are position-major, so row i is query position i // r, padded up
+    # to whole sublanes) and the pools are viewed ``[pages, page_tokens,
+    # kv_heads*d]`` (free: heads are contiguous), where a ``(1,
+    # page_tokens, d)`` block at lane offset ``kv`` IS one head's page.
+    rows = -(-t * r // _SUBLANES) * _SUBLANES
+    q_rows = q.reshape(b, t, kvh, r, d).transpose(0, 2, 1, 3, 4)
+    q_rows = q_rows.reshape(b, kvh, t * r, d)
+    if rows != t * r:
+        q_rows = jnp.pad(q_rows, ((0, 0), (0, 0), (0, rows - t * r), (0, 0)))
+    k_flat = k_pool.reshape(num_pages, page_tokens, kvh * d)
+    v_flat = v_pool.reshape(num_pages, page_tokens, kvh * d)
     last_page = num_pages - 1
 
     def _page(bi, kv, j, tbl, lens):
@@ -276,38 +274,40 @@ def paged_attention(
         # grid steps cost no HBM bytes (pl.when masks their compute)
         n_live = (lens[bi] + t + page_tokens - 1) // page_tokens
         jj = jnp.minimum(j, n_live - 1)
-        return (jnp.minimum(tbl[bi, jj], last_page), 0, kv, 0)
+        return (jnp.minimum(tbl[bi, jj], last_page), 0, kv)
+
+    def _rows(bi, kv, j, tbl, lens):
+        return (bi, kv, 0, 0)
 
     kernel = functools.partial(
         _kernel,
-        t=t,
         r=r,
         page_tokens=page_tokens,
         causal=causal,
         sqrt_d=float(math.sqrt(d)),
+        t=t,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kvh, n_logical),
         in_specs=[
-            pl.BlockSpec(
-                (1, t, r, d), lambda bi, kv, j, tbl, lens: (bi, 0, kv, 0)
-            ),
-            pl.BlockSpec((1, page_tokens, 1, d), _page),
-            pl.BlockSpec((1, page_tokens, 1, d), _page),
+            pl.BlockSpec((1, 1, rows, d), _rows),
+            pl.BlockSpec((1, page_tokens, d), _page),
+            pl.BlockSpec((1, page_tokens, d), _page),
         ],
-        out_specs=pl.BlockSpec(
-            (1, t, r, d), lambda bi, kv, j, tbl, lens: (bi, 0, kv, 0)
-        ),
+        out_specs=pl.BlockSpec((1, 1, rows, d), _rows),
         scratch_shapes=[
             pltpu.VMEM((rows, _STATS_LANES), jnp.float32),
             pltpu.VMEM((rows, _STATS_LANES), jnp.float32),
             pltpu.VMEM((rows, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
         interpret=_interpret(),
-    )(page_table, lengths, q, k_pool, v_pool)
+        name="paged_attention",
+    )(page_table, lengths, q_rows, k_flat, v_flat)
+    out = out[:, :, : t * r].reshape(b, kvh, t, r, d)
+    return out.transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)

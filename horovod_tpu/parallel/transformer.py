@@ -55,7 +55,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..common.compat import shard_map
+from jax import shard_map
 from .moe import MoEParams, init_moe_params, moe_ffn
 from .pipeline import gpipe, pipeline_1f1b
 from .ring_attention import ring_attention, ring_flash_attention

@@ -1160,10 +1160,11 @@ def serve(
     ``preemption.register_drain`` makes YOUR shutdown drain the serving
     plane first, before telemetry/checkpoint.
     """
-    from ..common import basics
+    from ..common import basics, compile_cache
     from .. import preemption
     from .engine import InferenceEngine
 
+    compile_cache.ensure()  # serve() does not need hvd.init()
     cfg = basics.live_config()
     # Label this worker's spans with its serving role so the trace
     # assembler gets one row per (host, role) without guessing.

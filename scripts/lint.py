@@ -61,6 +61,9 @@ ENV_READ_ALLOWED = {
     "horovod_tpu/elastic/driver.py",
     "horovod_tpu/runner/tpu_discovery.py",
     "horovod_tpu/runner/launch.py",
+    # JAX_COMPILATION_CACHE_DIR is JAX's own variable: read to decide
+    # whether to leave the cache path alone, never as a knob of ours
+    "horovod_tpu/common/compile_cache.py",
     # HOROVOD_STANDBY_HOSTNAME / _FINGERPRINT / CHECKPOINT_DIR are
     # identity stamped by the driver's warmer launch, same contract
     "horovod_tpu/elastic/standby.py",

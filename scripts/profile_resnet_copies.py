@@ -6,8 +6,7 @@ case with this data).
 Uses the traced timeline (jax.profiler -> merged chrome JSON) and sums
 device-lane complete events by bucket: copy, transpose, fusion,
 convolution, other. Prints per-bucket ms plus the N largest individual
-copy/transpose ops with their durations, then one JSON line for the
-chipwork harness.
+copy/transpose ops with their durations, then one JSON line.
 
 Env: BENCH_BATCH (256), BENCH_STEM (space_to_depth), BENCH_STEPS (3).
 """

@@ -30,7 +30,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 import horovod_tpu as hvd_mod  # noqa: E402
 from horovod_tpu import analysis  # noqa: E402
 from horovod_tpu.analysis import rules, sched_audit  # noqa: E402
-from horovod_tpu.common.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 
 # A hand-built module: two independent world all_reduces, one scalar

@@ -142,7 +142,7 @@ def test_sync_batch_norm_global_stats(hvd, rng):
     statistics: replicas with different data agree on mean/var (ref:
     test_torch.py's sync-BN equivalence-to-global-batch pattern [V])."""
     import horovod_tpu as hvd_pkg
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = hvd.mesh()
     bn = hvd_pkg.SyncBatchNorm(axis_name=hvd.WORLD_AXIS)
@@ -159,7 +159,7 @@ def test_sync_batch_norm_global_stats(hvd, rng):
         mesh=mesh,
         in_specs=(P(), P(hvd.WORLD_AXIS)),
         out_specs=P(hvd.WORLD_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     def apply(vars_, x):
         y, _ = bn.apply(

@@ -100,6 +100,42 @@ def test_untileable_seq_falls_back_to_dense():
     assert not cfg.uses_flash(seq=197)
 
 
+def test_dense_fallback_is_loud_and_counted(caplog):
+    """The shape dispatch stays, but a model that wants the kernels and
+    runs dense attention says so and counts it (the serving engine's
+    paged_attn_fallbacks discipline)."""
+    import dataclasses
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.common.metrics import registry
+    from horovod_tpu.models.transformer import Transformer, TransformerConfig
+
+    cfg = dataclasses.replace(
+        TransformerConfig.tiny(), num_layers=1, flash_attention=True
+    )
+    assert cfg.wants_flash() and "13" in cfg.flash_decline_reason(seq=13)
+    assert cfg.flash_decline_reason(seq=16) is None
+    before = registry.snapshot().get("flash.dense_fallbacks", 0)
+    logger = logging.getLogger("horovod_tpu.models.transformer")
+    logger.addHandler(caplog.handler)
+    try:
+        jax.eval_shape(
+            lambda: Transformer(cfg).init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 13), jnp.int32),
+                train=False,
+            )
+        )
+    finally:
+        logger.removeHandler(caplog.handler)
+    assert registry.snapshot()["flash.dense_fallbacks"] == before + 1
+    assert "runs dense attention" in caplog.text
+    # "auto" off the TPU never wanted the kernels: quiet
+    assert not TransformerConfig.tiny().wants_flash()
+
+
 def test_vmem_footprint_gate():
     """The dK/dV backward kernel stages the whole q-head group
     whole-sequence, so big seq*(h/kv_h) products must gate the model

@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 import horovod_tpu as hvd_mod  # noqa: E402
 from horovod_tpu import analysis  # noqa: E402
 from horovod_tpu.common import guard as guard_mod  # noqa: E402
-from horovod_tpu.common.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from horovod_tpu.common.metrics import registry  # noqa: E402
 
 
@@ -1024,7 +1024,7 @@ os.environ["XLA_FLAGS"] = (
 import numpy as np, jax, jax.numpy as jnp, optax
 from jax.sharding import PartitionSpec as P
 import horovod_tpu as hvd
-from horovod_tpu.common.compat import shard_map
+from jax import shard_map
 from horovod_tpu.checkpoint import DurableJaxState
 from horovod_tpu.data import ShardedFileDataset
 from horovod_tpu.testing import chaos

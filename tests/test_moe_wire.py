@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu import analysis
-from horovod_tpu.common.compat import shard_map
+from jax import shard_map
 from horovod_tpu.common import topology as topo_mod
 from horovod_tpu.ops import traced
 from horovod_tpu.parallel.moe import MoEParams, init_moe_params, moe_ffn

@@ -310,29 +310,6 @@ def test_zero_retrace_kernel_on_admissions_and_exhaustion(toy):
 # --------------------------------------------------------- fallback ladder
 
 
-def test_fallback_missing_pallas_counted(toy, monkeypatch):
-    """Rung 1: no Pallas lowering — the engine serves on the gather
-    read, warns, and counts the fallback; output stays exact."""
-    from horovod_tpu.ops import paged_attention as pa
-
-    model, params = toy
-    monkeypatch.setattr(pa, "_PALLAS", False)
-    reason = pa.unsupported_reason(128, 8)
-    assert reason and "Pallas" in reason
-    with pytest.raises(RuntimeError, match="Pallas"):
-        pa.paged_attention(
-            jnp.zeros((1, 1, 2, 8)), jnp.zeros((4, 8, 2, 8)),
-            jnp.zeros((4, 8, 2, 8)), jnp.zeros((1, 4), jnp.int32),
-            jnp.zeros((1,), jnp.int32),
-        )
-    eng = _engine(toy, paged_attn="on")
-    assert eng.paged_attn is False
-    assert eng.stats()["paged_attn_fallbacks"] == 1
-    out = _generate(eng, eng.manager.alloc(), [3, 5, 7], 5)
-    assert out == _greedy_ref(model, params, [3, 5, 7], 5)
-    assert eng.stats()["paged_attn_calls"] == 0
-
-
 def test_fallback_alignment_rungs_are_tpu_only():
     """Rungs 2–3: Mosaic tile floors (128-lane head_dim, 8-sublane
     page_tokens) gate only on real TPU backends — interpret mode (CPU

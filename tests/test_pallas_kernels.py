@@ -97,7 +97,7 @@ def test_quantized_allreduce_on_mesh(hvd, rng):
     error, across an 8-device mesh."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from horovod_tpu.ops import traced
@@ -112,7 +112,7 @@ def test_quantized_allreduce_on_mesh(hvd, rng):
         mesh=mesh,
         in_specs=P(hvd.WORLD_AXIS),
         out_specs=P(hvd.WORLD_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     def qmean(x):
         return traced.quantized_allreduce(x[0], op=hvd.Average)[None]
@@ -135,7 +135,7 @@ def test_distributed_optimizer_int8_compression(hvd, rng):
     from functools import partial
 
     import optax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     opt = hvd.DistributedOptimizer(
@@ -152,7 +152,7 @@ def test_distributed_optimizer_int8_compression(hvd, rng):
         mesh=mesh,
         in_specs=(P(hvd.WORLD_AXIS), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def step(g, p):
         state = opt.init(p)

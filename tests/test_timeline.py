@@ -98,8 +98,7 @@ def test_eager_timeline_device_completion_span(hvd, tmp_path):
     """The fused flush stamps a device-completion span per entry: a
     complete 'X' event named <PHASE>_DEVICE whose duration is the
     dispatch→block_until_ready delta (SURVEY §7 checklist row, eager
-    half — see docs/design.md for the semantics and the remote-tunnel
-    caveat)."""
+    half — see docs/design.md for the semantics)."""
     path = str(tmp_path / "tl.json")
     hvd_mod.start_timeline(path)
     x = np.stack([np.full((4,), float(r), np.float32) for r in range(8)])
