@@ -16,6 +16,7 @@ single controller.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Optional, Sequence
 
@@ -106,9 +107,14 @@ def init(process_sets: Optional[Sequence[ProcessSet]] = None) -> None:
     (operations.cc [V]). Unlike the reference there is no thread to spawn:
     collective scheduling is XLA's job.
     """
-    with _state.lock:
+    from . import tracing
+
+    with _state.lock, contextlib.ExitStack() as stack:
         if _state.initialized:
             return
+        # everything below is the span ``hvd.init``; the first process
+        # span mints the root that the later ones hang from
+        stack.enter_context(tracing.span("hvd.init"))
         cfg = config_mod.Config.from_env()
         # Logging first so every subsystem below starts up observable
         # (ref: logging.cc — level/timestamp read once at init [V]).

@@ -31,14 +31,38 @@ Knobs (typed in common/config.py, read via ``basics.live_config()``):
 ``HOROVOD_TRACE`` (master switch), ``HOROVOD_TRACE_SAMPLE`` (fraction
 of minted roots that are sampled; descendants inherit the decision),
 ``HOROVOD_TRACE_SPANS`` (ring bound).
+
+**Process spans** (:func:`span`, :func:`hot_span`, :func:`trace_time_span`)
+need no request: their parent is the thread's active span or the
+process root context that ``hvd.init`` mints once. They land in the
+same ring in the same record shape. Names are ``hvd.<layer>.<what>``
+(``hvd.init.*``, ``hvd.trainer.*``, ``hvd.exchange.*``,
+``hvd.batcher.*``, ``hvd.engine.*``; docs/observability.md has the
+table). ``span`` is for work that happens a bounded number of times a
+process (init, placement, trace time) and always records;
+``hot_span`` is for work per scheduler round or per idle stretch and
+records only under ``HOROVOD_TRACE``, at ``HOROVOD_TRACE_SAMPLE``.
+
+**The profiler's clock.** Every span that is *entered* (``with span``)
+while a ``jax.profiler`` session runs is also written into that session
+as ``TraceAnnotation("<name>#<seq>")``, so it sits on the device
+trace's clock beside the device's operations; ``seq`` is in the ring
+record too, which joins a trace event to its tags. Outside a session
+the check is one atomic load, and JAX is never imported from here: the
+annotation exists only when ``"jax" in sys.modules``. A span that is
+begun on one thread and ended by hand on another (``serve.decode``)
+cannot be a scoped annotation and stays in the ring alone.
 """
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import json
 import os
 import secrets
 import socket
+import sys
 import threading
 import time
 from collections import deque
@@ -131,6 +155,25 @@ def _new_span_id() -> str:
 # ------------------------------------------------------------------ spans
 
 _tls = threading.local()
+_seq = itertools.count()  # next() is atomic under the GIL
+
+
+def _profiler_annotation(name: str, seq: int):
+    """An entered ``jax.profiler.TraceAnnotation("<name>#<seq>")`` while
+    a profiler session runs, else None. JAX is never imported from
+    here: a process that has not imported it has no session."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        cls = jax.profiler.TraceAnnotation
+        if not cls.is_enabled():
+            return None
+        annotation = cls(f"{name}#{seq}")
+        annotation.__enter__()
+        return annotation
+    except Exception:  # noqa: BLE001 - a span must never fail its caller
+        return None
 
 
 class Span:
@@ -144,7 +187,8 @@ class Span:
     """
 
     __slots__ = (
-        "name", "ctx", "parent_id", "tags", "ts", "_t0", "_done",
+        "name", "ctx", "parent_id", "tags", "ts", "seq", "_t0", "_done",
+        "_annotation",
     )
 
     def __init__(
@@ -158,6 +202,10 @@ class Span:
         self.ctx = ctx  # ctx.span_id is THIS span's id
         self.parent_id = parent_id
         self.tags = dict(tags) if tags else {}
+        # process-wide serial: the name of the span's profiler
+        # annotation is "<name>#<seq>", so a trace event finds its tags
+        self.seq = next(_seq)
+        self._annotation = None
         self.ts = time.time()
         self._t0 = time.monotonic()
         self._done = False
@@ -185,6 +233,7 @@ class Span:
                 "span_id": self.ctx.span_id,
                 "parent_id": self.parent_id,
                 "name": self.name,
+                "seq": self.seq,
                 "ts": self.ts,
                 "dur_ms": round(dur_ms, 3),
                 "tags": self.tags,
@@ -195,9 +244,13 @@ class Span:
 
     def __enter__(self) -> "Span":
         push_active(self)
+        self._annotation = _profiler_annotation(self.name, self.seq)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         pop_active(self)
         if exc_type is not None and "outcome" not in self.tags:
             self.tags["outcome"] = "error"
@@ -356,16 +409,27 @@ def set_role(role: str) -> None:
 
 
 def _reset() -> None:
-    """Test hook: drop the recorder + settings cache so the next call
-    re-reads config."""
-    global _recorder, _settings
+    """Test hook: drop the recorder, the settings cache and the process
+    root so the next call re-reads config."""
+    global _recorder, _settings, _process_root
     with _rec_lock:
         _recorder = None
         _settings = None
+        _process_root = None
 
 
 def enabled() -> bool:
     return _load_settings()[0]
+
+
+def _coin(sample: float) -> bool:
+    """One sampling decision at ``HOROVOD_TRACE_SAMPLE``."""
+    if sample >= 1.0:
+        return True
+    if sample <= 0.0:
+        return False
+    # secrets over random: no seed-correlation with user code
+    return secrets.randbelow(1_000_000) < sample * 1_000_000
 
 
 def mint(sampled: Optional[bool] = None) -> Optional[TraceContext]:
@@ -376,13 +440,7 @@ def mint(sampled: Optional[bool] = None) -> Optional[TraceContext]:
     if not on:
         return None
     if sampled is None:
-        if sample >= 1.0:
-            sampled = True
-        elif sample <= 0.0:
-            sampled = False
-        else:
-            # secrets over random: no seed-correlation with user code
-            sampled = secrets.randbelow(1_000_000) < sample * 1_000_000
+        sampled = _coin(sample)
     if not sampled:
         return None
     return TraceContext(_new_trace_id(), _new_span_id(), True)
@@ -412,6 +470,60 @@ def start_span(
         return None
     child = TraceContext(parent.trace_id, _new_span_id(), True)
     return Span(name, child, parent.span_id, tags)
+
+
+# --------------------------------------------------------- process spans
+
+_process_root: Optional[TraceContext] = None
+_NO_SPAN = contextlib.nullcontext()
+
+
+def process_root() -> TraceContext:
+    """The context that process spans hang from when no span is active
+    on their thread: minted once (``hvd.init`` does it; a span opened
+    before init mints it then) and kept for the life of the process."""
+    global _process_root
+    root = _process_root
+    if root is None:
+        with _rec_lock:
+            if _process_root is None:
+                _process_root = TraceContext(
+                    _new_trace_id(), _new_span_id(), True
+                )
+            root = _process_root
+    return root
+
+
+def span(name: str, **tags) -> Span:
+    """A process span, ``with tracing.span("hvd.init.optimizer_init"): ...``:
+    child of this thread's active span, else of the process root.
+    Always recorded, so only for work that happens a bounded number of
+    times a process; :func:`hot_span` is for the rest."""
+    active_span = current()
+    parent = active_span.ctx if active_span is not None else process_root()
+    child = TraceContext(parent.trace_id, _new_span_id(), True)
+    return Span(name, child, parent.span_id, tags)
+
+
+def hot_span(name: str, **tags):
+    """:func:`span` under ``HOROVOD_TRACE`` at ``HOROVOD_TRACE_SAMPLE``
+    (a root's coin, thrown per span: a process span belongs to no
+    request), else a shared null context (``as`` gives None): for spans
+    per scheduler round or per idle stretch."""
+    on, sample = _load_settings()
+    return span(name, **tags) if on and _coin(sample) else _NO_SPAN
+
+
+def trace_time_span(name: str, probe, **tags):
+    """:func:`span` when ``probe`` is a JAX tracer, that is while
+    ``jit``/``grad`` trace the caller, else a shared null context: an
+    eager call pays one ``isinstance``. For code that runs on the host
+    only at trace time (an optimizer's ``update``, a model's
+    ``__call__``), whose Python is part of every cold start."""
+    jax = sys.modules.get("jax")
+    if jax is None or not isinstance(probe, jax.core.Tracer):
+        return _NO_SPAN
+    return span(name, **tags)
 
 
 def root_span(name: str, ctx: Optional[TraceContext], **tags):

@@ -14,12 +14,14 @@ horovod_tpu/parallel/ — this module is the single-chip / pure-DP model.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..common import tracing as _tracing
 from ..common.logging import get_logger
 from ..common.metrics import registry as _metrics
 from ..ops.flash_attention import DEFAULT_BLOCK as _DEFAULT_FLASH_BLOCK
@@ -673,10 +675,27 @@ class LMHead(nn.Module):
         return y + bias
 
 
+def _span_at_trace_time(call):
+    """``hvd.trainer.trace_model`` around a model's ``__call__`` while
+    JAX traces it (``tokens`` is a tracer): how long the model's Python
+    takes under jit/grad is part of every cold start. An eager call
+    pays one ``isinstance``."""
+
+    @functools.wraps(call)
+    def wrapped(self, tokens, *args, **kwargs):
+        with _tracing.trace_time_span(
+            "hvd.trainer.trace_model", tokens, layers=self.cfg.num_layers
+        ):
+            return call(self, tokens, *args, **kwargs)
+
+    return wrapped
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
+    @_span_at_trace_time
     def __call__(
         self, tokens, mask=None, train: bool = True,
         return_hidden: bool = False, lengths=None,

@@ -695,6 +695,7 @@ def _leaf_panes(leaf, n):
     return pad_to(leaf.reshape(-1), n).reshape(n, -1)
 
 
+@jax.named_scope(traced.EXCHANGE_SCOPE)
 def bucketed_reduce_scatter(
     grads,
     op=None,
@@ -786,7 +787,7 @@ def bucketed_reduce_scatter(
         ):
             out[i] = g  # passthrough (float0 cotangents etc.)
         else:
-            red = jax.lax.psum(g, axis_name, axis_index_groups=groups)
+            red = traced.clax.psum(g, axis_name, axis_index_groups=groups)
             out[i] = red / n if op == Average else red
         if r_leaves is not None:
             res_out[i] = r_leaves[i]
@@ -853,7 +854,7 @@ def bucketed_reduce_scatter(
                 red = red / jnp.asarray(n, red.dtype)
         else:
             wire_buf = buf.astype(jnp.bfloat16) if bw == "bf16" else buf
-            red = jax.lax.psum_scatter(
+            red = traced.clax.psum_scatter(
                 wire_buf, axis_name, scatter_dimension=0, tiled=False,
                 axis_index_groups=groups,
             ).astype(buf.dtype)
@@ -888,6 +889,7 @@ def bucketed_reduce_scatter(
     return shards, jax.tree_util.tree_unflatten(treedef, res_out)
 
 
+@jax.named_scope(traced.EXCHANGE_SCOPE)
 def bucketed_shard_all_gather(
     shards,
     like,
@@ -961,7 +963,7 @@ def bucketed_shard_all_gather(
             for j in idxs:
                 i = nonscalar[j]
                 l = l_leaves[i]
-                full = jax.lax.all_gather(
+                full = traced.clax.all_gather(
                     s_leaves[i], axis_name, axis=0,
                     axis_index_groups=groups,
                 ).reshape(-1)
@@ -1029,7 +1031,7 @@ def bucketed_shard_all_gather(
                 )
         else:
             wire_buf = buf.astype(jnp.bfloat16) if bw == "bf16" else buf
-            full = jax.lax.all_gather(
+            full = traced.clax.all_gather(
                 wire_buf, axis_name, axis=0, axis_index_groups=groups,
             ).astype(buf.dtype)  # [n, C]
             if r_leaves is not None:
@@ -1115,7 +1117,8 @@ def overlap_boundary(
         return t, None
 
     def _bwd(_, ct):
-        return (bucketed_allreduce(ct, **kw),)
+        with jax.named_scope(traced.EXCHANGE_SCOPE):
+            return (bucketed_allreduce(ct, **kw),)
 
     _boundary.defvjp(_fwd, _bwd)
     return _boundary(tree)

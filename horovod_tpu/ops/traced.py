@@ -21,6 +21,7 @@ the closest SPMD analog of "non-members don't call the op".
 
 from __future__ import annotations
 
+import types
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -31,6 +32,31 @@ from jax import lax
 from ..common.topology import WORLD_AXIS
 from ..common.process_sets import ProcessSet
 from .reduction_ops import Average, Sum, Adasum, Min, Max, Product, resolve_op
+
+# ------------------------------------------------------------- scopes
+#
+# ``jax.named_scope`` puts a name into the ``op_name`` of every
+# operation traced under it: metadata only, the compiled program is the
+# same with or without it (tests/test_scopes.py compares the optimised
+# HLO). A reader of a device trace sorts the step's time by these names.
+# Whoever starts an exchange (``DistributedOptimizer``'s ``communicate``,
+# the tape API, ``overlap_boundary``, the ZeRO optimizer) opens
+# ``hvd_exchange``, and every collective beneath it carries
+# ``collective``. The optimizers put the inner transform's update (and
+# the guard's skip) under ``hvd_update`` and the
+# ``backward_passes_per_step`` accumulation under ``hvd_accumulate``.
+EXCHANGE_SCOPE = "hvd_exchange"
+UPDATE_SCOPE = "hvd_update"
+ACCUMULATE_SCOPE = "hvd_accumulate"
+
+# ``jax.lax``'s collectives, each under the scope ``collective``: the one
+# way this package puts a collective into a traced program.
+clax = types.SimpleNamespace(**{
+    name: jax.named_scope("collective")(getattr(lax, name))
+    for name in ("psum", "pmin", "pmax", "psum_scatter", "all_gather",
+                 "all_to_all", "ppermute")
+})
+
 
 # The stall inspector used to run only on EAGER fusion cycles, so a
 # purely-traced job (the TPU fast path) could stall silently: leaked
@@ -100,7 +126,7 @@ def _masked_gather(tensor, info: _SetInfo, axis_name, member, pos):
         (info.size * d,) + tuple(tensor.shape[1:]), tensor.dtype
     )
     buf = lax.dynamic_update_slice_in_dim(buf, contrib, pos * d, axis=0)
-    return lax.psum(buf, axis_name)
+    return clax.psum(buf, axis_name)
 
 
 def rank(axis_name: str = WORLD_AXIS):
@@ -176,7 +202,7 @@ def allreduce(
             tensor = tensor * jnp.asarray(
                 prescale_factor, dtype=tensor.dtype
             )
-        out = lax.psum(
+        out = clax.psum(
             tensor, axis_name, axis_index_groups=[list(g) for g in groups]
         )
         if op == Average:
@@ -226,14 +252,14 @@ def allreduce(
             if gate is None
             else jnp.where(gate, tensor, jnp.zeros_like(tensor))
         )
-        out = lax.psum(contrib, axis_name)
+        out = clax.psum(contrib, axis_name)
         if op == Average:
             if live is None:
                 out = out / jnp.asarray(n, dtype=out.dtype)
             else:
                 # live count is traced: the join mask may differ step
                 # to step without forcing a retrace
-                n_live = lax.psum(
+                n_live = clax.psum(
                     jnp.where(gate, 1.0, 0.0).astype(out.dtype), axis_name
                 )
                 out = out / jnp.maximum(
@@ -247,7 +273,7 @@ def allreduce(
                 member, tensor, jnp.full_like(tensor, _identity(tensor, Min))
             )
         )
-        out = lax.pmin(contrib, axis_name)
+        out = clax.pmin(contrib, axis_name)
     elif op == Max:
         contrib = (
             tensor
@@ -256,14 +282,14 @@ def allreduce(
                 member, tensor, jnp.full_like(tensor, _identity(tensor, Max))
             )
         )
-        out = lax.pmax(contrib, axis_name)
+        out = clax.pmax(contrib, axis_name)
     elif op == Product:
         contrib = (
             tensor
             if member is None
             else jnp.where(member, tensor, jnp.ones_like(tensor))
         )
-        gathered = lax.all_gather(contrib, axis_name)
+        gathered = clax.all_gather(contrib, axis_name)
         out = jnp.prod(gathered, axis=0)
     else:
         raise ValueError(f"unsupported reduce op {op}")
@@ -357,7 +383,7 @@ def grouped_allreduce(
             t if member is None else jnp.where(member, t, jnp.zeros_like(t))
             for t in tensors
         )
-        outs = lax.psum(contribs, axis_name)
+        outs = clax.psum(contribs, axis_name)
         if op == Average:
             outs = tuple(o / jnp.asarray(n, o.dtype) for o in outs)
     elif op == Min:
@@ -367,7 +393,7 @@ def grouped_allreduce(
             else jnp.where(member, t, jnp.full_like(t, _identity(t, Min)))
             for t in tensors
         )
-        outs = lax.pmin(contribs, axis_name)
+        outs = clax.pmin(contribs, axis_name)
     elif op == Max:
         contribs = tuple(
             t
@@ -375,7 +401,7 @@ def grouped_allreduce(
             else jnp.where(member, t, jnp.full_like(t, _identity(t, Max)))
             for t in tensors
         )
-        outs = lax.pmax(contribs, axis_name)
+        outs = clax.pmax(contribs, axis_name)
     else:
         raise ValueError(f"unsupported grouped reduce op {op}")
     outs = list(outs)
@@ -401,7 +427,7 @@ def allgather(
     _stall_check()
     info = _set_info(process_set, axis_name)
     if info is None:
-        return lax.all_gather(tensor, axis_name, axis=0, tiled=True)
+        return clax.all_gather(tensor, axis_name, axis=0, tiled=True)
     member, pos = _member(info, axis_name)
     return _masked_gather(tensor, info, axis_name, member, pos)
 
@@ -420,7 +446,7 @@ def broadcast(
     info = _set_info(process_set, axis_name)
     idx = lax.axis_index(axis_name)
     contribution = jnp.where(idx == root_rank, tensor, jnp.zeros_like(tensor))
-    out = lax.psum(contribution, axis_name)
+    out = clax.psum(contribution, axis_name)
     if info is not None:
         member, _ = _member(info, axis_name)
         out = jnp.where(member, out, tensor)
@@ -443,7 +469,7 @@ def alltoall(
     _stall_check()
     info = _set_info(process_set, axis_name)
     if info is None:
-        return lax.all_to_all(
+        return clax.all_to_all(
             tensor, axis_name, split_axis=0, concat_axis=0, tiled=True
         )
     k = info.size
@@ -465,7 +491,7 @@ def alltoall(
         perm = [(info.ranks[q], info.ranks[(q + s) % k]) for q in range(k)]
         send_at = ((pos + s) % k) * d
         send = lax.dynamic_slice_in_dim(tensor, send_at, d, axis=0)
-        recv = lax.ppermute(send, axis_name, perm)
+        recv = clax.ppermute(send, axis_name, perm)
         recv_slot = ((pos - s) % k) * d
         out = lax.dynamic_update_slice_in_dim(out, recv, recv_slot, axis=0)
     return jnp.where(member, out, tensor)
@@ -494,7 +520,7 @@ def reducescatter(
         tensor = tensor * jnp.asarray(prescale_factor, tensor.dtype)
     if info is None:
         n = lax.axis_size(axis_name)
-        out = lax.psum_scatter(
+        out = clax.psum_scatter(
             tensor, axis_name, scatter_dimension=0, tiled=True
         )
     else:
@@ -507,7 +533,7 @@ def reducescatter(
         n = k
         member, pos = _member(info, axis_name)
         contrib = jnp.where(member, tensor, jnp.zeros_like(tensor))
-        total = lax.psum(contrib, axis_name)
+        total = clax.psum(contrib, axis_name)
         d = tensor.shape[0] // k
         out = lax.dynamic_slice_in_dim(total, pos * d, d, axis=0)
     if op == Average:
@@ -678,9 +704,9 @@ def quantized_allreduce(
         wire_scales = scales * prescale if prescale_factor != 1.0 else scales
         # all_to_all = the scatter half of reduce-scatter: afterwards
         # row r holds the chunk rank r quantized for us, with its scales
-        recv = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
+        recv = clax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
                               tiled=True)              # [n, nb, block]
-        recv_scales = lax.all_to_all(
+        recv_scales = clax.all_to_all(
             wire_scales, axis_name, split_axis=0, concat_axis=0,
             tiled=True,
         )                                               # [n, nb]
@@ -690,8 +716,8 @@ def quantized_allreduce(
         q2, s2 = _stochastic_round_blocks(
             shard[None], block_size, jax.random.fold_in(key, 7919)
         )
-        all_q = lax.all_gather(q2[0], axis_name)   # [n, nb, block]
-        all_s = lax.all_gather(s2[0], axis_name)   # [n, nb]
+        all_q = clax.all_gather(q2[0], axis_name)   # [n, nb, block]
+        all_s = clax.all_gather(s2[0], axis_name)   # [n, nb]
         out = _block_dequant(all_q, all_s)[:, :chunk].reshape(-1)[:m]
         dequant_local = _block_dequant(q, scales)[:, :chunk]
         e2 = (shard - _block_dequant(q2, s2)[0])[:chunk]
@@ -702,9 +728,9 @@ def quantized_allreduce(
         )
         # all_to_all = the scatter half of reduce-scatter: afterwards
         # row r holds the chunk rank r quantized for us, with its scale.
-        recv = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
+        recv = clax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
                               tiled=True)
-        recv_scales = lax.all_to_all(
+        recv_scales = clax.all_to_all(
             wire_scales.reshape(n, 1), axis_name, split_axis=0,
             concat_axis=0, tiled=True,
         ).reshape(n)
@@ -716,8 +742,8 @@ def quantized_allreduce(
         # Second stage: per-tensor Pallas quantizer on the reduced
         # shard, decorrelated from stage one and from other ranks.
         q2, s2 = int8_quantize(shard, seed=seed * 2 + 1 + idx * 7919)
-        all_q = lax.all_gather(q2, axis_name)    # [n, chunk] int8
-        all_s = lax.all_gather(s2, axis_name)    # [n] f32
+        all_q = clax.all_gather(q2, axis_name)    # [n, chunk] int8
+        all_s = clax.all_gather(s2, axis_name)    # [n] f32
         out = (all_q.astype(jnp.float32) * all_s[:, None]).reshape(-1)[:m]
         dequant_local = q.astype(jnp.float32) * scales[:, None]
         e2 = shard - q2.astype(jnp.float32) * s2
@@ -800,9 +826,9 @@ def quantized_reducescatter(
     key = jax.random.fold_in(jax.random.PRNGKey(0), seed)
     key = jax.random.fold_in(key, idx)
     q, scales = _stochastic_round_blocks(x, block, key)  # [n, nb, block]
-    recv = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
+    recv = clax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
                           tiled=True, axis_index_groups=groups)
-    recv_s = lax.all_to_all(scales, axis_name, split_axis=0,
+    recv_s = clax.all_to_all(scales, axis_name, split_axis=0,
                             concat_axis=0, tiled=True,
                             axis_index_groups=groups)
     shard = jnp.sum(_block_dequant(recv, recv_s), axis=0)[:cols]
@@ -843,10 +869,10 @@ def quantized_allgather(
     key = jax.random.fold_in(jax.random.PRNGKey(1), seed)
     key = jax.random.fold_in(key, idx)
     q, s = _stochastic_round_blocks(x, block, key)  # [1, nb, block]
-    all_q = lax.all_gather(
+    all_q = clax.all_gather(
         q[0], axis_name, axis_index_groups=groups
     )  # [n, nb, block]
-    all_s = lax.all_gather(
+    all_s = clax.all_gather(
         s[0], axis_name, axis_index_groups=groups
     )  # [n, nb]
     out = _block_dequant(all_q, all_s)[:, :cols]
@@ -902,11 +928,11 @@ def quantized_alltoall(
     key = jax.random.fold_in(key, idx)
     q, scales = _stochastic_round_blocks(x, block, key)
     nb = scales.shape[1]
-    recv = lax.all_to_all(
+    recv = clax.all_to_all(
         q.reshape(n, slots, nb, block), axis_name,
         split_axis=0, concat_axis=0, tiled=True, axis_index_groups=groups,
     )
-    recv_s = lax.all_to_all(
+    recv_s = clax.all_to_all(
         scales.reshape(n, slots, nb), axis_name,
         split_axis=0, concat_axis=0, tiled=True, axis_index_groups=groups,
     )
@@ -975,7 +1001,7 @@ def hierarchical_alltoall(
         ).astype(dtype)
     else:
         wire = "fp32" if exact else inter_wire
-        y = lax.all_to_all(
+        y = clax.all_to_all(
             _stage_cast(xr, wire), axis_name,
             split_axis=0, concat_axis=0, tiled=True,
             axis_index_groups=inter_groups,
@@ -991,7 +1017,7 @@ def hierarchical_alltoall(
     # regroup by destination intra position and deliver inside the slice
     y = y.reshape(H, L, slots, d).transpose(1, 0, 2, 3)  # [L_d, H_s, ...]
     iw = "fp32" if exact else intra_wire
-    z = lax.all_to_all(
+    z = clax.all_to_all(
         _stage_cast(y.reshape(L, H * slots, d), iw), axis_name,
         split_axis=0, concat_axis=0, tiled=True,
         axis_index_groups=intra_groups,
@@ -1058,11 +1084,11 @@ def _quantized_sum_groups(
     flat = jnp.pad(row, (0, chunk * n - m)) if chunk * n != m else row
     chunks = flat.reshape(n, chunk)
     q, scales = _stochastic_round_blocks(chunks, block, key)
-    recv = lax.all_to_all(
+    recv = clax.all_to_all(
         q, axis_name, split_axis=0, concat_axis=0, tiled=True,
         axis_index_groups=groups,
     )
-    recv_s = lax.all_to_all(
+    recv_s = clax.all_to_all(
         scales, axis_name, split_axis=0, concat_axis=0, tiled=True,
         axis_index_groups=groups,
     )
@@ -1070,8 +1096,8 @@ def _quantized_sum_groups(
     q2, s2 = _stochastic_round_blocks(
         shard[None], block, jax.random.fold_in(key, 7919)
     )
-    all_q = lax.all_gather(q2[0], axis_name, axis_index_groups=groups)
-    all_s = lax.all_gather(s2[0], axis_name, axis_index_groups=groups)
+    all_q = clax.all_gather(q2[0], axis_name, axis_index_groups=groups)
+    all_s = clax.all_gather(s2[0], axis_name, axis_index_groups=groups)
     out = _block_dequant(all_q, all_s)[:, :chunk].reshape(-1)[:m]
     if not want_residual:
         return out, None
@@ -1161,7 +1187,7 @@ def hierarchical_allreduce_groups(
         flat = jnp.pad(flat, (0, pad))
     if prescale_factor != 1.0:
         flat = flat * jnp.asarray(prescale_factor, flat.dtype)
-    shard = lax.psum_scatter(
+    shard = clax.psum_scatter(
         _stage_cast(flat, intra_wire), axis_name,
         scatter_dimension=0, tiled=True, axis_index_groups=intra_groups,
     ).astype(flat.dtype)
@@ -1185,16 +1211,16 @@ def hierarchical_allreduce_groups(
                 # back to INPUT units: the correction will be
                 # re-multiplied by the prescale on its way in
                 res = res / jnp.asarray(prescale_factor, res.dtype)
-            residual = lax.all_gather(
+            residual = clax.all_gather(
                 res / jnp.asarray(L, res.dtype), axis_name,
                 tiled=True, axis_index_groups=intra_groups,
             )[:m]
     else:
-        red = lax.psum(
+        red = clax.psum(
             _stage_cast(shard, inter_wire), axis_name,
             axis_index_groups=inter_groups,
         ).astype(shard.dtype)
-    out = lax.all_gather(
+    out = clax.all_gather(
         _stage_cast(red, intra_wire), axis_name,
         tiled=True, axis_index_groups=intra_groups,
     ).astype(flat.dtype)
@@ -1251,7 +1277,7 @@ def hierarchical_reducescatter(
     cols = panes.shape[1]
     dtype = panes.dtype
     buf = panes.reshape(H, L, cols)
-    s1 = lax.psum_scatter(
+    s1 = clax.psum_scatter(
         _stage_cast(buf, intra_wire), axis_name,
         scatter_dimension=1, tiled=True, axis_index_groups=intra_groups,
     ).astype(dtype).reshape(H, cols)
@@ -1261,7 +1287,7 @@ def hierarchical_reducescatter(
             seed=seed, block_size=block_size, groups=inter_groups,
         ).astype(dtype)
     else:
-        shard = lax.psum_scatter(
+        shard = clax.psum_scatter(
             _stage_cast(s1, inter_wire), axis_name,
             scatter_dimension=0, tiled=True,
             axis_index_groups=inter_groups,
@@ -1302,11 +1328,11 @@ def hierarchical_allgather(
             block_size=block_size, groups=inter_groups,
         ).astype(dtype)  # [H, cols]
     else:
-        g1 = lax.all_gather(
+        g1 = clax.all_gather(
             _stage_cast(shard, inter_wire), axis_name,
             axis_index_groups=inter_groups,
         ).astype(dtype)  # [H, cols]
-    g2 = lax.all_gather(
+    g2 = clax.all_gather(
         _stage_cast(g1, intra_wire), axis_name,
         axis_index_groups=intra_groups,
     ).astype(dtype)  # [L, H, cols]
@@ -1363,7 +1389,7 @@ def hierarchical_allreduce(
         raise ValueError("hierarchical_allreduce supports Sum/Average only")
     out, _ = _two_level_allreduce(
         tensor, op, intra_axis, inter_axis,
-        lambda shard: (lax.psum(shard, inter_axis), None),
+        lambda shard: (clax.psum(shard, inter_axis), None),
         prescale=prescale_factor, postscale=postscale_factor,
     )
     return out
@@ -1391,11 +1417,11 @@ def _two_level_allreduce(
         flat = jnp.pad(flat, (0, padded - m))
     if prescale != 1.0:
         flat = flat * jnp.asarray(prescale, flat.dtype)
-    shard = lax.psum_scatter(
+    shard = clax.psum_scatter(
         flat, intra_axis, scatter_dimension=0, tiled=True
     )                                       # [padded/L], summed intra
     red, extra = inter_reduce(shard)        # cross-slice hop, 1/L bytes
-    out = lax.all_gather(red, intra_axis, tiled=True)  # [padded]
+    out = clax.all_gather(red, intra_axis, tiled=True)  # [padded]
     if op == Average:
         out = out / jnp.asarray(intra_n * inter_n, out.dtype)
     if postscale != 1.0:
@@ -1403,7 +1429,7 @@ def _two_level_allreduce(
     out = out[:m].reshape(shape).astype(dtype)
     if extra is None:
         return out, None
-    extra_full = lax.all_gather(
+    extra_full = clax.all_gather(
         extra / jnp.asarray(intra_n, extra.dtype), intra_axis,
         tiled=True,
     )

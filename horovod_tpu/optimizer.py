@@ -35,11 +35,24 @@ import optax
 
 from .common import guard as _guard
 from .common import telemetry as _telemetry
+from .common import tracing as _tracing
 from .common.process_sets import ProcessSet
 from .common.topology import WORLD_AXIS
 from .ops import overlap, traced
 from .ops.compression import Compression, Compressor
 from .ops.reduction_ops import Adasum, Average, ReduceOp, Sum, resolve_op
+
+
+def _under_trace() -> bool:
+    """True while JAX traces the caller (jit, shard_map, grad):
+    ``jax.core.trace_state_clean`` of earlier JAX, which 0.9 no longer
+    has (its absence read as "not traced", and a jitted tape opened one
+    host record at trace time and ticked never)."""
+    return not jax.core.trace_ctx.is_top_level()
+
+
+def _leaf_bytes(x) -> int:
+    return int(getattr(x, "nbytes", 0) or 0)
 
 
 def _allreduce_grads(
@@ -391,6 +404,7 @@ def DistributedOptimizer(
             int(jax.lax.axis_size(axis_name)), intra=local_sgd_intra
         )
 
+    @jax.named_scope(traced.EXCHANGE_SCOPE)  # on all it puts into the step
     def communicate(grads, seed, residuals=None):
         """Exchange + optional guard flag. Returns a uniform
         ``(reduced, new_residuals_or_None, finite_or_None)`` triple so
@@ -465,9 +479,23 @@ def DistributedOptimizer(
                 state.guard_skips + 1, streak_next,
             )
 
-        return jax.lax.cond(finite, apply, skip, operand=None)
+        with jax.named_scope(traced.UPDATE_SCOPE):
+            return jax.lax.cond(finite, apply, skip, operand=None)
+
+    # the wrapped transform's update, named in the compiled step
+    inner_update = jax.named_scope(traced.UPDATE_SCOPE)(optimizer.update)
 
     def init_fn(params):
+        with _tracing.span("hvd.init.optimizer_init") as sp:
+            state = _init_state(params)
+            leaves = jax.tree_util.tree_leaves(state)
+            sp.tag(
+                leaves=len(leaves),
+                bytes=sum(_leaf_bytes(x) for x in leaves),
+            )
+            return state
+
+    def _init_state(params):
         inner = optimizer.init(params)
         zero = jnp.zeros((), jnp.int32)
         residual = (
@@ -508,6 +536,16 @@ def DistributedOptimizer(
         )
 
     def update_fn(grads, state: _AccumulationState, params=None):
+        # trace time only: how long the exchange's and the update's
+        # Python takes is part of every cold start (init.trace_optimizer_s)
+        with _tracing.trace_time_span(
+            "hvd.trainer.trace_update", state.step
+        ) as sp:
+            if sp is not None:
+                sp.tag(leaves=len(jax.tree_util.tree_leaves(grads)))
+            return _update(grads, state, params)
+
+    def _update(grads, state: _AccumulationState, params):
         # Flight-recorder auto-threading (common/telemetry.py): one
         # step-boundary tick per compiled update, riding the SAME
         # internal step counter that seeds stochastic rounding — this
@@ -534,7 +572,7 @@ def DistributedOptimizer(
                     local_anchor=state.local_anchor,
                     local_residual=state.local_residual,
                 )
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            updates, inner = inner_update(reduced, state.inner, params)
             return updates, _AccumulationState(
                 inner=inner, accum=None, counter=state.counter,
                 step=state.step + 1, residual=residual,
@@ -548,23 +586,26 @@ def DistributedOptimizer(
         # k micro-grads is applied unless average_aggregated_gradients=True
         # (ref: gradient_aggregation defaults,
         # horovod/tensorflow/gradient_aggregation*.py [V]).
-        accum = jax.tree_util.tree_map(
-            lambda a, g: a + g, state.accum, grads
-        )
+        with jax.named_scope(traced.ACCUMULATE_SCOPE):
+            accum = jax.tree_util.tree_map(
+                lambda a, g: a + g, state.accum, grads
+            )
         counter = state.counter + 1
         boundary = counter >= k
 
         def do_step(_):
-            agg = (
-                jax.tree_util.tree_map(lambda a: a / k, accum)
-                if average_aggregated_gradients
-                else accum
-            )
+            with jax.named_scope(traced.ACCUMULATE_SCOPE):
+                agg = (
+                    jax.tree_util.tree_map(lambda a: a / k, accum)
+                    if average_aggregated_gradients
+                    else accum
+                )
             reduced, residual, finite = communicate(
                 agg, state.step,
                 residuals=state.residual if error_feedback else None,
             )
-            zeroed = jax.tree_util.tree_map(jnp.zeros_like, accum)
+            with jax.named_scope(traced.ACCUMULATE_SCOPE):
+                zeroed = jax.tree_util.tree_map(jnp.zeros_like, accum)
             if guard_on:
                 # a skipped boundary still clears the accumulator: the
                 # poisoned micro-batch window is discarded, not replayed
@@ -575,7 +616,7 @@ def DistributedOptimizer(
                     updates, inner, zeroed, jnp.zeros((), jnp.int32),
                     residual, skips, streak,
                 )
-            updates, inner = optimizer.update(reduced, state.inner, params)
+            updates, inner = inner_update(reduced, state.inner, params)
             return (
                 updates, inner, zeroed, jnp.zeros((), jnp.int32),
                 residual, state.guard_skips, state.guard_streak,
@@ -759,13 +800,8 @@ def value_and_grad(
         tracing (a jitted wrapper runs this body once, at trace time —
         the optimizer's debug-callback tick owns that case) and when a
         step is already open (explicit hvd.step_begin wins)."""
-        if not _telemetry.auto_enabled():
+        if not _telemetry.auto_enabled() or _under_trace():
             return False
-        try:
-            if not jax.core.trace_state_clean():
-                return False
-        except Exception:
-            pass
         step = hvd_step if isinstance(hvd_step, int) else None
         return _telemetry.hub().auto_step_begin(step)
 
@@ -784,11 +820,7 @@ def value_and_grad(
             # mechanism as the optimizer's auto-threading. A concrete
             # constant hvd_step under jit collapses to one record (the
             # quantized-seed warning above covers that misuse).
-            try:
-                under_trace = not jax.core.trace_state_clean()
-            except Exception:
-                under_trace = False
-            if under_trace:
+            if _under_trace():
                 # source "tape": these ids are the CALLER's step
                 # counter, so they outrank the optimizer's internal
                 # ticks — when both fire in one program only one
@@ -825,10 +857,11 @@ def value_and_grad(
             )
             return vg2(*args, **kwargs)
         val, grads = vg(*args, **kwargs)
-        grads = _allreduce_grads(
-            grads, op, compression, 1.0, 1.0, process_set, axis_name,
-            seed=seed,
-        )
+        with jax.named_scope(traced.EXCHANGE_SCOPE):
+            grads = _allreduce_grads(
+                grads, op, compression, 1.0, 1.0, process_set, axis_name,
+                seed=seed,
+            )
         return val, grads
 
     return wrapped
@@ -863,6 +896,14 @@ def broadcast_parameters(params, root_rank: int = 0):
       rank's row is overwritten with ``root_rank``'s, which is the
       reference's actual semantics (rank 0 may have restored a
       checkpoint the others don't have)."""
+    with _tracing.span("hvd.init.broadcast_parameters") as sp:
+        return _broadcast_tree(params, root_rank, sp)
+
+
+def _broadcast_tree(params, root_rank: int, sp):
+    """:func:`broadcast_parameters` inside the caller's span, which
+    gets the leaves, the bytes and the transfers (``device_put`` calls:
+    one per leaf, two per rank-major leaf) as tags."""
     from .common import basics
     from .common.topology import WORLD_AXIS, replicated_sharding
 
@@ -889,13 +930,20 @@ def broadcast_parameters(params, root_rank: int = 0):
             )
         return jax.device_put(x, sharding)
 
-    return jax.tree_util.tree_map(one, params)
+    out = jax.tree_util.tree_map(one, params)
+    leaves = jax.tree_util.tree_leaves(out)
+    sp.tag(
+        leaves=len(leaves), bytes=sum(_leaf_bytes(x) for x in leaves),
+        device_puts=len(leaves) + sum(map(_rank_major, leaves)),
+    )
+    return out
 
 
 def broadcast_optimizer_state(opt_state, root_rank: int = 0):
     """Replicate optimizer state (ref: broadcast_optimizer_state [V]).
     Same mechanism as broadcast_parameters — optax states are pytrees."""
-    return broadcast_parameters(opt_state, root_rank)
+    with _tracing.span("hvd.init.broadcast_optimizer_state") as sp:
+        return _broadcast_tree(opt_state, root_rank, sp)
 
 
 def broadcast_object(obj, root_rank: int = 0, name: Optional[str] = None):

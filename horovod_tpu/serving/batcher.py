@@ -57,6 +57,7 @@ SIGTERM waits for streamed requests to finish or fall back.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import threading
@@ -99,6 +100,15 @@ class Request:
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     ttft_ms: float = 0.0
     gen_ms: float = 0.0
+    # one stamp per output token, ms from submission to the moment the
+    # batcher committed it (:meth:`commit`): what a client needs to time
+    # the first token and every gap, since the reply comes only when
+    # the request is done
+    token_ms: List[float] = dataclasses.field(default_factory=list)
+    # ms from submission to the first admission (a slot and its pages
+    # were there): the queue wait, which TTFT holds together with the
+    # prefill
+    queue_ms: float = 0.0
     # paged memory plane (serving/paged_kv.py): pause/resume state. A
     # request paused on pool exhaustion re-queues with ``paused=True``;
     # ``kept_pages`` holds its page-table snapshot (refcounts
@@ -131,6 +141,13 @@ class Request:
         default_factory=threading.Event
     )
 
+    def commit(self, token: int, now: Optional[float] = None) -> None:
+        """Append one output token with its stamp (``now``: a
+        ``time.monotonic()`` reading, taken here when not given)."""
+        now = time.monotonic() if now is None else now
+        self.out_tokens.append(int(token))
+        self.token_ms.append((now - self.submitted) * 1e3)
+
     def wait(self, timeout: Optional[float] = None) -> bool:
         return self._done.wait(timeout)
 
@@ -145,6 +162,8 @@ class Request:
             "prompt_len": int(self.prompt.size),
             "ttft_ms": round(self.ttft_ms, 3),
             "gen_ms": round(self.gen_ms, 3),
+            "token_ms": [round(t, 3) for t in self.token_ms],
+            "queue_ms": round(self.queue_ms, 3),
         }
         if self.trace is not None:
             out["trace_id"] = self.trace.trace_id
@@ -330,7 +349,7 @@ class ContinuousBatcher:
             seed=seed,
             trace=trace,
         )
-        req.out_tokens.append(int(first_token))
+        req.commit(first_token)
         req.ingest = {
             "logical": [int(lp) for lp in pages],
             "arrays": arrays,
@@ -392,7 +411,8 @@ class ContinuousBatcher:
             ),
             trace=trace,
         )
-        req.out_tokens.extend(toks)
+        for tok in toks:
+            req.commit(tok)
         req.ingest = {
             "logical": [int(lp) for lp in pages],
             "arrays": arrays,
@@ -450,6 +470,13 @@ class ContinuousBatcher:
         worker's. Called from the handoff thread."""
         req.out_tokens = [int(t) for t in result.get("tokens", ())]
         req.gen_ms = float(result.get("gen_ms", 0.0))
+        # the decode worker's stamps count from ITS submission, which
+        # followed this worker's first token: shifted by the local
+        # TTFT they leave out the transfer, like gen_ms does
+        remote = [float(t) for t in result.get("token_ms", ())]
+        req.token_ms = req.token_ms[:1] + [
+            req.ttft_ms + t for t in remote[1:]
+        ]
         req.status = DONE if result.get("status") == "done" else DEADLINE
         with self._cond:
             self._handoffs -= 1
@@ -571,10 +598,20 @@ class ContinuousBatcher:
         return records
 
     def _run(self) -> None:
+        # holds the open ``hvd.batcher.idle_wait`` span: one per idle
+        # stretch (HOROVOD_TRACE), however many 20 ms waits it takes
+        with contextlib.ExitStack() as idle:
+            self._rounds(idle)
+
+    def _rounds(self, idle: contextlib.ExitStack) -> None:
+        waiting = False
         while True:
             with self._cond:
                 if not self._running:
                     return
+                if waiting and self._queue:
+                    idle.close()  # work arrived: the stretch ends here
+                    waiting = False
             try:
                 did = self.step()
             except Exception:
@@ -592,11 +629,20 @@ class ContinuousBatcher:
                     self._draining = True
                     self._running = False
                 return
+            if did and waiting:  # work that came past the queue
+                idle.close()
+                waiting = False
             if not did:
                 with self._cond:
                     if self._running and not self._queue:
                         # short timeout: queued deadlines must still
                         # expire while the plane idles
+                        if not waiting:
+                            idle.enter_context(_tracing.hot_span(
+                                "hvd.batcher.idle_wait",
+                                active=len(self._slot_req),
+                            ))
+                            waiting = True
                         self._cond.wait(timeout=0.02)
 
     def _abort_all(self, reason: str) -> None:
@@ -712,6 +758,8 @@ class ContinuousBatcher:
             with self._cond:
                 # single consumer: the head is still req
                 self._queue.popleft()
+            if req.admit_seq < 0:
+                req.queue_ms = (time.monotonic() - req.submitted) * 1e3
             req.admit_seq = next(self._admit_ids)
             if req.kept_pages is not None:
                 # resume from pause: the kept pages pointer-attach and
@@ -817,8 +865,9 @@ class ContinuousBatcher:
                         slot, req.prompt, trace=req.trace
                     )
                     req.status = RUNNING
-                    req.ttft_ms = (time.monotonic() - req.submitted) * 1e3
-                    req.out_tokens.append(int(first))
+                    t_first = time.monotonic()
+                    req.ttft_ms = (t_first - req.submitted) * 1e3
+                    req.commit(first, t_first)
                     if pspan is not None:
                         pspan.end(ttft_ms=round(req.ttft_ms, 3))
                     self.recorder.record_ttft(
@@ -982,7 +1031,7 @@ class ContinuousBatcher:
         now = time.monotonic()
         for slot, req in list(self._slot_req.items()):
             self.engine.manager.advance(slot)
-            req.out_tokens.append(int(nxt[slot]))
+            req.commit(nxt[slot], now)
             req.gen_ms = (now - req.submitted) * 1e3 - req.ttft_ms
             self.recorder.record_tpot(
                 step_ms, req.trace.trace_id if req.trace else ""

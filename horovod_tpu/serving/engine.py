@@ -978,13 +978,28 @@ class InferenceEngine:
                 self._decode_fn(), args, "decode", decode=True,
                 meta={"slots": int(self.slots)},
             )
-        out, self.manager.cache, self._sample_keys = self._decode_exe(
-            *args
-        )
+        with _tracing.hot_span("hvd.engine.decode_step") as sp:
+            if sp is not None:
+                sp.tag(**self._decode_tags())
+            out, self.manager.cache, self._sample_keys = self._decode_exe(
+                *args
+            )
         self._counters["decode_steps"] += 1
         if self.paged_attn:
             self._counters["paged_attn_calls"] += 1
         return np.asarray(out)
+
+    def _decode_tags(self) -> dict:
+        """Tags of ``hvd.engine.decode_step``: active slots and, on the
+        paged plane, live pages of the pool."""
+        mgr = self.manager
+        tags = {"active": len(mgr.active_slots())}
+        if self.paged:
+            tags["pages"] = int(mgr.num_pages)
+            tags["live_pages"] = int(
+                mgr.num_pages - mgr.free_pages_available()
+            )
+        return tags
 
     # ------------------------------------------------------------- sampling
 

@@ -79,6 +79,7 @@ import numpy as np
 import optax
 
 from .common.topology import WORLD_AXIS
+from .ops import traced as _traced
 from .ops.reduction_ops import Average, ReduceOp, Sum, resolve_op
 from .parallel.fsdp import (
     dyn_shard as _shard_dyn_impl,
@@ -823,14 +824,15 @@ class ShardedDistributedOptimizer:
             # exactly like init's _shard_host — so state shapes are
             # stable step-over-step (a shape flip would force a retrace
             # and break donation)
+            @jax.named_scope(_traced.EXCHANGE_SCOPE)
             def rs(g):
                 if g.ndim == 0:
-                    red = jax.lax.psum(
+                    red = _traced.clax.psum(
                         g, self._axis, axis_index_groups=intra_groups
                     )
                     return red / width if self._op == Average else red
                 flat = _pad_to(g.reshape(-1), width).reshape(width, -1)
-                red = jax.lax.psum_scatter(
+                red = _traced.clax.psum_scatter(
                     flat, self._axis, scatter_dimension=0, tiled=False,
                     axis_index_groups=intra_groups,
                 )
@@ -852,11 +854,12 @@ class ShardedDistributedOptimizer:
             # step without stalling the others (and the local-phase
             # program stays free of inter-slice groups).
             ok_local = tree_finite(g_sh)
-            bad = jax.lax.psum(
-                jnp.where(ok_local, 0.0, 1.0).astype(jnp.float32),
-                self._axis,
-                axis_index_groups=intra_groups,
-            )
+            with jax.named_scope(_traced.EXCHANGE_SCOPE):
+                bad = _traced.clax.psum(
+                    jnp.where(ok_local, 0.0, 1.0).astype(jnp.float32),
+                    self._axis,
+                    axis_index_groups=intra_groups,
+                )
             finite = bad == 0
             # feed the inner transform clean zeros on a bad step; its
             # output and state delta are discarded below anyway, this
@@ -864,7 +867,8 @@ class ShardedDistributedOptimizer:
             g_sh = jax.tree_util.tree_map(
                 lambda g: jnp.where(finite, g, jnp.zeros_like(g)), g_sh
             )
-        upd_sh, new_local = self._inner.update(g_sh, local_state, p_sh)
+        with jax.named_scope(_traced.UPDATE_SCOPE):
+            upd_sh, new_local = self._inner.update(g_sh, local_state, p_sh)
         if self._guard_on:
             # skip-step semantics by selection: zero updates, state of
             # the last APPLIED step (where, not multiply — selects are
@@ -913,10 +917,11 @@ class ShardedDistributedOptimizer:
                     groups=intra_groups,
                 )
         else:
+            @jax.named_scope(_traced.EXCHANGE_SCOPE)
             def gather(u, p):
                 if p.ndim == 0:
                     return u
-                full = jax.lax.all_gather(
+                full = _traced.clax.all_gather(
                     u, self._axis, axis=0,
                     axis_index_groups=intra_groups,
                 ).reshape(-1)
@@ -1080,7 +1085,7 @@ class ShardedDistributedOptimizer:
             )
             new_r = None
         new_anchor_flat = a_flat + merged
-        gathered = jax.lax.all_gather(
+        gathered = _traced.clax.all_gather(
             new_anchor_flat, self._axis, axis_index_groups=intra_groups
         )  # [L, C] — position-major chunks of the consensus params
         new_p, new_a, new_res = [], [], []

@@ -221,6 +221,11 @@ class TestFlightRecorder:
                 jax.config.update("jax_platforms", "cpu")
                 import horovod_tpu as hvd
 
+                # the first step brings the hub and its SIGTERM hook up:
+                # READY only after it, or a loaded machine kills a
+                # worker that has no handler yet (rc -15)
+                hvd.step_begin()
+                hvd.step_end()
                 print("READY", flush=True)
                 while True:
                     hvd.step_begin()
