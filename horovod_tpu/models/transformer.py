@@ -3,9 +3,12 @@ configs (BASELINE.json: BERT-large pretraining, GPT-2 medium [V]).
 
 One configurable implementation: ``causal=True`` → GPT-2-style decoder;
 ``causal=False`` → BERT-style encoder. TPU-first: bfloat16 activations,
-fp32 layernorm/softmax accumulation, static shapes, `remat` for
-HBM-bound configs, head dims sized for the MXU (multiples of 128 at
-real scale).
+fp32 layernorm/softmax accumulation, static shapes, head dims sized for
+the MXU (multiples of 128 at real scale). `remat` trades the blocks'
+activations for recomputation in the backward; how much it keeps is
+decided from the shapes and the device's memory (:func:`remat_plan`):
+the matmul and flash-kernel outputs where they fit a quarter of the
+device, else only each block's input.
 
 The distributed execution path (tp/sp/pp/ep over a mesh) lives in
 horovod_tpu/parallel/ — this module is the single-chip / pure-DP model.
@@ -25,6 +28,7 @@ from ..common import tracing as _tracing
 from ..common.logging import get_logger
 from ..common.metrics import registry as _metrics
 from ..ops.flash_attention import DEFAULT_BLOCK as _DEFAULT_FLASH_BLOCK
+from ..ops.flash_attention import RESIDUAL_NAMES as _FLASH_RESIDUAL_NAMES
 
 _log = get_logger("models.transformer")
 
@@ -40,6 +44,9 @@ class TransformerConfig:
     causal: bool = True
     dropout_rate: float = 0.0
     dtype: Any = jnp.bfloat16
+    # Rematerialize every block in the backward (training only). What a
+    # block keeps besides its input is not a setting: remat_plan()
+    # decides it from the per-chip shapes and the device's memory limit.
     remat: bool = False
     # Blockwise Pallas attention (ops/flash_attention.py): True/False,
     # or "auto" = use it on TPU whenever no padding mask is passed (the
@@ -675,6 +682,75 @@ class LMHead(nn.Module):
         return y + bias
 
 
+# The largest share of the device's memory that remat's saved matmul and
+# kernel outputs may take (PERF.md section 6, PR 26, has the two chip
+# readings it rests on): past it the blocks recompute everything, as a
+# user who set ``remat`` because memory is short expects.
+REMAT_SAVE_SHARE = 0.25
+
+
+def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
+    """What ``Transformer(remat=True)`` keeps between forward and
+    backward, from what the model knows at trace time: ``(mode,
+    saved_bytes)`` for ``tokens`` tokens on this chip and a device
+    memory of ``bytes_limit`` bytes (None: not known).
+
+    ``save_matmuls``: each block keeps the outputs of its weight matmuls
+    (qkv, the attention's output projection, the first feed-forward
+    matmul) and of the flash forward (the attention output and one lane
+    of ``lse``), and recomputes only the element-wise work; on the
+    flash path q, k and v are kept as the kernels take them, in place of
+    the projection's output, so the head transposes are not repeated
+    either. ``saved_bytes`` is their size over all layers (the
+    attention output is counted on the dense path too, which recomputes
+    it). Taken when that is at most ``REMAT_SAVE_SHARE`` of the
+    device. ``recompute_all``: each block keeps its input alone —
+    where the saving would not fit, where the limit cannot be read (CPU)
+    and for MoE blocks, whose expert einsums this reckoning does not
+    cover. ``off``: ``cfg.remat`` is not set."""
+    if not cfg.remat:
+        return "off", 0
+    if cfg.moe_experts or not bytes_limit:
+        return "recompute_all", 0
+    head_dim = cfg.d_model // cfg.num_heads
+    kv_width = 2 * (cfg.num_kv_heads or cfg.num_heads) * head_dim
+    # q + kv, attention output, output projection, first feed-forward
+    width = 3 * cfg.d_model + kv_width + cfg.d_ff
+    per_token = width * jnp.dtype(cfg.dtype).itemsize + 4 * cfg.num_heads
+    saved = cfg.num_layers * int(tokens) * per_token
+    if saved > REMAT_SAVE_SHARE * bytes_limit:
+        return "recompute_all", 0
+    return "save_matmuls", saved
+
+
+def _device_bytes_limit() -> Optional[int]:
+    """``bytes_limit`` of this process's first device; None where the
+    backend reports no memory statistics (CPU)."""
+    try:
+        stats = jax.local_devices()[0].memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+def _save_matmuls_policy():
+    """Checkpoint policy of ``save_matmuls``: weight matmuls are the
+    ``dot_general``s without batch dimensions (the dense attention
+    fallback's einsums have them, and are recomputed); the flash
+    kernels' residuals go by name, since a policy on primitives does
+    not see through a ``pallas_call``. An output the backward does not
+    read (the second feed-forward matmul's; the qkv projection's where
+    q, k and v are kept by name) is not kept."""
+    policies = jax.checkpoint_policies
+    return policies.save_from_both_policies(
+        policies.dots_with_no_batch_dims_saveable,
+        policies.save_only_these_names(*_FLASH_RESIDUAL_NAMES),
+    )
+
+
+_TRACE_MODEL_SPAN = "hvd.trainer.trace_model"
+
+
 def _span_at_trace_time(call):
     """``hvd.trainer.trace_model`` around a model's ``__call__`` while
     JAX traces it (``tokens`` is a tracer): how long the model's Python
@@ -684,7 +760,7 @@ def _span_at_trace_time(call):
     @functools.wraps(call)
     def wrapped(self, tokens, *args, **kwargs):
         with _tracing.trace_time_span(
-            "hvd.trainer.trace_model", tokens, layers=self.cfg.num_layers
+            _TRACE_MODEL_SPAN, tokens, layers=self.cfg.num_layers
         ):
             return call(self, tokens, *args, **kwargs)
 
@@ -739,8 +815,18 @@ class Transformer(nn.Module):
             x = nn.LayerNorm(dtype=jnp.float32)(x)
             return LMHead(cfg, name="lm_head")(x), new_cache
         block = Block
-        if cfg.remat:
-            block = nn.remat(Block, static_argnums=(3,))
+        mode, saved_bytes = remat_plan(
+            cfg, tokens.shape[0] * tokens.shape[1],
+            _device_bytes_limit() if cfg.remat else None,
+        )
+        if mode != "off":
+            policy = (
+                _save_matmuls_policy() if mode == "save_matmuls" else None
+            )
+            block = nn.remat(Block, static_argnums=(3,), policy=policy)
+        span = _tracing.current()
+        if span is not None and span.name == _TRACE_MODEL_SPAN:
+            span.tag(remat=mode, remat_saved_bytes=saved_bytes)
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"block_{i}")(x, mask, train, lengths)
         x = nn.LayerNorm(dtype=jnp.float32)(x)

@@ -32,6 +32,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -537,15 +538,34 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
     return o, lse
 
 
-def _flash_fwd_vjp(q, k, v, causal, block_q, block_k, window):
-    o, lse = _flash_fwd(
-        q, k, v, causal, block_q, block_k, window=window
+# The names a ``jax.checkpoint`` policy saves the forward rules'
+# residuals by (``save_only_these_names(*RESIDUAL_NAMES)``): a policy on
+# primitives does not see through a ``pallas_call``.
+RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_o", "flash_lse")
+
+
+def _named_residuals(q, k, v, o, lse):
+    """What every forward rule keeps for the backward kernels, ``(q, k,
+    v, o, lse_lane)``, each under its name of ``RESIDUAL_NAMES``: a
+    rematted caller whose policy saves the names runs neither the
+    forward kernel nor the head transposes that made q, k and v a second
+    time; outside ``jax.checkpoint`` a name is the identity.
+
+    Keep ONE lane of ``lse`` — the broadcast 128-lane layout is a
+    Mosaic in-kernel constraint, not something worth holding across
+    the whole forward pass (24 BERT-large layers of (bh, seq, 128)
+    fp32 would be ~800 MB); re-broadcast transiently in the bwd."""
+    return tuple(
+        checkpoint_name(x, name)
+        for x, name in zip((q, k, v, o, lse[..., 0]), RESIDUAL_NAMES)
     )
-    # Keep ONE lane as the residual — the broadcast 128-lane layout is a
-    # Mosaic in-kernel constraint, not something worth holding across
-    # the whole forward pass (24 BERT-large layers of (bh, seq, 128)
-    # fp32 would be ~800 MB); re-broadcast transiently in the bwd.
-    return o, (q, k, v, o, lse[..., 0])
+
+
+def _flash_fwd_vjp(q, k, v, causal, block_q, block_k, window):
+    q, k, v, o, lse_lane = _named_residuals(q, k, v, *_flash_fwd(
+        q, k, v, causal, block_q, block_k, window=window
+    ))
+    return o, (q, k, v, o, lse_lane)
 
 
 def _flash_bwd_vjp_w(causal, block_q, block_k, window, res, do):
@@ -558,10 +578,10 @@ def _flash_bwd_vjp_w(causal, block_q, block_k, window, res, do):
 
 def _flash_fwd_vjp_padded(q, k, v, lens, causal, block_q, block_k,
                           window):
-    o, lse = _flash_fwd(
+    q, k, v, o, lse_lane = _named_residuals(q, k, v, *_flash_fwd(
         q, k, v, causal, block_q, block_k, lens=lens, window=window
-    )
-    return o, (q, k, v, o, lse[..., 0], lens)
+    ))
+    return o, (q, k, v, o, lse_lane, lens)
 
 
 def _flash_bwd_vjp_padded(causal, block_q, block_k, window, res, do):
@@ -682,11 +702,11 @@ def _flash_bhtd_gqa(q, k, v, causal, block_q, block_k, h_per_kv, window):
 def _flash_fwd_vjp_gqa(
     q, k, v, causal, block_q, block_k, h_per_kv, window
 ):
-    o, lse = _flash_fwd(
+    q, k, v, o, lse_lane = _named_residuals(q, k, v, *_flash_fwd(
         q, k, v, causal, block_q, block_k, h_per_kv=h_per_kv,
         window=window,
-    )
-    return o, (q, k, v, o, lse[..., 0])
+    ))
+    return o, (q, k, v, o, lse_lane)
 
 
 def _flash_bwd_vjp_gqa(
@@ -716,11 +736,11 @@ def _flash_bhtd_gqa_padded(
 def _flash_fwd_vjp_gqa_padded(
     q, k, v, lens, causal, block_q, block_k, h_per_kv, window
 ):
-    o, lse = _flash_fwd(
+    q, k, v, o, lse_lane = _named_residuals(q, k, v, *_flash_fwd(
         q, k, v, causal, block_q, block_k, lens=lens,
         h_per_kv=h_per_kv, window=window,
-    )
-    return o, (q, k, v, o, lse[..., 0], lens)
+    ))
+    return o, (q, k, v, o, lse_lane, lens)
 
 
 def _flash_bwd_vjp_gqa_padded(

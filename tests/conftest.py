@@ -71,3 +71,16 @@ def dense_attention_oracle(q, k, v, causal):
     return jnp.einsum(
         "bhqk,bkhd->bqhd", p, v.astype(jnp.float32)
     ).astype(q.dtype)
+
+
+def stripped_hlo(compiled_text: str) -> str:
+    """A compiled program's text without what describes its source: each
+    instruction's ``metadata={...}`` and the module's tables of files,
+    functions, lines and stack frames (which hold the caller's own line
+    numbers)."""
+    import re
+
+    text = re.sub(r", metadata=\{[^}]*\}", "", compiled_text)
+    return re.sub(
+        r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+        r"(\d+ .*\n)*", "\n", text)

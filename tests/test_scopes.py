@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import optax
 import pytest
+from conftest import stripped_hlo
 from jax.sharding import PartitionSpec as P
 
 
@@ -79,16 +80,6 @@ def _op_names(compiled_text: str):
     return set(re.findall(r'op_name="([^"]*)"', compiled_text))
 
 
-def _stripped(compiled_text: str) -> str:
-    """The program without what describes its source: each instruction's
-    ``metadata={...}`` and the module's tables of files, functions, lines
-    and stack frames (which also hold this file's own line numbers)."""
-    text = re.sub(r", metadata=\{[^}]*\}", "", compiled_text)
-    return re.sub(
-        r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
-        r"(\d+ .*\n)*", "\n", text)
-
-
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_every_exchange_path_carries_the_scopes(hvd, path):
     build, kwargs = PATHS[path]
@@ -131,4 +122,4 @@ def test_scopes_are_metadata_only(hvd, monkeypatch, path):
     bare = step.lower(*args).compile().as_text()
     for scope in ("hvd_exchange", "hvd_update", "/collective/"):
         assert scope not in bare, scope
-    assert _stripped(scoped) == _stripped(bare)
+    assert stripped_hlo(scoped) == stripped_hlo(bare)
