@@ -10,6 +10,13 @@ decided from the shapes and the device's memory (:func:`remat_plan`):
 the matmul and flash-kernel outputs where they fit a quarter of the
 device, else only each block's input.
 
+The same ``Block`` also builds the sparse long-context decoders
+(``layer_kinds``): per layer a window or a full attention, with or
+without RoPE, and a dense gated feed-forward or an expert layer that is
+told which experts of the deployment it holds (:class:`ExpertFFN`);
+RMSNorm in a sandwich, bias-free projections, QK-norm and a gated
+attention output are fields of the one ``TransformerConfig``.
+
 The distributed execution path (tp/sp/pp/ep over a mesh) lives in
 horovod_tpu/parallel/ — this module is the single-chip / pure-DP model.
 """
@@ -104,6 +111,95 @@ class TransformerConfig:
     # slower on a vocab_size-wide projection that is ~15% of forward
     # FLOPs at GPT-2 scale).
     head_mixed_precision: bool = True
+    # ---- Per-layer kinds and the block's variants. Every default is the
+    # GPT-2/BERT block the fields above describe.
+    # One "<attention>/<feed-forward>" string per layer (its length must
+    # be num_layers); None = every layer alike, as the fields above say.
+    # Attention: "window" (the causal band of ``sliding_window``) or
+    # "full" (causal, no band), each with "-nope" appended where the
+    # layer carries no position rotation although ``rope`` is on.
+    # Feed-forward: "dense" (``d_ff`` wide, gated where ``ffn_gated``)
+    # or "experts" (:class:`ExpertFFN`, the ``moe_*`` fields below).
+    # E.g. ("window/dense", "window/experts", "full-nope/experts").
+    layer_kinds: Optional[tuple] = None
+    # Width of one attention head; None = d_model // num_heads. Set where
+    # heads x head_dim is not d_model.
+    head_dim: Optional[int] = None
+    # "layernorm" (mean and bias) or "rmsnorm" (scale alone), in fp32.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    # A second norm on each branch's output before the residual add:
+    # x + norm(attn(norm(x))), x + norm(ffn(norm(x))).
+    sandwich_norm: bool = False
+    # Biases on the projections, the feed-forward and the head.
+    use_bias: bool = True
+    # Feed-forward W_d(silu(W_g x) * W_u x) in place of W_2 gelu(W_1 x).
+    ffn_gated: bool = False
+    # RMSNorm over head_dim on q and on k, before any rotation.
+    qk_norm: bool = False
+    # o = o * sigmoid(W_gate x) on the attention output, before W_o.
+    attn_output_gate: bool = False
+    # Multiplier on the token embedding's output (muP: sqrt(d_model)).
+    embed_scale: float = 1.0
+    # Expert layers ("experts" in layer_kinds). The router scores all
+    # ``moe_experts_total`` experts and picks ``moe_top_k`` a token; this
+    # chip holds the experts ``[first, last)`` of ``moe_experts_held``
+    # (None = all) and computes their part of the result, dropless; what
+    # experts held elsewhere would add is left out (the exchange across
+    # chips is parallel/moe.py's, not the model's). ``moe_d_ff`` is one
+    # routed expert's width, ``moe_shared_d_ff`` that of the shared
+    # expert every token passes (0 = none). Gates: ``moe_score``
+    # ("sigmoid" or "softmax") of the router's fp32 logits, renormalised
+    # over the chosen k where ``moe_route_norm``, times
+    # ``moe_route_scale``.
+    moe_experts_total: int = 0
+    moe_experts_held: Optional[tuple] = None
+    moe_top_k: int = 1
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
+    moe_score: str = "sigmoid"
+    moe_route_norm: bool = True
+    moe_route_scale: float = 1.0
+
+    def dim_per_head(self) -> int:
+        return self.head_dim or self.d_model // self.num_heads
+
+    def layer_kind(self, layer: int):
+        """``(attention kind, feed-forward kind)`` of a layer, or
+        ``(None, None)``: the model-wide settings."""
+        if self.layer_kinds is None:
+            return None, None
+        if len(self.layer_kinds) != self.num_layers:
+            raise ValueError(
+                f"layer_kinds names {len(self.layer_kinds)} layers, "
+                f"num_layers is {self.num_layers}"
+            )
+        attn, _, ffn = self.layer_kinds[layer].partition("/")
+        if attn.removesuffix("-nope") not in ("window", "full") or ffn not in (
+            "dense", "experts"
+        ):
+            raise ValueError(
+                f"layer kind {self.layer_kinds[layer]!r} is not "
+                "'<window|full>[-nope]/<dense|experts>'"
+            )
+        return attn, ffn
+
+    def attention_kind(self, kind: Optional[str]):
+        """``(window, rope)`` of an attention kind of ``layer_kinds``
+        (None: the model-wide ``sliding_window`` and ``rope``)."""
+        if kind is None:
+            return self.sliding_window, self.rope
+        if kind.startswith("window") and not self.sliding_window:
+            raise ValueError("a 'window' layer needs sliding_window")
+        return (
+            self.sliding_window if kind.startswith("window") else None,
+            self.rope and not kind.endswith("-nope"),
+        )
+
+    def has_experts(self) -> bool:
+        return bool(self.moe_experts) or any(
+            k.endswith("/experts") for k in self.layer_kinds or ()
+        )
 
     def wants_flash(self) -> bool:
         """The configuration half of the flash gate: ``True``/``False``
@@ -124,30 +220,14 @@ class TransformerConfig:
             )
         if seq is None:
             return None
-        from ..ops.flash_attention import (
-            bwd_vmem_bytes,
-            fits_vmem,
-            supports_seq,
-        )
+        from ..ops.flash_attention import supports_seq
 
         if not supports_seq(seq, self.flash_block_q, self.flash_block_k):
             # untileable lengths (e.g. ViT's 197 tokens) would fail
             # Mosaic's block constraints
             return f"seq {seq} tiles no 8-aligned block"
-        head_dim = self.d_model // self.num_heads
-        group = self.num_heads // (self.num_kv_heads or self.num_heads)
-        itemsize = jnp.dtype(self.dtype).itemsize
-        if not fits_vmem(seq, head_dim, group, itemsize, self.flash_block_k):
-            # the backward dK/dV kernel stages the whole q-head group
-            # whole-sequence
-            est = bwd_vmem_bytes(
-                seq, head_dim, group, itemsize, self.flash_block_k
-            )
-            return (
-                f"the dK/dV backward would stage ~{est / 2**20:.0f} MiB "
-                f"(seq {seq}, head_dim {head_dim}, {group} q heads per kv "
-                "head), over the VMEM budget"
-            )
+        # no decline for VMEM: where the dK/dV kernel's whole-sequence
+        # staging would not fit, it stages by block (ops/flash_attention)
         return None
 
     def uses_flash(self, mask=None, seq=None) -> bool:
@@ -251,7 +331,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len=None, dtype=None):
             f"table ({cfg.max_len}); raise cfg.max_len or use rope=True"
         )
     kv_heads = cfg.num_kv_heads or cfg.num_heads
-    head_dim = cfg.d_model // cfg.num_heads
+    head_dim = cfg.dim_per_head()
     dt = cfg.dtype if dtype is None else dtype
     return [
         {
@@ -262,14 +342,39 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len=None, dtype=None):
     ]
 
 
+def _norm(cfg: TransformerConfig, **kwargs):
+    """The model's norm, computed in fp32."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, **kwargs)
+    if cfg.norm != "layernorm":
+        raise ValueError(f"norm {cfg.norm!r} is not layernorm or rmsnorm")
+    return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, **kwargs)
+
+
 class MultiHeadAttention(nn.Module):
     cfg: TransformerConfig
+    # this layer's attention kind of cfg.layer_kinds; None = model-wide
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, mask=None, lengths=None, cache=None,
                  cache_index=None, pages=None, paged_attn=False):
         cfg = self.cfg
-        head_dim = cfg.d_model // cfg.num_heads
+        window, rope = cfg.attention_kind(self.kind)
+        with jax.named_scope("attn_window" if window else "attn_full"):
+            return self._attend(x, mask, lengths, cache, cache_index,
+                                pages, paged_attn, window, rope)
+
+    def _attend(self, x, mask, lengths, cache, cache_index, pages,
+                paged_attn, window, rope):
+        cfg = self.cfg
+        head_dim = cfg.dim_per_head()
+        if pages is not None and self.kind is not None:
+            raise NotImplementedError(
+                "pages= with layer_kinds: the paged kernel has no band "
+                "and the page allocator holds one kind of layer "
+                "(ROADMAP B-M3); the dense cached path (pages=None) works"
+            )
         if cache is not None:
             if not cfg.causal:
                 raise ValueError(
@@ -291,28 +396,49 @@ class MultiHeadAttention(nn.Module):
                     f"num_heads ({cfg.num_heads})"
                 )
             q = nn.DenseGeneral(
-                (cfg.num_heads, head_dim), dtype=cfg.dtype, name="q"
+                (cfg.num_heads, head_dim), dtype=cfg.dtype,
+                use_bias=cfg.use_bias, name="q",
             )(x)
             kv = nn.DenseGeneral(
                 (2, cfg.num_kv_heads, head_dim), dtype=cfg.dtype,
-                name="kv",
+                use_bias=cfg.use_bias, name="kv",
             )(x)
             k, v = kv[..., 0, :, :], kv[..., 1, :, :]
         else:
             qkv = nn.DenseGeneral(
-                (3, cfg.num_heads, head_dim), dtype=cfg.dtype, name="qkv"
+                (3, cfg.num_heads, head_dim), dtype=cfg.dtype,
+                use_bias=cfg.use_bias, name="qkv",
             )(x)
             q, k, v = (
                 qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
             )
-        if cfg.rope:
+        if cfg.qk_norm:
+            q = _norm(cfg, name="q_norm")(q).astype(cfg.dtype)
+            k = _norm(cfg, name="k_norm")(k).astype(cfg.dtype)
+        if rope:
             rope_offset = 0 if cache is None else cache_index
             q = apply_rope(q, cfg.rope_base, offset=rope_offset)
             k = apply_rope(k, cfg.rope_base, offset=rope_offset)
+
+        def project(out):
+            """W_o on the heads' output, gated where the model says."""
+            if cfg.attn_output_gate:
+                gate = nn.DenseGeneral(
+                    (cfg.num_heads, head_dim), dtype=cfg.dtype,
+                    use_bias=False, name="gate",
+                )(x)
+                out = (
+                    out * jax.nn.sigmoid(gate.astype(jnp.float32))
+                ).astype(cfg.dtype)
+            return nn.DenseGeneral(
+                cfg.d_model, axis=(-2, -1), dtype=cfg.dtype,
+                use_bias=cfg.use_bias, name="out",
+            )(out)
+
         if cache is not None:
             return self._cached_attention(cfg, x, q, k, v, cache,
-                                          cache_index, head_dim,
-                                          pages=pages,
+                                          cache_index, head_dim, project,
+                                          window, pages=pages,
                                           paged_attn=paged_attn)
         # lengths (right-padding) stays on the flash path — the kernels
         # take it natively; only ARBITRARY masks force dense.
@@ -337,11 +463,9 @@ class MultiHeadAttention(nn.Module):
             out = flash_attention(
                 q, k, v, causal=cfg.causal,
                 block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-                lengths=lengths, window=cfg.sliding_window,
+                lengths=lengths, window=window,
             )
-            return nn.DenseGeneral(
-                cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, name="out"
-            )(out)
+            return project(out)
         if cfg.num_kv_heads and cfg.num_kv_heads != cfg.num_heads:
             # dense fallback materializes the head repeat the flash
             # path avoids
@@ -355,14 +479,12 @@ class MultiHeadAttention(nn.Module):
         if cfg.causal:
             t = x.shape[1]
             causal_mask = jnp.tril(jnp.ones((t, t), bool))
-            if cfg.sliding_window:
+            if window:
                 rows = jnp.arange(t)[:, None]
                 cols = jnp.arange(t)[None, :]
-                causal_mask = causal_mask & (
-                    rows - cols < cfg.sliding_window
-                )
+                causal_mask = causal_mask & (rows - cols < window)
             scores = jnp.where(causal_mask[None, None], scores, -1e30)
-        elif cfg.sliding_window:
+        elif window:
             raise ValueError("sliding_window requires causal=True")
         valid = None
         if lengths is not None:
@@ -382,12 +504,11 @@ class MultiHeadAttention(nn.Module):
         if valid is not None:
             # match the flash path: padded query rows are zero
             out = jnp.where(valid[:, :, None, None], out, 0.0)
-        return nn.DenseGeneral(
-            cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, name="out"
-        )(out)
+        return project(out)
 
     def _cached_attention(self, cfg, x, q, k, v, cache, cache_index,
-                          head_dim, pages=None, paged_attn=False):
+                          head_dim, project, window, pages=None,
+                          paged_attn=False):
         """Incremental-decode attention: write this call's k/v into the
         per-slot cache at ``cache_index`` (each batch row at its own
         position — prefill passes t=prompt tokens at index 0, decode
@@ -473,7 +594,7 @@ class MultiHeadAttention(nn.Module):
             reason = _pa.unsupported_reason(
                 head_dim, page_tokens, queries=t * r
             )
-            if reason is None and cfg.sliding_window:
+            if reason is None and window:
                 reason = (
                     "sliding_window is not implemented by the paged "
                     "kernel"
@@ -482,10 +603,7 @@ class MultiHeadAttention(nn.Module):
                 out = _pa.paged_attention(
                     q, k_cache, v_cache, pages, idx, causal=True
                 )
-                return nn.DenseGeneral(
-                    cfg.d_model, axis=(-2, -1), dtype=cfg.dtype,
-                    name="out",
-                )(out), new_cache
+                return project(out), new_cache
             # loud fallback ladder: requested the kernel, geometry (or
             # backend) can't take it — warn at trace time, count it,
             # and ride the gather oracle below
@@ -520,21 +638,20 @@ class MultiHeadAttention(nn.Module):
         q_pos = idx[:, None] + jnp.arange(t)          # [b, t] global
         key_pos = jnp.arange(seq)                     # [seq]
         valid = key_pos[None, None, :] <= q_pos[:, :, None]  # [b, t, seq]
-        if cfg.sliding_window:
+        if window:
             valid = valid & (
-                q_pos[:, :, None] - key_pos[None, None, :]
-                < cfg.sliding_window
+                q_pos[:, :, None] - key_pos[None, None, :] < window
             )
         scores = jnp.where(valid[:, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-        return nn.DenseGeneral(
-            cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, name="out"
-        )(out), new_cache
+        return project(out), new_cache
 
 
 class MoEFFN(nn.Module):
-    """Switch-style top-1 MoE FFN for decode/serving: router logits in
+    """The serving engine's top-1 expert bank (``cfg.moe_experts``; the
+    training path's expert layer, top-k and share-holding, is
+    :class:`ExpertFFN`). Switch-style top-1 MoE FFN: router logits in
     fp32, argmax routing (pure DATA — shapes never depend on it), and
     the expert bank applied through dense one-hot einsums over the
     leading ``[E]`` axis (MXU-friendly, no gather/scatter; at decode
@@ -577,32 +694,133 @@ class MoEFFN(nn.Module):
         return (y * gate).astype(cfg.dtype)
 
 
+class GatedMLP(nn.Module):
+    """``W_down(silu(W_gate x) * W_up x)`` at ``width``."""
+
+    cfg: TransformerConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+
+        def dense(features, name):
+            return nn.Dense(features, dtype=cfg.dtype,
+                            use_bias=cfg.use_bias, name=name)
+
+        h = nn.silu(dense(self.width, "gate")(x)) * dense(self.width, "up")(x)
+        return dense(cfg.d_model, "down")(h)
+
+
+class ExpertFFN(nn.Module):
+    """An expert layer that is told which experts of the deployment it
+    holds (``cfg.moe_experts_held``): the router scores all
+    ``cfg.moe_experts_total`` experts in float32 and picks ``moe_top_k`` a
+    token on score + ``select_bias``; the gates are the unbiased scores,
+    renormalised over all k chosen (held here or not) and scaled; the
+    layer returns ``shared(x) + sum over the chosen experts held here of
+    gate_e * expert_e(x)``. Dropless with static shapes: the chosen
+    (token, expert) pairs are sorted by held expert and pass a grouped
+    matmul whose work follows the rows really routed here
+    (parallel/moe.py: :func:`route_top_k`, :func:`held_experts_ffn`).
+    What experts held elsewhere would add is left out: their exchange is
+    parallel/moe.py's, and no code stands in for it here.
+
+    ``select_bias`` is the load-balancing bias: a leaf of the tree that
+    only selection reads, so its gradient is zero; its balancing update
+    lies outside the gradient step."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..parallel import moe as _moe
+
+        cfg = self.cfg
+        total, d, f = cfg.moe_experts_total, cfg.d_model, cfg.moe_d_ff
+        first, last = cfg.moe_experts_held or (0, total)
+        if not 0 <= first < last <= total:
+            raise ValueError(
+                f"moe_experts_held {cfg.moe_experts_held} is no range of "
+                f"the {total} experts"
+            )
+        tokens = x.reshape(-1, d)
+        with jax.named_scope("moe_route"):
+            logits = nn.Dense(
+                total, dtype=jnp.float32, use_bias=False,
+                precision="highest", name="router",
+            )(tokens.astype(jnp.float32))
+            select_bias = self.param(
+                "select_bias", nn.initializers.zeros, (total,), jnp.float32
+            )
+            chosen, gates = _moe.route_top_k(
+                logits, select_bias, cfg.moe_top_k, score=cfg.moe_score,
+                norm=cfg.moe_route_norm, scale=cfg.moe_route_scale,
+            )
+        self.sow("intermediates", "chosen", chosen)
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
+        held = last - first
+        w_gate, w_up, w_down = (
+            self.param(name, init, shape, jnp.float32).astype(cfg.dtype)
+            for name, shape in (
+                ("w_gate", (held, d, f)), ("w_up", (held, d, f)),
+                ("w_down", (held, f, d)),
+            )
+        )
+        tokens = tokens.astype(cfg.dtype)
+        y = _moe.held_experts_ffn(
+            tokens, chosen, gates, w_gate, w_up, w_down, first
+        )
+        if cfg.moe_shared_d_ff:
+            with jax.named_scope("moe_shared"):
+                y = y + GatedMLP(cfg, cfg.moe_shared_d_ff, name="shared")(
+                    tokens
+                )
+        return y.reshape(x.shape).astype(cfg.dtype)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
+    # this layer's entry of cfg.layer_kinds; None = the model-wide block
+    layer: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, mask=None, train: bool = True, lengths=None,
                  cache=None, cache_index=None, pages=None,
                  paged_attn=False):
         cfg = self.cfg
-        h = nn.LayerNorm(dtype=jnp.float32)(x)
+        attn_kind, ffn_kind = (
+            (None, None) if self.layer is None else cfg.layer_kind(self.layer)
+        )
+        h = _norm(cfg)(x)
         new_cache = None
+        attention = MultiHeadAttention(cfg, kind=attn_kind)
         if cache is None:
-            h = MultiHeadAttention(cfg)(h, mask, lengths)
+            h = attention(h, mask, lengths)
         else:
-            h, new_cache = MultiHeadAttention(cfg)(
+            h, new_cache = attention(
                 h, mask, lengths, cache=cache, cache_index=cache_index,
                 pages=pages, paged_attn=paged_attn,
             )
+        if cfg.sandwich_norm:
+            h = _norm(cfg)(h).astype(cfg.dtype)
         h = nn.Dropout(cfg.dropout_rate, deterministic=not train)(h)
         x = x + h
-        h = nn.LayerNorm(dtype=jnp.float32)(x)
-        if cfg.moe_experts:
+        h = _norm(cfg)(x)
+        if ffn_kind == "experts":
+            h = ExpertFFN(cfg, name="moe")(h)
+        elif cfg.moe_experts:
             h = MoEFFN(cfg, name="moe")(h)
+        elif cfg.ffn_gated:
+            h = GatedMLP(cfg, cfg.d_ff, name="mlp")(h)
         else:
-            h = nn.Dense(cfg.d_ff, dtype=cfg.dtype)(h)
+            h = nn.Dense(cfg.d_ff, dtype=cfg.dtype, use_bias=cfg.use_bias)(h)
             h = nn.gelu(h)
-            h = nn.Dense(cfg.d_model, dtype=cfg.dtype)(h)
+            h = nn.Dense(
+                cfg.d_model, dtype=cfg.dtype, use_bias=cfg.use_bias
+            )(h)
+        if cfg.sandwich_norm:
+            h = _norm(cfg)(h).astype(cfg.dtype)
         h = nn.Dropout(cfg.dropout_rate, deterministic=not train)(h)
         if cache is None:
             return x + h
@@ -669,7 +887,7 @@ class LMHead(nn.Module):
         )
         bias = self.param(
             "bias", nn.initializers.zeros, (cfg.vocab_size,), jnp.float32
-        )
+        ) if cfg.use_bias else None
         if cfg.head_mixed_precision:
             y = jax.lax.dot_general(
                 x.astype(cfg.dtype),
@@ -679,7 +897,7 @@ class LMHead(nn.Module):
             )
         else:
             y = x.astype(jnp.float32) @ kernel
-        return y + bias
+        return y if bias is None else y + bias
 
 
 # The largest share of the device's memory that remat's saved matmul and
@@ -696,8 +914,9 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     memory of ``bytes_limit`` bytes (None: not known).
 
     ``save_matmuls``: each block keeps the outputs of its weight matmuls
-    (qkv, the attention's output projection, the first feed-forward
-    matmul) and of the flash forward (the attention output and one lane
+    (qkv, the output gate's where the model has one, the attention's
+    output projection, the first feed-forward matmul, or the gate and up
+    matmuls of a gated one) and of the flash forward (the attention output and one lane
     of ``lse``), and recomputes only the element-wise work; on the
     flash path q, k and v are kept as the kernels take them, in place of
     the projection's output, so the head transposes are not repeated
@@ -706,16 +925,26 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     it). Taken when that is at most ``REMAT_SAVE_SHARE`` of the
     device. ``recompute_all``: each block keeps its input alone —
     where the saving would not fit, where the limit cannot be read (CPU)
-    and for MoE blocks, whose expert einsums this reckoning does not
-    cover. ``off``: ``cfg.remat`` is not set."""
+    and for any model with expert layers (``moe_experts``, or "experts"
+    among ``layer_kinds``): an expert layer's grouped matmuls are kernel
+    calls on ``tokens x moe_top_k`` rows, which the policy neither sees
+    nor should keep (2 x 8192 tokens, top-8, 2048 wide: 512 MiB a
+    tensor), and this reckoning counts only ``tokens``, not the
+    parameters' own share of the device, which is what is short where
+    experts are held. So the whole model recomputes, its dense and
+    attention parts too. ``off``: ``cfg.remat`` is not set."""
     if not cfg.remat:
         return "off", 0
-    if cfg.moe_experts or not bytes_limit:
+    if cfg.has_experts() or not bytes_limit:
         return "recompute_all", 0
-    head_dim = cfg.d_model // cfg.num_heads
-    kv_width = 2 * (cfg.num_kv_heads or cfg.num_heads) * head_dim
-    # q + kv, attention output, output projection, first feed-forward
-    width = 3 * cfg.d_model + kv_width + cfg.d_ff
+    q_width = cfg.num_heads * cfg.dim_per_head()
+    kv_width = 2 * (cfg.num_kv_heads or cfg.num_heads) * cfg.dim_per_head()
+    # q + kv, attention output (and its gate), output projection, first
+    # feed-forward (gate and up of a gated one)
+    width = (
+        q_width * (3 if cfg.attn_output_gate else 2) + kv_width
+        + cfg.d_model + cfg.d_ff * (2 if cfg.ffn_gated else 1)
+    )
     per_token = width * jnp.dtype(cfg.dtype).itemsize + 4 * cfg.num_heads
     saved = cfg.num_layers * int(tokens) * per_token
     if saved > REMAT_SAVE_SHARE * bytes_limit:
@@ -767,6 +996,20 @@ def _span_at_trace_time(call):
     return wrapped
 
 
+def _tag_layer_kinds(span, cfg: TransformerConfig, tokens: int):
+    """What the per-layer kinds make of the model, on the trace span."""
+    first, last = cfg.moe_experts_held or (0, cfg.moe_experts_total)
+    span.tag(
+        layer_kinds=",".join(cfg.layer_kinds),
+        experts_total=cfg.moe_experts_total,
+        experts_held=last - first,
+        top_k=cfg.moe_top_k,
+        # rows of an expert layer's sorted buffer: every choice of every
+        # token can land on this chip
+        moe_rows_capacity=tokens * cfg.moe_top_k,
+    )
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
 
@@ -779,6 +1022,12 @@ class Transformer(nn.Module):
     ):
         cfg = self.cfg
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype)(tokens)
+        if cfg.embed_scale != 1.0:
+            x = x * cfg.embed_scale
+        layers = [
+            None if cfg.layer_kinds is None else i
+            for i in range(cfg.num_layers)
+        ]
         if not cfg.rope:
             if cache is None:
                 positions = jnp.arange(tokens.shape[1])[None]
@@ -806,13 +1055,13 @@ class Transformer(nn.Module):
                 raise ValueError("return_hidden with cache= is not supported")
             new_cache = []
             for i in range(cfg.num_layers):
-                x, layer_cache = Block(cfg, name=f"block_{i}")(
+                x, layer_cache = Block(cfg, layers[i], name=f"block_{i}")(
                     x, mask, train, lengths,
                     cache=cache[i], cache_index=cache_index,
                     pages=pages, paged_attn=paged_attn,
                 )
                 new_cache.append(layer_cache)
-            x = nn.LayerNorm(dtype=jnp.float32)(x)
+            x = _norm(cfg)(x)
             return LMHead(cfg, name="lm_head")(x), new_cache
         block = Block
         mode, saved_bytes = remat_plan(
@@ -827,9 +1076,15 @@ class Transformer(nn.Module):
         span = _tracing.current()
         if span is not None and span.name == _TRACE_MODEL_SPAN:
             span.tag(remat=mode, remat_saved_bytes=saved_bytes)
+            if cfg.layer_kinds is not None:
+                _tag_layer_kinds(
+                    span, cfg, tokens.shape[0] * tokens.shape[1]
+                )
         for i in range(cfg.num_layers):
-            x = block(cfg, name=f"block_{i}")(x, mask, train, lengths)
-        x = nn.LayerNorm(dtype=jnp.float32)(x)
+            x = block(cfg, layers[i], name=f"block_{i}")(
+                x, mask, train, lengths
+            )
+        x = _norm(cfg)(x)
         if return_hidden:
             # pre-head activations for the chunked fused loss
             # (ops/fused_xent.py): callers apply the lm_head params

@@ -396,12 +396,15 @@ def supports_seq(
     return ok(_pick_block(t, block_q)) and ok(_pick_block(t, block_k))
 
 
-# A guess made before the chip could be asked, and conservative: on the
-# v5e (scripts/chip_roster.py --vmem-sweep, d=128 bf16, PR 21) the dK/dV
-# kernel compiled and ran at every shape up to an ESTIMATE of 48.8 MiB
-# (r=8 t=8192, r=4 t=16384) and ran out of VMEM only at 97 MiB (r=8
-# t=16384). The gate stays here until a sweep at head_dim 64 too (lane
-# padding doubles the real footprint there) replaces it — ROADMAP A6.
+# What the dK/dV kernel may stage whole-sequence (:func:`bwd_vmem_bytes`):
+# up to it the kernel fetches the kv row's whole q-head group once and
+# loops over it in place; past it the q group is staged block by block
+# within the band (:func:`_dkv_blocked`), so the footprint follows block
+# and window and no shape is sent to the dense path for VMEM. A guess
+# made before the chip could be asked, and conservative: on the v5e
+# (scripts/chip_roster.py --vmem-sweep, d=128 bf16, PR 21) the
+# whole-sequence kernel compiled and ran up to an ESTIMATE of 48.8 MiB
+# and ran out of VMEM at 97 MiB.
 _VMEM_BUDGET_DEFAULT = 12 * 2**20
 
 
@@ -442,13 +445,12 @@ def fits_vmem(
     itemsize: int = 2,
     block_k: int = None,
 ) -> bool:
-    """Whether the backward kernels' per-program staging fits the
+    """Whether the dK/dV kernel's whole-sequence staging fits the
     per-core VMEM budget (HOROVOD_FLASH_VMEM_BUDGET bytes, default
-    12 MiB). TransformerConfig.uses_flash and the
-    ulysses/ring auto-gates fall back to the dense engines when this
-    fails; direct ``flash_attention``/``ring_flash_attention`` callers
-    get a warning rather than an error (forward-only use stages ~3x
-    less and may still compile)."""
+    12 MiB). Where it does not, ``flash_attention`` stages the q group
+    by block (:func:`_dkv_blocked`); the ulysses/ring auto-gates, whose
+    hop engines have no blocked variant, fall back to their dense
+    engines, and ``ring_flash_attention`` warns."""
     return (
         bwd_vmem_bytes(seq, d, h_per_kv, itemsize, block_k)
         <= _vmem_budget()
@@ -659,6 +661,13 @@ def _flash_bwd_impl(
         interpret=_interpret(),
         name="flash_dq",
     )(*dq_operands)
+    if not fits_vmem(seq, d, r, q.dtype.itemsize, block_k):
+        dk, dv = _dkv_blocked(
+            q, k, v, do, o, lse, lens[::r] if padded else None,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            group=r, window=window,
+        )
+        return dq, dk, dv
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal,
@@ -791,10 +800,13 @@ def flash_attention(
     ``window`` (int, requires ``causal=True``): Mistral-style causal
     sliding window — row r attends cols in (r-window, r], masked
     in-kernel with the block loops clamped to the band on both sides,
-    so COMPUTE scales with the window. K/V are still staged
-    whole-sequence per program (the BlockSpecs fetch (1, seq, d)), so
-    HBM->VMEM traffic and VMEM footprint remain O(seq) — at extreme
-    sequence lengths use ring attention for the memory win. Composes
+    so COMPUTE scales with the window. The forward and dQ kernels
+    still stage K/V whole-sequence per program (the BlockSpecs fetch
+    (1, seq, d): 2 MiB a tensor at seq 8192, d 128, bf16), so their
+    VMEM footprint remains O(seq) — at extreme sequence lengths use
+    ring attention for the memory win. The dK/dV kernel stages its q
+    group whole-sequence where that fits the budget (:func:`fits_vmem`)
+    and block by block within the band where it does not. Composes
     with lengths and GQA."""
     b, t, h, d = q.shape
     if window is not None:
@@ -812,8 +824,6 @@ def flash_attention(
             f"k={k.shape[2]}, v={v.shape[2]}"
         )
     h_per_kv = h // kv_h
-    if not fits_vmem(t, d, h_per_kv, q.dtype.itemsize, block_k):
-        _warn_vmem(t, d, h_per_kv, q.dtype.itemsize, block_k)
     block_q = _pick_block(t, block_q)
     block_k = _pick_block(t, block_k)
 
@@ -860,3 +870,164 @@ def flash_attention(
     # the backward kernels.
     valid = jnp.arange(t)[None, :] < lens[:, None]  # [b, t]
     return jnp.where(valid[..., None, None], out, 0.0)
+
+
+# dK/dV with the q group staged block by block (ROADMAP A9). The
+# whole-sequence kernel above fetches (group, seq, d) blocks of q, do and
+# o: 48 MiB at group 8, seq 8192, d 128. Here the grid gains an innermost
+# "arbitrary" dimension over (group member, q block within the K block's
+# band); each step fetches one (block_q, d) block of q, do, o and lse and
+# adds its part to dk/dv held in VMEM scratch, so the footprint follows
+# block_q and block_k alone. Steps past a K block's band (the causal
+# mask's short rows, a window's end) compute nothing and fetch nothing
+# new: their index clamps to the last block that was fetched.
+
+
+def _dkv_q_range(ki, block_q, block_k, n_q, causal, window):
+    """``[first, last)`` q blocks that see K block ``ki`` (before any
+    length bound): the same bounds as :func:`_dkv_kernel`'s loop."""
+    first = ki * block_k // block_q if causal else 0
+    last = n_q
+    if window is not None:
+        last = jnp.minimum(
+            n_q, ((ki + 1) * block_k - 1 + window - 1) // block_q + 1
+        )
+    return first, last
+
+
+def _dkv_band_blocks(seq, block_q, block_k, causal, window):
+    """The most q blocks any K block's band holds (static)."""
+    n_q = seq // block_q
+    if window is None or not causal:
+        return n_q
+    first_row = lambda ki: (ki * block_k // block_q) * block_q
+    span = max(
+        min(seq, (ki + 1) * block_k - 1 + window) - first_row(ki)
+        for ki in range(seq // block_k)
+    )
+    return min(n_q, -(-span // block_q))
+
+
+def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                        *rest, scale, causal, block_q, block_k, padded,
+                        steps, n_q, window):
+    if padded:
+        len_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
+        kv_len = len_ref[pl.program_id(0)]
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
+        kv_len = None
+    ki = pl.program_id(1)
+    t = pl.program_id(2)
+    first, last = _dkv_q_range(ki, block_q, block_k, n_q, causal, window)
+    if padded:
+        last = _length_bound(kv_len, block_q, last)
+    i = first + t % steps
+
+    @pl.when(t == 0)
+    def _zero():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(i < last)
+    def _accumulate():
+        k = k_ref[0].astype(jnp.float32)  # [BK, D]
+        v = v_ref[0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)  # [BQ, D]
+        do = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0][:, 0:1]
+        delta = jnp.sum(
+            do * o_ref[0].astype(jnp.float32), axis=-1, keepdims=True
+        )
+        s = scale * jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [BQ, BK]
+        if causal:
+            s = _apply_causal_mask(s, i, ki, block_q, block_k)
+        if padded:
+            s = _apply_length_mask(s, ki, block_k, kv_len)
+        if window is not None:
+            s = _apply_window_mask(s, i, ki, block_q, block_k, window)
+        p = jnp.exp(s - lse)
+        if padded:
+            rows = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, p.shape, 0
+            )
+            p = jnp.where(rows < kv_len, p, 0.0)
+        dv_acc[...] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta)
+        dk_acc[...] += scale * jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _store():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
+                 block_k, group, window):
+    """``(dk, dv)`` through :func:`_dkv_kernel_blocked`; ``lens`` is per
+    kv row or None. Same operands and results as the whole-sequence
+    call in :func:`_flash_bwd_impl`."""
+    bh, seq, d = q.shape
+    lanes = lse.shape[-1]
+    n_q, n_k = seq // block_q, seq // block_k
+    steps = _dkv_band_blocks(seq, block_q, block_k, causal, window)
+    r = group
+
+    def q_block(b, ki, t):
+        first, _ = _dkv_q_range(ki, block_q, block_k, n_q, causal, window)
+        return b * r + t // steps, jnp.minimum(first + t % steps, n_q - 1), 0
+
+    def kv_block(b, ki, t):
+        return b, ki, 0
+
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), q_block),
+        pl.BlockSpec((1, block_k, d), kv_block),
+        pl.BlockSpec((1, block_k, d), kv_block),
+        pl.BlockSpec((1, block_q, d), q_block),
+        pl.BlockSpec((1, block_q, d), q_block),
+        pl.BlockSpec((1, block_q, lanes), q_block),
+    ]
+    operands = [q, k, v, do, o, lse]
+    if lens is not None:
+        in_specs.append(_lens_spec())
+        operands.append(lens)
+    return pl.pallas_call(
+        functools.partial(
+            _dkv_kernel_blocked, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, padded=lens is not None,
+            steps=steps, n_q=n_q, window=window,
+        ),
+        grid=(bh // r, n_k, r * steps),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=_interpret(),
+        name="flash_dkv",
+    )(*operands)

@@ -390,3 +390,149 @@ def moe_ffn(
         total=lax.psum(jnp.sum(routed), axis_name),
     )
     return out, stats
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing and one chip's held experts (models.transformer.ExpertFFN).
+# The routing is the one implementation: an exchange across the 'ep' axis
+# sends the same sorted rows to their experts' chips and calls the same
+# grouped matmuls there. On one chip there is no exchange, and nothing
+# stands in for it: the experts held elsewhere add nothing.
+# ---------------------------------------------------------------------------
+
+
+def route_top_k(logits, select_bias, top_k: int, *, score: str = "sigmoid",
+                norm: bool = True, scale: float = 1.0):
+    """``(chosen [tokens, k] int32, gates [tokens, k] float32)`` from the
+    router's float32 ``logits [tokens, experts]``. The scores are
+    ``sigmoid`` or ``softmax`` of the logits; the k experts are chosen on
+    score + ``select_bias`` (the load-balancing bias, which selection
+    alone reads: no gradient reaches it); the gates are the unbiased
+    scores of the chosen, divided by their sum where ``norm``, times
+    ``scale``."""
+    logits = logits.astype(jnp.float32)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"score {score!r} is not sigmoid or softmax")
+    _, chosen = lax.top_k(scores + lax.stop_gradient(select_bias), top_k)
+    gates = jnp.take_along_axis(scores, chosen, axis=-1)
+    if norm:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), gates * scale
+
+
+def _gmm_tiling(rows: int, k: int, n: int):
+    """Tile sizes of the grouped matmul: the row tile must divide the
+    rows; 512 x 1024 x 1024 stages ~10 MiB of VMEM in bfloat16."""
+    tm = 512
+    while rows % tm:
+        tm //= 2
+    if tm < 8 and jax.default_backend() == "tpu":
+        raise ValueError(
+            f"{rows} routed rows tile no 8-aligned block: tokens x top_k "
+            "must be a multiple of 8"
+        )
+    return tm, min(k, 1024), min(n, 1024)
+
+
+def _grouped_matmul(rows, weights, group_sizes):
+    """``rows [m, k]`` sorted by group times ``weights [groups, k, n]``,
+    the first ``group_sizes[g]`` rows with group 0's matrix and so on
+    (Pallas megablox: only tiles that hold a group's rows are visited,
+    so the work follows ``sum(group_sizes)``, not m). Rows past the last
+    group are NOT written: the caller masks them."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
+
+    m, k = rows.shape
+    return _megablox.gmm(
+        rows, weights, group_sizes, rows.dtype,
+        _gmm_tiling(m, k, weights.shape[-1]), None, None, False,
+        jax.default_backend() != "tpu",
+    )
+
+
+@jax.custom_vjp
+def _rows_to_sorted(x, order, inverse, here):
+    """``x [tokens, d]`` -> the (token, choice) pairs' rows in sorted order
+    ``[tokens * k, d]``. A gather; its transpose is a gather too, by the
+    inverse permutation, and it reads no row of a pair that is not held
+    here (such rows of the cotangent were never written)."""
+    return x[order // here.shape[1]]
+
+
+def _rows_to_sorted_fwd(x, order, inverse, here):
+    return _rows_to_sorted(x, order, inverse, here), (inverse, here)
+
+
+def _rows_to_sorted_bwd(res, g):
+    inverse, here = res
+    pairs = g[inverse].reshape(*here.shape, g.shape[-1])
+    dx = jnp.sum(
+        jnp.where(here[..., None], pairs, 0).astype(jnp.float32), axis=1
+    )
+    return dx.astype(g.dtype), None, None, None
+
+
+_rows_to_sorted.defvjp(_rows_to_sorted_fwd, _rows_to_sorted_bwd)
+
+
+@jax.custom_vjp
+def _sorted_to_pairs(ys, order, inverse, here):
+    """Sorted rows ``[tokens * k, d]`` -> ``[tokens, k, d]`` by (token,
+    choice), zero for a pair that is not held here."""
+    pairs = ys[inverse].reshape(*here.shape, ys.shape[-1])
+    return jnp.where(here[..., None], pairs, 0)
+
+
+def _sorted_to_pairs_fwd(ys, order, inverse, here):
+    return _sorted_to_pairs(ys, order, inverse, here), (order, here)
+
+
+def _sorted_to_pairs_bwd(res, g):
+    order, here = res
+    g = jnp.where(here[..., None], g, 0).reshape(-1, g.shape[-1])
+    return g[order], None, None, None
+
+
+_sorted_to_pairs.defvjp(_sorted_to_pairs_fwd, _sorted_to_pairs_bwd)
+
+
+def held_experts_ffn(x, chosen, gates, w_gate, w_up, w_down, first_held: int):
+    """The part of an expert layer's result that the experts held here
+    give: ``sum over a token's chosen experts e in [first_held, first_held
+    + held) of gates_e * W_down[e](silu(W_gate[e] x) * W_up[e] x)``, for
+    ``x [tokens, d]``, ``chosen``/``gates [tokens, k]`` and weights
+    ``[held, d, f]``, ``[held, d, f]``, ``[held, f, d]``.
+
+    Dropless with static shapes: all ``tokens * k`` pairs are sorted by
+    held expert (pairs whose expert is held elsewhere last), so every
+    choice of every token may land here; the grouped matmuls visit only
+    the rows of the held experts' groups, so the work follows the rows
+    really routed here."""
+    tokens, k = chosen.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        local = chosen - first_held
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0,
+            dtype=jnp.int32,
+        )
+        rows = _rows_to_sorted(x, order, inverse, here)
+    with jax.named_scope("moe_experts"):
+        gate = _grouped_matmul(rows, w_gate, group_sizes)
+        up = _grouped_matmul(rows, w_up, group_sizes)
+    hidden = jax.nn.silu(gate) * up
+    with jax.named_scope("moe_experts"):
+        out = _grouped_matmul(hidden, w_down, group_sizes)
+    with jax.named_scope("moe_combine"):
+        pairs = _sorted_to_pairs(out, order, inverse, here)
+        return jnp.sum(
+            pairs.astype(jnp.float32) * gates[..., None], axis=1
+        ).astype(x.dtype)

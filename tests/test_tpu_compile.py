@@ -1,0 +1,81 @@
+"""The kernels of the 8k-token expert model compiled at their real widths for
+a described TPU v5e (no chip: the TPU's compiler is installed here and
+compiles for a chip that is described and not attached). What interpret
+mode cannot show: a block that Mosaic refuses, more VMEM than a kernel may
+use. Nothing runs, so this says nothing about results or times.
+
+The topology is described inside a fixture, in this one file: only one
+process at a time may load the TPU's library, and it keeps it until it
+exits."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.parallel import moe
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """The code asks the backend whether to interpret its kernels; this
+    is a compile for the TPU."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compiled(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_kernels_compile_at_b2_s8192_gqa8_head128(
+        window, one_chip, as_on_the_chip):
+    shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                              sharding=one_chip)
+    q, kv = shape((2, 8192, 32, 128)), shape((2, 8192, 4, 128))
+    assert not fa.fits_vmem(8192, 128, 8, 2, 512)  # dK/dV stages by block
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32))
+
+    # forward, dQ, dK/dV
+    assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
+
+
+def test_held_experts_compile_at_16384_tokens_top8_16_of_128(
+        one_chip, as_on_the_chip):
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tokens, d, f, held = 16384, 2048, 1024, 16
+
+    def loss(x, gates, w_gate, w_up, w_down, chosen):
+        return jnp.sum(moe.held_experts_ffn(
+            x, chosen, gates, w_gate, w_up, w_down, 0).astype(jnp.float32))
+
+    calls = _compiled(
+        jax.grad(loss, (0, 1, 2, 3, 4)), shape((tokens, d)),
+        shape((tokens, 8), jnp.float32), shape((held, d, f)),
+        shape((held, d, f)), shape((held, f, d)),
+        shape((tokens, 8), jnp.int32))
+    # three grouped matmuls forward; for each, one for its rows' and one
+    # for its weights' gradient
+    assert calls == 9
