@@ -6,9 +6,10 @@ One configurable implementation: ``causal=True`` → GPT-2-style decoder;
 fp32 layernorm/softmax accumulation, static shapes, head dims sized for
 the MXU (multiples of 128 at real scale). `remat` trades the blocks'
 activations for recomputation in the backward; how much it keeps is
-decided from the shapes and the device's memory (:func:`remat_plan`):
-the matmul and flash-kernel outputs where they fit a quarter of the
-device, else only each block's input.
+decided from the shapes and the device's memory (:func:`remat_plan`),
+the richest that fits a share of what the parameters' state leaves of
+the device: the matmul and flash-kernel outputs, else what the flash
+kernels' backward reads, else only each block's input.
 
 The same ``Block`` also builds the sparse long-context decoders
 (``layer_kinds``): per layer a window or a full attention, with or
@@ -196,10 +197,9 @@ class TransformerConfig:
             self.rope and not kind.endswith("-nope"),
         )
 
-    def has_experts(self) -> bool:
-        return bool(self.moe_experts) or any(
-            k.endswith("/experts") for k in self.layer_kinds or ()
-        )
+    def expert_layers(self) -> int:
+        """How many layers' feed-forward is an expert layer."""
+        return sum(k.endswith("/experts") for k in self.layer_kinds or ())
 
     def wants_flash(self) -> bool:
         """The configuration half of the flash gate: ``True``/``False``
@@ -900,56 +900,156 @@ class LMHead(nn.Module):
         return y if bias is None else y + bias
 
 
-# The largest share of the device's memory that remat's saved matmul and
-# kernel outputs may take (PERF.md section 6, PR 26, has the two chip
-# readings it rests on): past it the blocks recompute everything, as a
-# user who set ``remat`` because memory is short expects.
-REMAT_SAVE_SHARE = 0.25
+# The rungs of remat_plan(), richest first, each with the largest share
+# of what the state leaves of the device's memory that its kept bytes may
+# take; past it a user who set ``remat`` because memory is short gets the
+# next rung down. Each is the largest share measured to run, rounded up
+# (PERF.md section 6). ``save_matmuls``: GPT-2 medium at 18 x 512 tokens
+# keeps 4.09 GB of the 12.03 GB its state leaves, 34.0%, at a peak of
+# 12.31 of 15.74 GiB (PR 26). ``save_attention``: Trinity-Mini's five
+# layers at 2 x 8192 keep 1.53 GB of the 8.44 GB left, 18.1% (PR 28).
+# The poorer rung's share is the smaller because it keeps fewer bytes a
+# token: at one share the step would hold more tokens, and the step's
+# other temporaries grow with the tokens (0.26-0.30 MB a token in both
+# models) and need what the share leaves.
+REMAT_SAVE_SHARE = {"save_matmuls": 0.35, "save_attention": 0.2}
+# What training holds for each parameter, reckoned: float32 weight,
+# gradient and one optimizer moment. (The 705.5M parameters of PR 27's
+# cell measured 5.644 GB of weights and momentum as the step's
+# arguments, 8 bytes each, before the gradients.)
+REMAT_STATE_BYTES_PER_PARAM = 12
+
+
+def _param_count(cfg: TransformerConfig) -> int:
+    """The parameters ``Transformer(cfg)`` creates on this chip, from the
+    shapes alone: an expert layer counts the experts it holds, not the
+    deployment's."""
+    d, head_dim = cfg.d_model, cfg.dim_per_head()
+    bias = int(cfg.use_bias)
+    per_norm = 2 if cfg.norm == "layernorm" else 1  # scale (and bias)
+    q_width = cfg.num_heads * head_dim
+    kv_width = 2 * (cfg.num_kv_heads or cfg.num_heads) * head_dim
+
+    def gated(width):
+        return 3 * d * width + bias * (2 * width + d)
+
+    attention = (
+        (d + bias) * (q_width + kv_width)
+        + (d * q_width if cfg.attn_output_gate else 0)
+        + q_width * d + bias * d
+        + (2 * per_norm * head_dim if cfg.qk_norm else 0)
+    )
+    if cfg.moe_experts:  # MoEFFN: router and bank, biases always
+        dense = cfg.moe_experts * (d + 1 + 2 * d * cfg.d_ff + cfg.d_ff + d)
+    elif cfg.ffn_gated:
+        dense = gated(cfg.d_ff)
+    else:
+        dense = 2 * d * cfg.d_ff + bias * (cfg.d_ff + d)
+    first, last = cfg.moe_experts_held or (0, cfg.moe_experts_total)
+    experts = (
+        (d + 1) * cfg.moe_experts_total  # router and select_bias
+        + (last - first) * 3 * d * cfg.moe_d_ff
+        + (gated(cfg.moe_shared_d_ff) if cfg.moe_shared_d_ff else 0)
+    )
+    norms = (4 if cfg.sandwich_norm else 2) * per_norm * d
+    expert_layers = cfg.expert_layers()
+    return (
+        cfg.vocab_size * d + (0 if cfg.rope else cfg.max_len * d)
+        + cfg.num_layers * (attention + norms)
+        + expert_layers * experts + (cfg.num_layers - expert_layers) * dense
+        + per_norm * d + (d + bias) * cfg.vocab_size
+    )
 
 
 def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     """What ``Transformer(remat=True)`` keeps between forward and
     backward, from what the model knows at trace time: ``(mode,
     saved_bytes)`` for ``tokens`` tokens on this chip and a device
-    memory of ``bytes_limit`` bytes (None: not known).
+    memory of ``bytes_limit`` bytes (None: not known). A ladder: the
+    richest rung whose bytes, over all layers, are at most its
+    ``REMAT_SAVE_SHARE`` of what the state leaves of the device,
+    ``bytes_limit - REMAT_STATE_BYTES_PER_PARAM x`` the parameters this
+    chip holds (:func:`_param_count`: held experts, not published ones).
 
     ``save_matmuls``: each block keeps the outputs of its weight matmuls
-    (qkv, the output gate's where the model has one, the attention's
-    output projection, the first feed-forward matmul, or the gate and up
-    matmuls of a gated one) and of the flash forward (the attention output and one lane
-    of ``lse``), and recomputes only the element-wise work; on the
-    flash path q, k and v are kept as the kernels take them, in place of
-    the projection's output, so the head transposes are not repeated
-    either. ``saved_bytes`` is their size over all layers (the
-    attention output is counted on the dense path too, which recomputes
-    it). Taken when that is at most ``REMAT_SAVE_SHARE`` of the
-    device. ``recompute_all``: each block keeps its input alone —
-    where the saving would not fit, where the limit cannot be read (CPU)
-    and for any model with expert layers (``moe_experts``, or "experts"
-    among ``layer_kinds``): an expert layer's grouped matmuls are kernel
-    calls on ``tokens x moe_top_k`` rows, which the policy neither sees
-    nor should keep (2 x 8192 tokens, top-8, 2048 wide: 512 MiB a
-    tensor), and this reckoning counts only ``tokens``, not the
-    parameters' own share of the device, which is what is short where
-    experts are held. So the whole model recomputes, its dense and
-    attention parts too. ``off``: ``cfg.remat`` is not set."""
+    (the output gate's where the model has one, the attention's output
+    projection, the first feed-forward matmul, or the gate and up
+    matmuls of a gated one; in an expert layer the router's float32
+    logits and the shared expert's gate and up; where the model has
+    them, what QK-norm and the sandwich norm read: the q and k/v
+    projections' outputs and the last feed-forward matmul's) and of the
+    flash forward (the attention output and one lane of ``lse``), and
+    recomputes only the element-wise work and, in an expert layer, the
+    dispatch and the grouped matmuls; on the flash path q, k and v are
+    kept as the kernels take them, in place of the projection's output
+    where no norm reads that, so the head transposes are not repeated
+    either. The attention output is counted on the dense path too,
+    which recomputes it. Not offered to the serving bank
+    (``moe_experts``), whose one-hot einsums make a ``tokens x experts
+    x d_ff`` output that the policy would keep.
+
+    ``save_attention``: each block keeps what the flash kernels' backward
+    reads and no more, by name (``ops/flash_attention.py:
+    RESIDUAL_NAMES``): q, k and v as the kernels take them, the
+    attention output and one lane of ``lse``; of an expert layer also
+    the routing's integer results (``parallel/moe.py: ROUTING_NAMES``,
+    ``tokens x moe_top_k`` int32 each). The backward's second forward
+    then runs no flash forward, no RoPE or head transpose, no ``top_k``
+    and no sort of the dispatch, and no q/k/v projection unless QK-norm
+    reads its output (its backward needs the value before the norm);
+    it recomputes the rest: norms, the gate's and the output
+    projection, the feed-forward; in an expert layer the router, the
+    dispatch's gathers and the grouped matmuls, whose tensors of
+    ``tokens x moe_top_k`` rows stay unkept (2 x 8192 tokens, top-8,
+    2048 wide: 512 MiB each). Offered where the model rides the kernels
+    (``cfg.wants_flash()``): the dense path has no such names.
+
+    ``recompute_all``: each block keeps its input alone, where no rung
+    fits and where the limit cannot be read (CPU). ``off``:
+    ``cfg.remat`` is not set."""
     if not cfg.remat:
         return "off", 0
-    if cfg.has_experts() or not bytes_limit:
+    if not bytes_limit:
         return "recompute_all", 0
+    itemsize = jnp.dtype(cfg.dtype).itemsize
     q_width = cfg.num_heads * cfg.dim_per_head()
     kv_width = 2 * (cfg.num_kv_heads or cfg.num_heads) * cfg.dim_per_head()
-    # q + kv, attention output (and its gate), output projection, first
-    # feed-forward (gate and up of a gated one)
-    width = (
-        q_width * (3 if cfg.attn_output_gate else 2) + kv_width
-        + cfg.d_model + cfg.d_ff * (2 if cfg.ffn_gated else 1)
+    # q, k and v, the attention output, one float32 lse lane a head
+    attention = (2 * q_width + kv_width) * itemsize + 4 * cfg.num_heads
+    # the output gate's and the output projection's outputs; the q and
+    # k/v projections' where a norm reads them (through RoPE and the
+    # head transpose alone the backward needs no value); the last
+    # feed-forward matmul's where a norm follows it
+    matmuls = (
+        (q_width if cfg.attn_output_gate else 0) + cfg.d_model
+        + (q_width + kv_width if cfg.qk_norm else 0)
+        + (cfg.d_model if cfg.sandwich_norm else 0)
+    ) * itemsize
+    # the first feed-forward matmuls' outputs (gate and up of a gated
+    # one); of an expert layer the router's float32 logits and the
+    # shared expert's gate and up
+    dense = cfg.d_ff * (2 if cfg.ffn_gated else 1) * itemsize
+    experts = 4 * cfg.moe_experts_total + 2 * cfg.moe_shared_d_ff * itemsize
+    expert_layers = cfg.expert_layers()
+    # by name on both rungs: the kernels' residuals and, of an expert
+    # layer, the chosen experts and the dispatch's two permutations
+    named = (
+        cfg.num_layers * attention + expert_layers * 3 * 4 * cfg.moe_top_k
     )
-    per_token = width * jnp.dtype(cfg.dtype).itemsize + 4 * cfg.num_heads
-    saved = cfg.num_layers * int(tokens) * per_token
-    if saved > REMAT_SAVE_SHARE * bytes_limit:
-        return "recompute_all", 0
-    return "save_matmuls", saved
+    # bytes a token over all layers; 0: the rung is not offered
+    rungs = {
+        "save_matmuls": 0 if cfg.moe_experts else (
+            named + cfg.num_layers * matmuls + expert_layers * experts
+            + (cfg.num_layers - expert_layers) * dense
+        ),
+        "save_attention": named if cfg.wants_flash() else 0,
+    }
+    room = bytes_limit - REMAT_STATE_BYTES_PER_PARAM * _param_count(cfg)
+    for mode, per_token in rungs.items():
+        saved = int(tokens) * per_token
+        if 0 < saved <= REMAT_SAVE_SHARE[mode] * room:
+            return mode, saved
+    return "recompute_all", 0
 
 
 def _device_bytes_limit() -> Optional[int]:
@@ -962,19 +1062,30 @@ def _device_bytes_limit() -> Optional[int]:
     return (stats or {}).get("bytes_limit")
 
 
-def _save_matmuls_policy():
-    """Checkpoint policy of ``save_matmuls``: weight matmuls are the
-    ``dot_general``s without batch dimensions (the dense attention
-    fallback's einsums have them, and are recomputed); the flash
-    kernels' residuals go by name, since a policy on primitives does
-    not see through a ``pallas_call``. An output the backward does not
-    read (the second feed-forward matmul's; the qkv projection's where
-    q, k and v are kept by name) is not kept."""
+def _remat_policy(mode: str):
+    """Checkpoint policy of a rung of :func:`remat_plan`. The flash
+    kernels' residuals go by name on both saving rungs, since a policy
+    on primitives does not see through a ``pallas_call``, and with
+    them an expert layer's routing (``parallel/moe.py: ROUTING_NAMES``);
+    ``save_matmuls`` adds the weight matmuls, the ``dot_general``s
+    without batch dimensions (the dense attention fallback's einsums
+    have them, and are recomputed). An output the backward does not
+    read (the second feed-forward matmul's, the qkv projection's where
+    q, k and v are kept by name, unless a norm follows) is not kept.
+    ``recompute_all``: None, nothing but the block's input."""
+    from ..parallel.moe import ROUTING_NAMES
+
     policies = jax.checkpoint_policies
-    return policies.save_from_both_policies(
-        policies.dots_with_no_batch_dims_saveable,
-        policies.save_only_these_names(*_FLASH_RESIDUAL_NAMES),
+    names = policies.save_only_these_names(
+        *_FLASH_RESIDUAL_NAMES, *ROUTING_NAMES
     )
+    if mode == "save_attention":
+        return names
+    if mode == "save_matmuls":
+        return policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, names
+        )
+    return None
 
 
 _TRACE_MODEL_SPAN = "hvd.trainer.trace_model"
@@ -1069,10 +1180,9 @@ class Transformer(nn.Module):
             _device_bytes_limit() if cfg.remat else None,
         )
         if mode != "off":
-            policy = (
-                _save_matmuls_policy() if mode == "save_matmuls" else None
+            block = nn.remat(
+                Block, static_argnums=(3,), policy=_remat_policy(mode)
             )
-            block = nn.remat(Block, static_argnums=(3,), policy=policy)
         span = _tracing.current()
         if span is not None and span.name == _TRACE_MODEL_SPAN:
             span.tag(remat=mode, remat_saved_bytes=saved_bytes)

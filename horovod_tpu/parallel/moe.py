@@ -32,6 +32,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 
 class MoEParams(NamedTuple):
@@ -401,6 +402,14 @@ def moe_ffn(
 # ---------------------------------------------------------------------------
 
 
+# The names a ``jax.checkpoint`` policy keeps the routing's integer results
+# by (``save_only_these_names(*ROUTING_NAMES)``): a token's chosen experts
+# and the two permutations of the dispatch, ``tokens x top_k`` int32 each,
+# so that remat's second forward repeats neither ``top_k`` nor the two
+# sorts. Under no policy the names cost nothing.
+ROUTING_NAMES = ("moe_chosen", "moe_order", "moe_inverse")
+
+
 def route_top_k(logits, select_bias, top_k: int, *, score: str = "sigmoid",
                 norm: bool = True, scale: float = 1.0):
     """``(chosen [tokens, k] int32, gates [tokens, k] float32)`` from the
@@ -418,10 +427,11 @@ def route_top_k(logits, select_bias, top_k: int, *, score: str = "sigmoid",
     else:
         raise ValueError(f"score {score!r} is not sigmoid or softmax")
     _, chosen = lax.top_k(scores + lax.stop_gradient(select_bias), top_k)
+    chosen = checkpoint_name(chosen.astype(jnp.int32), "moe_chosen")
     gates = jnp.take_along_axis(scores, chosen, axis=-1)
     if norm:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
-    return chosen.astype(jnp.int32), gates * scale
+    return chosen, gates * scale
 
 
 def _gmm_tiling(rows: int, k: int, n: int):
@@ -518,8 +528,10 @@ def held_experts_ffn(x, chosen, gates, w_gate, w_up, w_down, first_held: int):
         local = chosen - first_held
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held).reshape(-1)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
+        order = checkpoint_name(
+            jnp.argsort(key, stable=True).astype(jnp.int32), "moe_order")
+        inverse = checkpoint_name(
+            jnp.argsort(order).astype(jnp.int32), "moe_inverse")
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0,
             dtype=jnp.int32,

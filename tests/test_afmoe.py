@@ -284,22 +284,75 @@ def test_a_page_table_on_the_new_kinds_says_what_is_missing():
 
 # ----------------------------------------------- (f) what the program says
 
-def test_remat_recomputes_all_where_experts_are_held_and_says_so(monkeypatch):
+# over the five layers at 2 x SEQ float32 tokens: the flash kernels' q, o
+# (64 wide), k, v (32) and one lse a head, and in the four expert layers
+# top-2's chosen experts and two permutations; save_matmuls' further outputs of
+# the gate (64), W_o (64), the q and k/v projections that QK-norm reads
+# (64 + 64), the last feed-forward matmul that the sandwich norm reads (64),
+# and gate and up (2 x 96) in the dense layer, the router's logits (8, in
+# float32 whatever the model's dtype) and the shared expert's gate and up
+# (2 x 32) in the four expert layers
+_ATTENTION_KEPT = 2 * SEQ * (
+    5 * (4 * (64 + 64 + 32 + 32) + 4 * 4) + 4 * 3 * 2 * 4)
+_MATMULS_KEPT = _ATTENTION_KEPT + 2 * SEQ * 4 * (
+    5 * (64 + 64 + 64 + 64 + 64) + 2 * 96 + 4 * (8 + 2 * 32))
+
+
+def _state_bytes(cfg):
+    return T.REMAT_STATE_BYTES_PER_PARAM * T._param_count(cfg)
+
+
+@pytest.mark.parametrize("flash,room,want", [
+    (True, 1 << 40, ("save_matmuls", _MATMULS_KEPT)),
+    (True, 5 * _ATTENTION_KEPT, ("save_attention", _ATTENTION_KEPT)),
+    (True, 5 * _ATTENTION_KEPT - 8, ("recompute_all", 0)),
+    # off the kernels nothing goes by name
+    (False, 5 * _ATTENTION_KEPT, ("recompute_all", 0)),
+    (None, None, ("recompute_all", 0)),
+], ids=["save_matmuls", "save_attention", "too-little-room", "dense-path",
+        "limit-unknown"])
+def test_remat_climbs_the_ladder_where_experts_are_held_and_says_so(
+        flash, room, want, monkeypatch):
     monkeypatch.setenv("HOROVOD_TRACE", "0")
     tracing._reset()
     model, params, tokens, _ = _built(_config())
-    assert T.remat_plan(model.cfg, 2 * SEQ, 1 << 40) == ("recompute_all", 0)
+    cfg = dataclasses.replace(model.cfg, flash_attention=bool(flash))
+    limit = None if room is None else _state_bytes(cfg) + room
+    assert T._param_count(cfg) == sum(
+        x.size for x in jax.tree.leaves(params))
+    assert T.remat_plan(cfg, 2 * SEQ, limit) == want
+    monkeypatch.setattr(T, "_device_bytes_limit", lambda: limit)
+    model = T.Transformer(cfg)
     jax.make_jaxpr(lambda p, t: model.apply(p, t, train=True))(params, tokens)
     span = [r for r in tracing.recorder().spans()
             if r["name"] == "hvd.trainer.trace_model"][-1]
     tracing._reset()
     assert span["tags"] == {
-        "layers": 5, "remat": "recompute_all", "remat_saved_bytes": 0,
+        "layers": 5, "remat": want[0], "remat_saved_bytes": want[1],
         "layer_kinds": "window/dense,window/experts,window/experts,"
                        "full-nope/experts,window/experts",
         "experts_total": 8, "experts_held": 4, "top_k": 2,
         "moe_rows_capacity": 2 * SEQ * 2,
     }
+
+
+def test_the_cell_of_the_benchmark_keeps_the_kernels_residuals():
+    """Trinity-Mini's share at 2 x 8192 tokens beside a v5e's limit: the
+    matmuls' outputs are over their share of what 8.47 GB of state leave,
+    the kernels' residuals are not (PERF.md section 6, PR 28)."""
+    family, _ = _family()
+    with open(os.path.join(HERE, "..", "benchmark", "configs",
+                           "trinity-mini.json")) as f:
+        cfg = family.build_model(json.load(f), remat=True).cfg
+    cfg = dataclasses.replace(cfg, flash_attention=True)  # as on the chip
+    limit = int(15.74 * 2**30)
+    assert T._param_count(cfg) == 705_474_304
+    # bfloat16 q and o at 32 heads of 128, k and v at 4, an lse a head;
+    # top-8's chosen experts and two permutations in the four expert layers
+    per_token = 5 * ((2 * 4096 + 2 * 512) * 2 + 4 * 32) + 4 * 3 * 8 * 4
+    assert T.remat_plan(cfg, 2 * 8192, limit) == (
+        "save_attention", 2 * 8192 * per_token)
+    assert T.remat_plan(cfg, 2 * 8192, 1 << 40)[0] == "save_matmuls"
 
 
 def test_remat_plan_reckons_the_new_widths_of_a_dense_model():
