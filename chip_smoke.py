@@ -62,6 +62,17 @@ def _spanning_allreduces_compiled(hlo_text: str, world: int) -> int:
     return n
 
 
+def _host_sync(x):
+    """Wait for ``x`` by moving one scalar of it to the host: a transfer
+    of a value that depends on the whole timed loop cannot return early,
+    whatever the runtime does with ``block_until_ready``. The leaf is
+    sliced on the device first, so one scalar crosses."""
+    import jax
+
+    leaf = jax.tree.leaves(x)[0].reshape(-1)[:1]
+    return float(np.asarray(leaf)[0])
+
+
 def train_smoke(cfg, steps, batch=BATCH, seq=SEQ, op=None):
     """Run the quick-start trainer for ``steps`` optimizer steps of
     model config ``cfg`` on every local device; raise on any failed
@@ -73,7 +84,6 @@ def train_smoke(cfg, steps, batch=BATCH, seq=SEQ, op=None):
     from jax.sharding import PartitionSpec as P
 
     import horovod_tpu as hvd
-    from _benchlib import sync as host_sync
     from horovod_tpu import analysis
     from horovod_tpu.models import Transformer
 
@@ -216,7 +226,7 @@ def train_smoke(cfg, steps, batch=BATCH, seq=SEQ, op=None):
 
     t0 = time.perf_counter()
     params, opt_state, loss = step(params, opt_state, toks, labels)
-    host_sync(loss)  # also compiles sync's own tiny programs, once
+    _host_sync(loss)  # also compiles sync's own tiny programs, once
     first_step_s = time.perf_counter() - t0
     losses = [loss]
     compiles_after_step1 = len(compiles)
@@ -227,7 +237,7 @@ def train_smoke(cfg, steps, batch=BATCH, seq=SEQ, op=None):
         losses.append(loss)
     jax.block_until_ready(loss)
     dt_block = time.perf_counter() - t0
-    host_sync(loss)  # a host transfer that depends on the whole chain
+    _host_sync(loss)  # a host transfer that depends on the whole chain
     dt_host = time.perf_counter() - t0
 
     recompiles = len(compiles) - compiles_after_step1

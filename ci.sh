@@ -7,15 +7,11 @@
 #   2. native+TSAN — csrc/ builds clean AND passes a ThreadSanitizer
 #                    stress of its concurrent pieces (SURVEY.md §5.2)
 #   3. tests       — the full CPU suite on the virtual 8-device mesh
-#   4. bench-smoke — bench_fusion.py dryrun: the fusion A/B measurement
-#                    harness (host-pack vs in-JIT, bucketing, gather
-#                    fusion) must run green and emit per-leg artifacts,
-#                    so the engine's premise-measurement can't rot
-#   5. telemetry-smoke — 5-step CPU loop with the live /metrics
+#   4. telemetry-smoke — 5-step CPU loop with the live /metrics
 #                    endpoint on an ephemeral port: Prometheus scrape
 #                    (step p50/p95 + registry gauges) and the
 #                    flight-recorder JSON-lines dump must both work
-#   6. serve-smoke — scripts/serve_smoke.py: a 2-worker inference
+#   5. serve-smoke — scripts/serve_smoke.py: a 2-worker inference
 #                    fleet on a toy transformer — concurrent
 #                    mixed-length prompts routed through the
 #                    rendezvous-KV capacity announcements, TTFT/TPOT
@@ -34,7 +30,7 @@
 #                    finally SIGTERM the unified workers and assert
 #                    the drain completed every accepted request (exit
 #                    143) — the serving plane can't silently rot
-#   7. audit-smoke — scripts/hlo_audit.py: the lowered-program
+#   6. audit-smoke — scripts/hlo_audit.py: the lowered-program
 #                    invariant catalog over the canonical roster
 #                    (fused fp32/int8 wire, overlap buckets, ZeRO-2/3,
 #                    guard overhead, two-level + MoE routing, serve
@@ -42,7 +38,7 @@
 #                    auditor must exit nonzero on a deliberately
 #                    broken invariant (int8 forced onto an intra hop)
 #                    — an auditor that cannot fail is not evidence
-#   8. chaos-smoke — scripts/chaos_smoke.py: an integrity drill (one
+#   7. chaos-smoke — scripts/chaos_smoke.py: an integrity drill (one
 #                    injected NaN training step that the grad guard
 #                    must SKIP and count, one injected checkpoint
 #                    bitflip that digest verification must bypass via
@@ -60,7 +56,7 @@
 #                    live-migrated request assembling into one
 #                    connected trace spanning >= 3 processes
 #
-# Usage: ./ci.sh [lint|native|tests|bench-smoke|telemetry-smoke|serve-smoke|audit-smoke|chaos-smoke|all]
+# Usage: ./ci.sh [lint|native|tests|telemetry-smoke|serve-smoke|audit-smoke|chaos-smoke|all]
 # (default: all)
 
 set -euo pipefail
@@ -103,77 +99,6 @@ native() {
 tests() {
   step "tests: full CPU suite (8-device virtual mesh)"
   python -m pytest tests/ -q
-}
-
-bench_smoke() {
-  step "bench-smoke: bench_fusion.py dryrun (A/B harness + artifacts)"
-  local art_dir
-  art_dir="$(mktemp -d)"
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_fusion.py
-  # the A/B legs must have produced their per-leg JSON artifacts
-  for leg in ab_pack ab_bucketing ab_gather; do
-    test -s "$art_dir/fusion_${leg}.json" \
-      || { echo "missing artifact: fusion_${leg}.json" >&2; exit 1; }
-  done
-  step "bench-smoke: bench_int8.py dryrun (fused-vs-per-tensor leg)"
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_int8.py
-  test -s "$art_dir/int8_ab_fused.json" \
-    || { echo "missing artifact: int8_ab_fused.json" >&2; exit 1; }
-  step "bench-smoke: bench_overlap.py dryrun (bucketed-exchange A/B)"
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_overlap.py
-  for leg in ab_monolithic ab_bucketed ab_bucketed_rs; do
-    test -s "$art_dir/overlap_${leg}.json" \
-      || { echo "missing artifact: overlap_${leg}.json" >&2; exit 1; }
-  done
-  step "bench-smoke: bench_zero.py dryrun (ZeRO-1/2/3 A/B + live-buffer gate)"
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_zero.py
-  for leg in ab_zero1 ab_zero2 ab_zero3; do
-    test -s "$art_dir/zero_${leg}.json" \
-      || { echo "missing artifact: zero_${leg}.json" >&2; exit 1; }
-  done
-  step "bench-smoke: bench_hier.py dryrun (two-level wire A/B + DCN-byte gate)"
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_hier.py
-  for leg in ab_flat ab_hier ab_hier_int8; do
-    test -s "$art_dir/hier_${leg}.json" \
-      || { echo "missing artifact: hier_${leg}.json" >&2; exit 1; }
-  done
-  step "bench-smoke: bench_moe.py dryrun (expert-wire A/B + DCN-byte + capacity-tuner gates)"
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_moe.py
-  for leg in ab_flat ab_hier_int8 ab_captuned; do
-    test -s "$art_dir/moe_${leg}.json" \
-      || { echo "missing artifact: moe_${leg}.json" >&2; exit 1; }
-  done
-  step "bench-smoke: bench_lm.py ab_local_sgd dryrun (K=1 vs K=8 inter-byte + loss-parity gates)"
-  JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_AB=local_sgd \
-    BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_lm.py
-  for leg in k1 k8; do
-    test -s "$art_dir/lm_ab_local_sgd_${leg}.json" \
-      || { echo "missing artifact: lm_ab_local_sgd_${leg}.json" >&2; exit 1; }
-  done
-  step "bench-smoke: bench_serve.py dryrun (static-vs-continuous + paged-KV + prefix-cache + disaggregated + paged-attention + warm-cache + failover A/B)"
-  JAX_PLATFORMS=cpu \
-    BENCH_PLATFORM=cpu BENCH_DRYRUN=1 BENCH_ARTIFACT_DIR="$art_dir" \
-    python bench_serve.py
-  for leg in static continuous paged prefix disagg paged_attn warm_cache \
-             failover; do
-    test -s "$art_dir/serve_ab_${leg}.json" \
-      || { echo "missing artifact: serve_ab_${leg}.json" >&2; exit 1; }
-  done
-  echo "bench-smoke artifacts OK: $art_dir"
 }
 
 serve_smoke() {
@@ -222,11 +147,10 @@ case "${1:-all}" in
   lint)        lint ;;
   native)      native ;;
   tests)       tests ;;
-  bench-smoke) bench_smoke ;;
   telemetry-smoke) telemetry_smoke ;;
   serve-smoke) serve_smoke ;;
   audit-smoke) audit_smoke ;;
   chaos-smoke) chaos_smoke ;;
-  all)         lint; native; tests; bench_smoke; telemetry_smoke; serve_smoke; audit_smoke; chaos_smoke ;;
-  *) echo "usage: $0 [lint|native|tests|bench-smoke|telemetry-smoke|serve-smoke|audit-smoke|chaos-smoke|all]" >&2; exit 2 ;;
+  all)         lint; native; tests; telemetry_smoke; serve_smoke; audit_smoke; chaos_smoke ;;
+  *) echo "usage: $0 [lint|native|tests|telemetry-smoke|serve-smoke|audit-smoke|chaos-smoke|all]" >&2; exit 2 ;;
 esac

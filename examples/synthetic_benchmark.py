@@ -127,7 +127,7 @@ def main():
                 params, batch_stats, opt_state, x, y
             )
         # The loss chains through every step's params: one host
-        # transfer of it waits for the whole loop (see _benchlib.sync).
+        # transfer of it waits for the whole loop.
         if loss is not None:
             float(np.asarray(loss).ravel()[0])
 

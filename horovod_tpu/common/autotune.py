@@ -358,8 +358,8 @@ class WireTuner(_GoodputBandit):
 
     * buckets under ``min_int8_bytes`` never try int8 — the per-dispatch
       quantize tax is O(payload)+fixed while the wire saving is
-      O(payload), so below a payload floor the tax always wins (the
-      crossover bench_int8.py measures);
+      O(payload), so below a payload floor the tax always wins (where
+      the crossover lies on the chip is not measured);
     * ``candidates`` restricts the menu (int8 only where the op/dtype
       qualify — the fusion manager filters before asking).
     """
@@ -404,12 +404,12 @@ class OverlapTuner(_GoodputBandit):
 
     Driven by the STEP HARNESS, not from inside the compiled step: a
     bucket-count change changes the compiled program, so each candidate
-    is its own jitted step — the training loop (or bench:
-    ``bench_overlap.py`` runs exactly this loop) times a few chained,
-    honestly-synced steps per candidate, feeds ``record``, and rebuilds
-    its step with ``choose``'s answer once exploration drains. The
-    caller owns the timing discipline (docs/perf.md §measurement
-    integrity) or the tuner learns dispatch overhead, not overlap.
+    is its own jitted step — the training loop times a few chained
+    steps per candidate (ending in a host transfer that depends on the
+    last one), feeds ``record``, and rebuilds its step with
+    ``choose``'s answer once exploration drains. The caller owns the
+    timing discipline, or the tuner learns dispatch overhead, not
+    overlap.
 
     ``min_bucket_bytes`` is the static prior bounding the explore set:
     a candidate whose per-bucket size would fall under the floor can
@@ -456,9 +456,9 @@ class CapacityTuner(_GoodputBandit):
     cannot rank a priori. Scoring kept tokens per second of step wall
     time lets the measurement settle it, exactly the OverlapTuner's
     reasoning — and like the bucket count, capacity is a COMPILE-TIME
-    shape: the step harness times a few honestly-synced steps per
-    candidate across recompiles (bench_moe.py ``ab_captuned`` shows
-    the loop), never inside one compiled step.
+    shape: the step's own loop times a few steps per candidate across
+    recompiles (tests/test_moe_wire.py feeds it from ``moe_ffn``'s
+    stats), never inside one compiled step.
 
     ``observe_load`` additionally folds the raw histogram into
     per-candidate drop-rate / imbalance summaries, which ``choose``

@@ -239,8 +239,8 @@ class Config:
     # always wins). For a measured choice, the step harness can sweep
     # candidates through common/autotune.py's OverlapTuner — a bucket
     # count is a compile-time property of the step, so tuning happens
-    # across recompiles at the loop level (bench_overlap.py shows the
-    # pattern), never inside one compiled step
+    # across recompiles at the loop level (OverlapTuner's docstring
+    # has the loop), never inside one compiled step
     overlap_buckets: int = 4
     # buckets below this byte size merge forward: per-collective launch
     # overhead outweighs any overlap win under the floor

@@ -65,12 +65,13 @@ class TransformerConfig:
     # Flash kernel block sizes (tunable: bigger blocks = fewer K/V loop
     # iterations and larger MXU matmuls, more VMEM per program). Auto-
     # shrunk to the sequence length when it is shorter. The default
-    # (ops.flash_attention.DEFAULT_BLOCK = 512) won the round-4 on-chip
-    # sweep on GPT-2-medium seq-512 (83.0 samp/s / MFU 0.563 vs 60.3 /
-    # 0.409 at 128 — bench_results/gpt2_blk*_r04); VMEM per program
-    # stays modest because K/V are staged whole-sequence regardless of
-    # block_k, so bigger blocks only grow the (block_q, block_k) score
-    # tile (512x512 fp32 = 1 MiB).
+    # (ops.flash_attention.DEFAULT_BLOCK = 512) is what every cell of
+    # the benchmark runs (PERF_LEDGER.jsonl: the kernels take 15.2 ms
+    # of GPT-2 medium's step at 22-32% of their rooflines; whether a
+    # smaller causal block does better at seq 512 is ROADMAP A3's open
+    # question). VMEM per program stays modest because K/V are staged
+    # whole-sequence regardless of block_k, so bigger blocks only grow
+    # the (block_q, block_k) score tile (512x512 fp32 = 1 MiB).
     flash_block_q: int = _DEFAULT_FLASH_BLOCK
     flash_block_k: int = _DEFAULT_FLASH_BLOCK
     # Rotary position embeddings (Llama/Mistral-style) applied to q/k
@@ -232,7 +233,7 @@ class TransformerConfig:
 
     def uses_flash(self, mask=None, seq=None) -> bool:
         """THE gating rule for the Pallas flash path — single source
-        of truth for the model and for bench_lm's FLOPs correction."""
+        of truth for the model and for whoever counts its FLOPs."""
         return (
             self.wants_flash()
             and self.flash_decline_reason(mask, seq) is None

@@ -1544,7 +1544,8 @@ class FusionManager:
         """Bucket-tier dispatch: host-side pack into the padded buffer,
         one collective invocation, host-side unpack. This is the
         pre-rework dispatch path, kept as the composition-independent
-        fallback and as `bench_fusion.py`'s host-pack A/B leg."""
+        fallback (``HOROVOD_FUSION_INJIT=0``; tests/test_fusion_injit.py
+        holds its parity with the in-JIT path)."""
         if self.timeline is not None and len(batch) > 1:
             for e in batch:
                 self.timeline.begin(e.name, "MEMCPY_IN_FUSION_BUFFER")
@@ -1833,8 +1834,8 @@ class FusionManager:
         """The quantized fused wire: the whole fused buffer traverses
         the collective as block-scaled int8, entirely inside the
         compiled program — quantize ONCE over the batch instead of once
-        per tensor (the per-tensor quantize tax bench_int8.py measures,
-        amortized to one).
+        per tensor (the quantize pass has a fixed cost per call: one
+        call a batch, not one a tensor).
 
         Recipe = traced.quantized_allreduce's two-stage shape applied
         to this rank's [1, N] buffer row: block-quantize the row split

@@ -90,9 +90,8 @@ def _resolve_hier(hier, ep: int):
 def _resolve_wire(wire, intra_wire, payload_bytes: int, hier):
     """Trace-time wire choice. ``auto`` asks the shared WireTuner's
     ``(alltoall, hop)`` key family — a compile-time decision like the
-    OverlapTuner's bucket count: the harness feeds goodput across
-    recompiles (bench_moe.py shows the loop), the choice is frozen
-    into this trace."""
+    OverlapTuner's bucket count: the step's own loop feeds goodput
+    across recompiles, the choice is frozen into this trace."""
     from ..common import basics as _basics
 
     cfg = _basics.live_config()

@@ -14,9 +14,9 @@ Policy knobs:
   (``HOROVOD_SERVE_MAX_BATCH``). Prefill happens on the decode thread,
   so each admission delays every in-flight token by one prefill: this
   knob IS the TTFT-vs-TPOT interleaving trade (docs/serving.md).
-* ``policy="static"`` — the A/B baseline (bench_serve.py): admissions
-  only when the previous batch fully completed, i.e. classic batched
-  inference with its head-of-line blocking.
+* ``policy="static"`` — the baseline continuous batching is compared
+  with: admissions only when the previous batch fully completed, i.e.
+  classic batched inference with its head-of-line blocking.
 * per-request deadlines — queued requests expire before wasting a
   prefill; running requests are evicted at the deadline with their
   partial output (status ``"deadline"``).

@@ -223,8 +223,8 @@ class InferenceEngine:
             cache_factory = _default_cache_factory(model)
         # memory plane: paged block pool + prefix cache by default
         # (serving/paged_kv.py); paged=False keeps the PR 8 contiguous
-        # slab — the A/B baseline (bench_serve.py ab_paged). None knobs
-        # resolve from the env contract (docs/env_vars.md).
+        # slab, the layout tests/test_paged_kv.py compares against.
+        # None knobs resolve from the env contract (docs/env_vars.md).
         from ..common import basics
         from .kv_cache import create_kv_manager
 

@@ -40,13 +40,13 @@ from typing import List, Tuple
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Directories/globs linted. tests and benches are in scope for
+# Directories/globs linted. tests and root scripts are in scope for
 # bare-except + unused-import; the env-read and debug-callback rules
 # apply to the package only (tests legitimately monkeypatch env and
 # exercise callbacks).
 PACKAGE_DIRS = ("horovod_tpu",)
 EXTRA_DIRS = ("tests", "scripts", "examples")
-ROOT_GLOBS = ("bench", "_benchlib", "_hermetic", "__graft_entry__")
+ROOT_GLOBS = ("_hermetic", "__graft_entry__", "chip_smoke")
 
 # --- rule 1 allowlist: files whose os.environ READS are the contract,
 # not a config bypass (worker-protocol identity, child-env assembly,

@@ -759,6 +759,52 @@ class TestHierInt8TracedPath:
         assert np.abs(out[0] - want).max() < 4.0 * scale
 
 
+    @pytest.mark.parametrize(
+        "wire,least", [("fp32", 4.0), ("int8", 3.0)]
+    )
+    def test_inter_hop_bytes_drop_per_bucket_schedule(self, wire, least):
+        """The scarce hop's bytes over a bucket schedule, by the ledger's
+        own model (``FusionManager._hop_bytes``): the two-level wire
+        sends the 1/L shard across slices, so fp32 drops >= L x against
+        the flat wire and int8 >= 3 x (4L x less the block scales)."""
+        from horovod_tpu.ops.fusion import FusionManager
+
+        L, H, block = 4, 2, 512
+        leaves = [np.zeros((64, 64), np.float32) for _ in range(4)]
+        sched = overlap.build_bucket_schedule(leaves, 4, 0)
+        flat = inter = 0
+        for nbytes in sched.bucket_bytes:
+            elems = nbytes // 4
+            flat += FusionManager._hop_bytes(elems, "fp32", 4, L * H, block)[0]
+            inter += FusionManager._hop_bytes(
+                -(-elems // L), wire, 4, H, block
+            )[0]
+        assert flat / inter >= least, (flat, inter)
+
+    def test_bucketed_hier_int8_counts_per_bucket(self, hvd):
+        """Per bucket the int8 leg lowers to one intra reduce-scatter
+        and two inter all_to_alls (the int8 payload and its scales)."""
+        from horovod_tpu.ops.compression import Compression
+
+        rng = np.random.default_rng(16)
+        t = _tree(rng, [(64, 64)] * 4)
+
+        def body(tr):
+            local = jax.tree_util.tree_map(lambda x: x[0], tr)
+            out = overlap.bucketed_allreduce(
+                local, op=Sum, n_buckets=4, min_bucket_bytes=0,
+                compression=Compression.int8_block,
+                hier_stages=STAGES_84,
+            )
+            return jax.tree_util.tree_map(lambda x: x[None], out)
+
+        analysis.expect(
+            analysis.parse_module(_sm(body).lower(t)),
+            analysis.CollectiveCount("reduce_scatter", 4),
+            analysis.CollectiveCount("all_to_all", 8),
+        )
+
+
 # ------------------------------------------------ hierarchical Adasum
 
 

@@ -720,6 +720,76 @@ class TestRoundDriver:
             1 << 20, stages, "fp32"
         ) == 4 * want
 
+    def test_inter_bytes_drop_against_the_every_step_wire(self, hvd):
+        """K=8 rounds against hier-int8 on every step: the bytes over
+        the slice boundary fall by at least K/2 a step (the ledger's own
+        models on both sides)."""
+        from horovod_tpu import local_sgd
+        from horovod_tpu.ops.fusion import FusionManager
+
+        K, L, H, grad_bytes = 8, 4, 2, 1 << 20
+        every_step, _ = FusionManager._hop_bytes(
+            -(-(grad_bytes // 4) // L), "int8", 4, H, 512
+        )
+        per_step = local_sgd.round_inter_bytes(
+            grad_bytes, _stages(), "int8"
+        ) / K
+        assert every_step / per_step >= K / 2
+
+    def test_k8_keeps_half_of_k1s_loss_improvement(self, hvd, rng):
+        """16 steps of a small regression on per-rank data: local SGD
+        with a round every 8 steps learns at least half of what the
+        every-step exchange learns, and a round ran."""
+        from horovod_tpu import local_sgd
+
+        mesh = hvd.mesh()
+        w_true = rng.normal(size=(24, 8)).astype(np.float32)
+        xs = rng.normal(size=(16, WORLD, 6, 24)).astype(np.float32)
+        ys = xs @ w_true
+        params = {"w": jnp.zeros((24, 8), jnp.float32)}
+
+        def leg(k):
+            kw = dict(local_sgd_steps=k, local_sgd_intra=4) if k > 1 else {}
+            opt = hvd.DistributedOptimizer(
+                optax.sgd(0.05), op=hvd.Average, **kw
+            )
+
+            @partial(
+                jax.shard_map, mesh=mesh,
+                in_specs=(P(hvd.WORLD_AXIS),) * 4,
+                out_specs=(P(hvd.WORLD_AXIS),) * 3,
+                check_vma=False,
+            )
+            def step(pm, sm, xb, yb):
+                p, s = _strip(pm), _strip(sm)
+                loss, g = jax.value_and_grad(
+                    lambda q: jnp.mean((xb[0] @ q["w"] - yb[0]) ** 2)
+                )(p)
+                u, s = opt.update(g, s, p)
+                return _lift(optax.apply_updates(p, u)), _lift(s), loss[None]
+
+            step = jax.jit(step)
+            sync = _make_sync_step(hvd, opt, mesh) if k > 1 else None
+            pm, sm = _rank_major(params), _rank_major(opt.init(params))
+            losses, rounds = [], 0
+            for i in range(16):
+                pm, sm, loss = step(pm, sm, xs[i], ys[i])
+                losses.append(float(np.mean(np.asarray(loss))))
+                if k > 1 and local_sgd.due(i, k):
+                    out, synced = local_sgd.run_round(
+                        sync, pm, sm, payload_bytes=24 * 8 * 4,
+                        stages=_stages(),
+                    )
+                    if synced:
+                        (pm, sm), rounds = out, rounds + 1
+            return losses[0] - losses[-1], rounds
+
+        imp1, _ = leg(1)
+        imp8, rounds = leg(8)
+        assert rounds >= 1
+        assert imp1 > 0
+        assert imp8 >= 0.5 * imp1, (imp8, imp1)
+
     def test_chaos_fault_defers_round_zero_restarts(self, hvd, rng):
         """The acceptance drill, in-process: a DCN fault mid-sync-round
         exhausts the retry ladder, the round DEFERS (counted), training

@@ -478,7 +478,7 @@ def test_rope_properties_and_llama_shape_trains():
 def test_transformer_incremental_decode_matches_full(rope, num_kv_heads):
     """The serving engine's model contract (docs/serving.md): the
     cache-threaded forward must reproduce the full-sequence forward —
-    prefill logits bit-comparable, and token-by-token decode matching
+    prefill logits equal to float32 rounding, and token-by-token decode matching
     the full forward's greedy argmax at every position."""
     from horovod_tpu.models.transformer import init_cache
 
@@ -495,14 +495,17 @@ def test_transformer_incremental_decode_matches_full(rope, num_kv_heads):
     full = np.asarray(model.apply(params, tokens, train=False))
 
     # whole-prompt prefill through the cache path: every position's
-    # logits equal the full forward (extra cache keys are masked to
-    # exact zeros, so the reductions see identical terms)
+    # logits equal the full forward to float32 rounding (extra cache
+    # keys are masked to exact zeros, but the reductions run over 16
+    # keys and not 9, and the compiler may order them otherwise)
     cache = init_cache(cfg, 2, 16)
     logits, cache = model.apply(
         params, tokens, train=False,
         cache=cache, cache_index=jnp.zeros((2,), jnp.int32),
     )
-    np.testing.assert_array_equal(full, np.asarray(logits))
+    np.testing.assert_allclose(
+        full, np.asarray(logits), rtol=2e-5, atol=2e-5
+    )
 
     # token-by-token decode: greedy argmax bit-identical per position
     cache = init_cache(cfg, 2, 16)

@@ -411,6 +411,20 @@ class TestMoEFFN:
         sizes = _a2a_group_sizes(low)
         assert sizes, "expected group-limited all_to_all ops"
         assert all(s < 8 for s in sizes), sizes
+        # intra legs of L=4 and inter legs of H=2, nothing else
+        assert set(sizes) <= {4, 2}, sizes
+
+        # the flat wire is the monolithic baseline: every all_to_all
+        # spans the world
+        def flat(p, v):
+            return moe_ffn(p, v[0], capacity_factor=1.25, wire="fp32")[None]
+
+        flat_sizes = _a2a_group_sizes(
+            _sm(flat, (_PARAM_SPEC, P("ep")), P("ep")).lower(
+                params, jnp.asarray(x)
+            )
+        )
+        assert flat_sizes and all(s == 8 for s in flat_sizes), flat_sizes
 
     def test_int8_wire_differentiates_straight_through(self, hvd):
         """grad through the int8 wire: the custom_vjp routes the
@@ -481,6 +495,31 @@ class TestCapacityTuner:
         self._feed(t, key, 2.0, drop_frac=0.0, seconds=1.0)
         assert t.choose(key) == 2.0
         assert t.drop_rate(key, 1.0) == pytest.approx(0.4)
+
+    def test_drop_curve_of_real_steps_is_monotone(self, hvd):
+        """The tuner fed from ``moe_ffn``'s own stats: more capacity
+        never drops more, and the factor it settles on is a candidate."""
+        from horovod_tpu.common.autotune import CapacityTuner
+
+        rng = np.random.default_rng(8)
+        params = _full_params(jax.random.PRNGKey(3))
+        x = rng.normal(size=(8, 12, 16)).astype(np.float32)
+        t = CapacityTuner(trials=1, candidates=(0.5, 1.0, 2.0))
+        key = ("moe", 8, 12, 16)
+        while t.needs_trial(key, t.choose(key)):
+            cf = t.choose(key)
+            _, st = _run_moe(
+                params, x, stats=True, capacity_factor=cf, wire="fp32"
+            )
+            t.observe_load(
+                key, cf, np.asarray(st.expert_tokens),
+                dropped=float(st.dropped), total=float(st.total),
+                seconds=1.0,
+            )
+        drops = [t.drop_rate(key, c) for c in sorted(t.candidates)]
+        assert drops[0] > 0  # cf=0.5 bites
+        assert all(a >= b for a, b in zip(drops, drops[1:])), drops
+        assert t.choose(key) in t.candidates
 
     def test_all_over_bound_takes_largest(self):
         from horovod_tpu.common.autotune import CapacityTuner
@@ -925,7 +964,8 @@ class TestServeMoE:
     def test_paged_slab_parity(self, hvd):
         """MoE decode is bit-identical between the paged pool and the
         slab — routing is a pure function of values the two layouts
-        agree on."""
+        agree on. Slot 1 (11 prompt tokens) crosses into its second
+        16-token page at the sixth step."""
         from horovod_tpu.serving.engine import InferenceEngine
 
         model, params = _moe_model()
@@ -937,14 +977,20 @@ class TestServeMoE:
                 model, params, slots=2, max_len=64, paged=paged
             )
             toks = np.zeros(2, np.int32)
-            for slot, p in enumerate(prompts):
+            # slots come from the manager, as the batcher takes them:
+            # the pre-decode page sweep maps a frontier page for owned
+            # slots only, and a slot that nobody owns loses its writes
+            # past its first page
+            slots = [eng.manager.alloc(f"r{i}") for i in range(2)]
+            for slot, p in zip(slots, prompts):
                 toks[slot] = eng.prefill(slot, p)
             outs = [list() for _ in prompts]
             for _ in range(8):
-                for s in range(2):
+                for s in slots:
                     outs[s].append(int(toks[s]))
-                    eng.manager.advance(s)
                 toks = eng.decode_step(toks)
+                for s in slots:
+                    eng.manager.advance(s)
             return outs
 
         assert run(True) == run(False)

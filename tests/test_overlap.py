@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd_mod
 from horovod_tpu import analysis
@@ -345,7 +345,10 @@ class TestCompiledIndependence:
             )
 
         fn = _shmap(mesh, body)
-        out = t
+        # placed as the step's in_specs say (chip_smoke.py does the
+        # same): call 2 is fed call 1's mesh-sharded output, and an
+        # unplaced first argument would be another signature
+        out = jax.device_put(t, NamedSharding(mesh, P()))
         for _ in range(4):
             out = fn(out)
         assert traces["n"] == 1, "bucketed step retraced"
@@ -633,18 +636,49 @@ class TestOptimizerIntegration:
         return jax.jit(step)
 
     def test_distributed_optimizer_overlap_bitexact(self, hvd):
-        """DistributedOptimizer(overlap_buckets=N) reproduces the
-        monolithic trajectory bit-for-bit (op=Sum, fp32)."""
+        """DistributedOptimizer(overlap_buckets=N) hands its inner
+        optimizer the monolithic exchange's reduced gradients bit for
+        bit (op=Sum, fp32): the docstring's claim. The Adam trajectory
+        after them is held to a few ulp, not to the bit: the compiler
+        fuses the update into two different graphs (where the reduced
+        gradients are an output of the step, the parameters are equal
+        too)."""
         rng = np.random.default_rng(11)
         params, x, y = self._problem(rng)
         vg = jax.value_and_grad(self._loss)
-        o1 = hvd_mod.DistributedOptimizer(
-            optax.adam(1e-2), op=hvd_mod.Sum
-        )
-        o2 = hvd_mod.DistributedOptimizer(
-            optax.adam(1e-2), op=hvd_mod.Sum, overlap_buckets=2,
-            overlap_min_bytes=0,
-        )
+
+        def pair(inner):
+            return (
+                hvd_mod.DistributedOptimizer(inner, op=hvd_mod.Sum),
+                hvd_mod.DistributedOptimizer(
+                    inner, op=hvd_mod.Sum, overlap_buckets=2,
+                    overlap_min_bytes=0,
+                ),
+            )
+
+        # identity inner: the update IS the reduced gradient
+        def reduced(opt):
+            @partial(
+                jax.shard_map,
+                mesh=hvd_mod.mesh(),
+                in_specs=(P(), P(), P(hvd_mod.WORLD_AXIS),
+                          P(hvd_mod.WORLD_AXIS)),
+                out_specs=P(),
+                check_vma=False,
+            )
+            def exchange(p, st, xb, yb):
+                _, g = vg(p, xb[0], yb[0])
+                return opt.update(g, st, p)[0]
+
+            return jax.jit(exchange)(params, opt.init(params), x, y)
+
+        r1, r2 = (reduced(o) for o in pair(optax.identity()))
+        for k in params:
+            np.testing.assert_array_equal(
+                np.asarray(r1[k]), np.asarray(r2[k]), err_msg=k
+            )
+
+        o1, o2 = pair(optax.adam(1e-2))
         s1, s2 = o1.init(params), o2.init(params)
         st1, st2 = self._make_step(o1, vg), self._make_step(o2, vg)
         p1 = p2 = params
@@ -652,8 +686,11 @@ class TestOptimizerIntegration:
             p1, s1, l1 = st1(p1, s1, x, y)
             p2, s2, l2 = st2(p2, s2, x, y)
         for k in params:
-            assert (np.asarray(p1[k]) == np.asarray(p2[k])).all(), k
-        assert float(l1) == float(l2)
+            np.testing.assert_allclose(
+                np.asarray(p1[k]), np.asarray(p2[k]),
+                rtol=1e-6, atol=1e-7, err_msg=k,
+            )
+        np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
 
     def test_value_and_grad_in_backprop_parity(self, hvd):
         """hvd.value_and_grad(overlap_buckets=N) — the custom_vjp

@@ -106,6 +106,24 @@ def test_paged_vs_slab_greedy_bit_parity(toy):
     assert out_p == out_s == _greedy_ref(model, params, prompt, 8)
 
 
+def test_pool_bytes_follow_pages_not_max_len(toy):
+    """The footprint claim: the KV carry of an undersubscribed pool is
+    smaller than the slab's, and the same pool at twice the max_len is
+    byte for byte as large (only the page tables grow)."""
+
+    def carry_bytes(engine):
+        return sum(
+            leaf.nbytes
+            for leaf in jax.tree_util.tree_leaves(engine.manager.cache)
+        )
+
+    slab = _engine(toy, paged=False)
+    pool = _engine(toy, paged=True, pages=8)
+    pool2 = _engine(toy, paged=True, pages=8, max_len=128)
+    assert carry_bytes(pool) == carry_bytes(pool2)
+    assert carry_bytes(pool) < carry_bytes(slab)
+
+
 def test_paged_decode_logits_bitwise_equal_to_slab(toy):
     """Stronger than token parity: the decode-step logits of the active
     row are BITWISE equal between layouts (pages tile max_len exactly,

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd_pkg
 from horovod_tpu import analysis
@@ -222,8 +222,8 @@ def test_zero3_matches_zero1_update_math_bitexact(hvd):
 def test_zero3_param_residency_is_world_fold_smaller(hvd):
     """The stage-3 acceptance number, measured from the actual arrays:
     between-step resident params bytes drop world-fold (>= 1.8x at any
-    world >= 2) — the live-buffer claim bench_zero.py re-measures with
-    step timing and memory_analysis."""
+    world >= 2); the compiler's view of the same claim is the next
+    test's."""
     rng = np.random.default_rng(2)
     params, _, _ = _problem(rng, d_in=32, d_out=16)
     o3 = hvd_pkg.ShardedDistributedOptimizer(
@@ -242,6 +242,30 @@ def test_zero3_param_residency_is_world_fold_smaller(hvd):
     assert full / per_rank >= 1.8
     # padding overhead stays sub-2x of the ideal 1/world split
     assert per_rank <= 2 * full / WORLD
+
+
+def test_zero3_compiled_step_takes_fewer_argument_bytes(hvd):
+    """The compiler's own view of the residency claim: per device, the
+    compiled ZeRO-3 step takes fewer argument bytes than the ZeRO-1
+    step of the same problem (params stopped replicating)."""
+    mesh = hvd_pkg.mesh()
+    rng = np.random.default_rng(2)
+    params, x, y = _problem(rng, d_in=64, d_out=32)
+
+    def opt(stage):
+        return hvd_pkg.ShardedDistributedOptimizer(
+            optax.adam(1e-2), zero_stage=stage, overlap_buckets=2,
+            overlap_min_bytes=0,
+        )
+
+    o1, o3 = opt(1), opt(3)
+    m1 = _make_z1_step(o1, mesh).lower(
+        params, o1.init(params), x, y
+    ).compile().memory_analysis()
+    m3 = _make_z3_step(o3, mesh).lower(
+        o3.init_params(params), o3.init(params), x, y
+    ).compile().memory_analysis()
+    assert m3.argument_size_in_bytes < m1.argument_size_in_bytes
 
 
 def test_zero_steps_do_not_retrace(hvd):
@@ -275,7 +299,14 @@ def test_zero_steps_do_not_retrace(hvd):
         return optax.apply_updates(p, u), st
 
     z2 = jax.jit(z2)
-    p = params
+    # every argument placed as the step's in_specs say (chip_smoke.py
+    # does the same): call 2 is fed call 1's mesh-sharded outputs, and
+    # an unplaced first call would be another signature
+    def put(tree, spec):
+        return jax.device_put(tree, NamedSharding(mesh, spec))
+
+    x, y = put((x, y), P(hvd_pkg.WORLD_AXIS))
+    p, s2 = put(params, P()), put(s2, o2.state_spec())
     for _ in range(5):
         p, s2 = z2(p, s2, x, y)
     assert traces["z2"] == 1, "ZeRO-2 step retraced"
@@ -284,7 +315,8 @@ def test_zero_steps_do_not_retrace(hvd):
         optax.adam(1e-2), zero_stage=3, overlap_buckets=2,
         overlap_min_bytes=0,
     )
-    ps3, s3 = o3.init_params(params), o3.init(params)
+    ps3, s3 = put((o3.init_params(params), o3.init(params)),
+                  o3.state_spec())
 
     @partial(
         jax.shard_map, mesh=mesh,
