@@ -721,11 +721,20 @@ class ExpertFFN(nn.Module):
     renormalised over all k chosen (held here or not) and scaled; the
     layer returns ``shared(x) + sum over the chosen experts held here of
     gate_e * expert_e(x)``. Dropless with static shapes: the chosen
-    (token, expert) pairs are sorted by held expert and pass a grouped
-    matmul whose work follows the rows really routed here
-    (parallel/moe.py: :func:`route_top_k`, :func:`held_experts_ffn`).
-    What experts held elsewhere would add is left out: their exchange is
-    parallel/moe.py's, and no code stands in for it here.
+    (token, expert) pairs are sorted by held expert; the grouped matmuls
+    visit the held groups' rows, and every other pass (the gather into
+    sorted rows, the activation, the weighted add into the tokens' rows,
+    and their transposes) takes as many chunks of the sorted rows as hold
+    a row routed here, so the work follows the rows really routed here,
+    forward and backward (parallel/moe.py: :func:`route_top_k`,
+    :func:`held_experts_ffn`). What experts held elsewhere would add is
+    left out: their exchange is parallel/moe.py's, and no code stands in
+    for it here.
+
+    Sown into ``intermediates``: ``chosen`` (the experts of every token),
+    ``rows_routed`` (the (token, choice) pairs held here) and
+    ``rows_window`` (the rows the passes took: ``rows_routed`` rounded up
+    to whole chunks of ``moe.window_chunk(tokens * top_k)``).
 
     ``select_bias`` is the load-balancing bias: a leaf of the tree that
     only selection reads, so its gradient is zero; its balancing update
@@ -762,16 +771,18 @@ class ExpertFFN(nn.Module):
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
         held = last - first
         w_gate, w_up, w_down = (
-            self.param(name, init, shape, jnp.float32).astype(cfg.dtype)
+            self.param(name, init, shape, jnp.float32)
             for name, shape in (
                 ("w_gate", (held, d, f)), ("w_up", (held, d, f)),
                 ("w_down", (held, f, d)),
             )
         )
         tokens = tokens.astype(cfg.dtype)
-        y = _moe.held_experts_ffn(
+        y, rows_routed, rows_window = _moe.held_experts_ffn(
             tokens, chosen, gates, w_gate, w_up, w_down, first
         )
+        self.sow("intermediates", "rows_routed", rows_routed)
+        self.sow("intermediates", "rows_window", rows_window)
         if cfg.moe_shared_d_ff:
             with jax.named_scope("moe_shared"):
                 y = y + GatedMLP(cfg, cfg.moe_shared_d_ff, name="shared")(
@@ -993,16 +1004,18 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     reads and no more, by name (``ops/flash_attention.py:
     RESIDUAL_NAMES``): q, k and v as the kernels take them, the
     attention output and one lane of ``lse``; of an expert layer also
-    the routing's integer results (``parallel/moe.py: ROUTING_NAMES``,
-    ``tokens x moe_top_k`` int32 each). The backward's second forward
-    then runs no flash forward, no RoPE or head transpose, no ``top_k``
-    and no sort of the dispatch, and no q/k/v projection unless QK-norm
-    reads its output (its backward needs the value before the norm);
-    it recomputes the rest: norms, the gate's and the output
-    projection, the feed-forward; in an expert layer the router, the
-    dispatch's gathers and the grouped matmuls, whose tensors of
-    ``tokens x moe_top_k`` rows stay unkept (2 x 8192 tokens, top-8,
-    2048 wide: 512 MiB each). Offered where the model rides the kernels
+    the routing's integer results (``parallel/moe.py: ROUTING_NAMES``:
+    the chosen experts and the sorted order, ``tokens x moe_top_k``
+    int32 each). The backward's second forward then runs no flash
+    forward, no RoPE or head transpose, no ``top_k`` and no sort of the
+    dispatch, and no q/k/v projection unless QK-norm reads its output
+    (its backward needs the value before the norm); it recomputes the
+    rest: norms, the gate's and the output projection, the
+    feed-forward; in an expert layer the router, the dispatch's passes
+    over the routed rows' window and the grouped matmuls, whose
+    buffers of ``tokens x moe_top_k`` rows stay unkept (2 x 8192
+    tokens, top-8, 2048 wide: 512 MiB each, written up to the
+    window). Offered where the model rides the kernels
     (``cfg.wants_flash()``): the dense path has no such names.
 
     ``recompute_all``: each block keeps its input alone, where no rung
@@ -1033,9 +1046,9 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     experts = 4 * cfg.moe_experts_total + 2 * cfg.moe_shared_d_ff * itemsize
     expert_layers = cfg.expert_layers()
     # by name on both rungs: the kernels' residuals and, of an expert
-    # layer, the chosen experts and the dispatch's two permutations
+    # layer, the chosen experts and the dispatch's sorted order
     named = (
-        cfg.num_layers * attention + expert_layers * 3 * 4 * cfg.moe_top_k
+        cfg.num_layers * attention + expert_layers * 2 * 4 * cfg.moe_top_k
     )
     # bytes a token over all layers; 0: the rung is not offered
     rungs = {
@@ -1110,15 +1123,19 @@ def _span_at_trace_time(call):
 
 def _tag_layer_kinds(span, cfg: TransformerConfig, tokens: int):
     """What the per-layer kinds make of the model, on the trace span."""
+    from ..parallel.moe import window_chunk
+
     first, last = cfg.moe_experts_held or (0, cfg.moe_experts_total)
     span.tag(
         layer_kinds=",".join(cfg.layer_kinds),
         experts_total=cfg.moe_experts_total,
         experts_held=last - first,
         top_k=cfg.moe_top_k,
-        # rows of an expert layer's sorted buffer: every choice of every
-        # token can land on this chip
+        # rows of an expert layer's sorted order: every choice of every
+        # token can land on this chip; and the rows its dispatch takes at
+        # a time, as many times as hold a routed row
         moe_rows_capacity=tokens * cfg.moe_top_k,
+        moe_rows_chunk=window_chunk(tokens * cfg.moe_top_k),
     )
 
 

@@ -403,10 +403,12 @@ def moe_ffn(
 
 # The names a ``jax.checkpoint`` policy keeps the routing's integer results
 # by (``save_only_these_names(*ROUTING_NAMES)``): a token's chosen experts
-# and the two permutations of the dispatch, ``tokens x top_k`` int32 each,
-# so that remat's second forward repeats neither ``top_k`` nor the two
-# sorts. Under no policy the names cost nothing.
-ROUTING_NAMES = ("moe_chosen", "moe_order", "moe_inverse")
+# and the dispatch's sorted order of the (token, choice) pairs, ``tokens x
+# top_k`` int32 each, so that remat's second forward repeats neither
+# ``top_k`` nor the sort. The group sizes and the routed count are a
+# compare and a sum over the chosen experts, and are computed again. Under
+# no policy the names cost nothing.
+ROUTING_NAMES = ("moe_chosen", "moe_order")
 
 
 def route_top_k(logits, select_bias, top_k: int, *, score: str = "sigmoid",
@@ -427,7 +429,12 @@ def route_top_k(logits, select_bias, top_k: int, *, score: str = "sigmoid",
         raise ValueError(f"score {score!r} is not sigmoid or softmax")
     _, chosen = lax.top_k(scores + lax.stop_gradient(select_bias), top_k)
     chosen = checkpoint_name(chosen.astype(jnp.int32), "moe_chosen")
-    gates = jnp.take_along_axis(scores, chosen, axis=-1)
+    # the chosen experts' scores, picked by comparison: the same values as
+    # a gather along the experts, which on the chip costs 1 ms a layer for
+    # 8 of 128 scores a token (PERF.md section 6, PR 30)
+    picked = chosen[..., None] == jnp.arange(
+        scores.shape[-1], dtype=chosen.dtype)
+    gates = jnp.sum(jnp.where(picked, scores[..., None, :], 0), axis=-1)
     if norm:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
     return chosen, gates * scale
@@ -447,81 +454,270 @@ def _gmm_tiling(rows: int, k: int, n: int):
     return tm, min(k, 1024), min(n, 1024)
 
 
-def _grouped_matmul(rows, weights, group_sizes):
-    """``rows [m, k]`` sorted by group times ``weights [groups, k, n]``,
-    the first ``group_sizes[g]`` rows with group 0's matrix and so on
-    (Pallas megablox: only tiles that hold a group's rows are visited,
-    so the work follows ``sum(group_sizes)``, not m). Rows past the last
-    group are NOT written: the caller masks them."""
-    from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _grouped_matmul(rows, weights, group_sizes, transpose_rhs=False):
+    """``rows [m, k]`` sorted by group times ``weights [groups, k, n]``
+    (``[groups, n, k]`` where ``transpose_rhs``), the first
+    ``group_sizes[g]`` rows with group 0's matrix and so on (Pallas
+    megablox: only tiles that hold a group's rows are visited, so the work
+    follows ``sum(group_sizes)``, not m). Rows past the last group are NOT
+    written: the caller masks them."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, k = rows.shape
-    return _megablox.gmm(
-        rows, weights, group_sizes, rows.dtype,
-        _gmm_tiling(m, k, weights.shape[-1]), None, None, False,
-        jax.default_backend() != "tpu",
+    n = weights.shape[1 if transpose_rhs else 2]
+    return gmm(
+        rows, weights, group_sizes, rows.dtype, _gmm_tiling(m, k, n),
+        transpose_rhs=transpose_rhs, interpret=_interpret(),
     )
 
 
-@jax.custom_vjp
-def _rows_to_sorted(x, order, inverse, here):
-    """``x [tokens, d]`` -> the (token, choice) pairs' rows in sorted order
-    ``[tokens * k, d]``. A gather; its transpose is a gather too, by the
-    inverse permutation, and it reads no row of a pair that is not held
-    here (such rows of the cotangent were never written)."""
-    return x[order // here.shape[1]]
+def _grouped_outer(rows, grads, group_sizes, dtype):
+    """``rows[group]^T @ grads[group]`` for every group, ``[groups, k, n]``
+    in ``dtype``, for ``rows [m, k]`` and ``grads [m, n]`` sorted by group:
+    the weights' gradient of :func:`_grouped_matmul` (megablox ``tgmm``;
+    rows past the last group are not read)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 
-
-def _rows_to_sorted_fwd(x, order, inverse, here):
-    return _rows_to_sorted(x, order, inverse, here), (inverse, here)
-
-
-def _rows_to_sorted_bwd(res, g):
-    inverse, here = res
-    pairs = g[inverse].reshape(*here.shape, g.shape[-1])
-    dx = jnp.sum(
-        jnp.where(here[..., None], pairs, 0).astype(jnp.float32), axis=1
+    (m, k), n = rows.shape, grads.shape[1]
+    # the kernel stages a tile of the result twice; in float32 a tile is
+    # twice a product's bytes: half the tile keeps the call in ~12 MiB
+    tm, tk, tn = _gmm_tiling(m, k, n)
+    return tgmm(
+        rows.swapaxes(0, 1), grads, group_sizes, dtype,
+        (tm, max(tk // 2, 1), tn), interpret=_interpret(),
     )
-    return dx.astype(g.dtype), None, None, None
 
 
-_rows_to_sorted.defvjp(_rows_to_sorted_fwd, _rows_to_sorted_bwd)
+def window_chunk(rows: int) -> int:
+    """Rows of the sorted (token, choice) pairs that a pass of the dispatch
+    takes at a time, from the shape alone: a sixteenth of all ``tokens x
+    top_k`` rows where that is a whole number of 8-row tiles (the grouped
+    matmul's least), else all of them at once."""
+    chunk = rows // 16
+    return rows if rows % 16 or chunk % 8 else chunk
+
+
+def _unwritten(rows: int, width: int, dtype, name: str, after):
+    """A buffer ``[rows, width]`` that nothing has written: the result of
+    a kernel without a body, so no fill runs over rows that no pass will
+    produce or read. (Zeros on the CPU's interpreter.) The kernel is
+    handed one element of ``after`` and reads nothing: the buffer then
+    comes to be when ``after`` is there, not at the start of the step."""
+    from jax.experimental import pallas as pl
+
+    return pl.pallas_call(
+        lambda after, out: None,
+        out_shape=jax.ShapeDtypeStruct((rows, width), dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY), name=f"unwritten_{name}",
+        interpret=_interpret(),
+    )(after[(slice(0, 1),) * after.ndim])
+
+
+def _over_window(fn, chunks, outs, *rows):
+    """``outs`` with the rows of chunk ``i`` set to ``fn(first row of the
+    chunk, *chunk i of rows)`` for every ``i < chunks`` (a traced count):
+    one pass over a window of the sorted rows. ``rows`` and ``outs`` have
+    one row a sorted pair; rows past the window stay as they were."""
+    chunk = window_chunk(rows[0].shape[0])
+
+    def one_chunk(i, outs):
+        lo = i * chunk
+        got = fn(lo, *(lax.dynamic_slice_in_dim(r, lo, chunk) for r in rows))
+        return tuple(lax.dynamic_update_slice_in_dim(o, g, lo, 0)
+                     for o, g in zip(outs, got))
+
+    return lax.fori_loop(0, chunks, one_chunk, tuple(outs))
+
+
+def _add_window_to_tokens(fn, chunks, like, *rows):
+    """Float32 ``[tokens, d]``: the sum of the window's ``addends`` into
+    the rows ``tokens`` that ``fn`` gives them: ``(tokens, addends) =
+    fn(first row, *those rows of rows)``. A token's addends meet in
+    float32. On the chip a scatter-add costs ~0.8 ms a call before its
+    first row (PERF.md section 6, PR 30), so the window's chunks go four
+    to a scatter-add in a loop, and the one to three that are left in one
+    more, its size chosen by a ``switch``."""
+    chunk = window_chunk(rows[0].shape[0])
+    most = min(4, rows[0].shape[0] // chunk)
+
+    def add(total, lo, m):
+        tokens, addends = fn(lo, *(
+            lax.dynamic_slice_in_dim(r, lo, m * chunk) for r in rows))
+        return total.at[tokens].add(addends.astype(jnp.float32))
+
+    total = lax.fori_loop(
+        0, chunks // most, lambda i, total: add(total, i * most * chunk, most),
+        jnp.zeros(like.shape, jnp.float32))
+    lo = chunks // most * most * chunk
+    return lax.switch(
+        chunks % most,
+        [lambda total: total] + [
+            lambda total, m=m: add(total, lo, m) for m in range(1, most)],
+        total)
+
+
+def _swiglu(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+def _window(order, group_sizes):
+    """``(routed, chunks)``: the sorted rows that are routed here (the
+    others follow them) and the chunks of the sorted order that hold one."""
+    chunk = window_chunk(order.shape[0])
+    routed = jnp.sum(group_sizes)
+    return routed, (routed + chunk - 1) // chunk
+
+
+def _is_routed(lo, rows, routed):
+    """``[len(rows), 1]``: whether the sorted row, ``lo`` and on, is routed
+    here. A row past the routed count is in no group: no grouped matmul
+    wrote it, and what is read there must not be used."""
+    return (lo + jnp.arange(rows.shape[0]) < routed)[:, None]
 
 
 @jax.custom_vjp
-def _sorted_to_pairs(ys, order, inverse, here):
-    """Sorted rows ``[tokens * k, d]`` -> ``[tokens, k, d]`` by (token,
-    choice), zero for a pair that is not held here."""
-    pairs = ys[inverse].reshape(*here.shape, ys.shape[-1])
-    return jnp.where(here[..., None], pairs, 0)
+def _windowed_experts(x, gates, order, group_sizes, w_gate, w_up, w_down):
+    """``(y [tokens, d], rows_window)``: the held experts' part of the
+    layer's result from ``order``, the (token, choice) pairs sorted by held
+    expert with ``group_sizes`` rows each and the pairs held elsewhere
+    last, and the rows the passes took. The weights are cast to ``x``'s
+    type for the products."""
+    return _windowed_experts_fwd(
+        x, gates, order, group_sizes, w_gate, w_up, w_down)[0]
 
 
-def _sorted_to_pairs_fwd(ys, order, inverse, here):
-    return _sorted_to_pairs(ys, order, inverse, here), (order, here)
+# Both rules are jitted: every expert layer of a model has the same shapes,
+# so the passes' loops are traced once and not once a layer (1.7 s of the
+# cell's set-up where they are not, PERF.md section 6, PR 30).
+@jax.jit
+def _windowed_experts_fwd(x, gates, order, group_sizes, *weights):
+    w_gate, w_up, w_down = (w.astype(x.dtype) for w in weights)
+    pairs, top_k = order.shape[0], gates.shape[1]
+    routed, chunks = _window(order, group_sizes)
+    with jax.named_scope("moe_dispatch"):
+        rows, = _over_window(
+            lambda lo, pairs: (x[pairs // top_k],), chunks,
+            [_unwritten(pairs, x.shape[1], x.dtype, "rows", order)], order)
+    with jax.named_scope("moe_experts"):
+        gate = _grouped_matmul(rows, w_gate, group_sizes)
+        up = _grouped_matmul(rows, w_up, group_sizes)
+    hidden, = _over_window(
+        lambda lo, gate, up: (_swiglu(gate, up),), chunks,
+        [_unwritten(pairs, gate.shape[1], x.dtype, "hidden", up)], gate, up)
+    with jax.named_scope("moe_experts"):
+        out = _grouped_matmul(hidden, w_down, group_sizes)
+
+    def weighted(lo, pairs, out):
+        weight = gates.reshape(-1)[pairs][:, None]
+        return pairs // top_k, jnp.where(
+            _is_routed(lo, pairs, routed),
+            out.astype(jnp.float32) * weight, 0)
+
+    with jax.named_scope("moe_combine"):
+        y = _add_window_to_tokens(weighted, chunks, x, order, out)
+        y = y.astype(x.dtype)
+    return (y, chunks * window_chunk(pairs)), (
+        x, gates, order, group_sizes, weights, rows, gate, up, hidden, out)
 
 
-def _sorted_to_pairs_bwd(res, g):
-    order, here = res
-    g = jnp.where(here[..., None], g, 0).reshape(-1, g.shape[-1])
-    return g[order], None, None, None
+@jax.jit
+def _windowed_experts_bwd(res, cotangents):
+    """The transpose over the same window: the combine's is a gather of
+    ``dy`` by token, the gather's a scatter-add into ``dx``; a token's
+    rows meet in float32, and the weights' gradients leave the grouped
+    kernel in float32 for whoever holds the weights in it."""
+    x, gates, order, group_sizes, weights, rows, gate, up, hidden, out = res
+    w_gate, w_up, w_down = (w.astype(x.dtype) for w in weights)
+    dy, _ = cotangents
+    pairs, top_k = order.shape[0], gates.shape[1]
+    chunk = window_chunk(pairs)
+    routed, chunks = _window(order, group_sizes)
+
+    def combine_transposed(lo, pairs, out):
+        dweighted = dy[pairs // top_k].astype(jnp.float32)
+        dweight = jnp.sum(jnp.where(
+            _is_routed(lo, pairs, routed),
+            out.astype(jnp.float32) * dweighted, 0), axis=1)
+        weight = gates.reshape(-1)[pairs][:, None]
+        return (dweighted * weight).astype(x.dtype), dweight
+
+    with jax.named_scope("moe_combine"):
+        dout, dweights = _over_window(
+            combine_transposed, chunks,
+            [_unwritten(pairs, x.shape[1], x.dtype, "dout", dy),
+             jnp.zeros((pairs,), jnp.float32)], order, out)
+
+        def to_its_pair(i, dgates):
+            at = lax.dynamic_slice_in_dim(order, i * chunk, chunk)
+            return dgates.at[at].set(
+                lax.dynamic_slice_in_dim(dweights, i * chunk, chunk),
+                unique_indices=True)
+
+        dgates = lax.fori_loop(
+            0, chunks, to_its_pair, jnp.zeros((pairs,), jnp.float32)
+        ).reshape(gates.shape)
+    with jax.named_scope("moe_experts"):
+        dhidden = _grouped_matmul(dout, w_down, group_sizes, True)
+        dw_down = _grouped_outer(hidden, dout, group_sizes, jnp.float32)
+    dgate, dup = _over_window(
+        lambda lo, dhidden, gate, up: jax.vjp(_swiglu, gate, up)[1](dhidden),
+        chunks,
+        [_unwritten(pairs, gate.shape[1], x.dtype, name, dhidden)
+         for name in ("dgate", "dup")], dhidden, gate, up)
+    with jax.named_scope("moe_experts"):
+        drows = _grouped_matmul(dgate, w_gate, group_sizes, True)
+        drows_up = _grouped_matmul(dup, w_up, group_sizes, True)
+
+        def add_up(i, drows):
+            # the sum of the sorted rows' two cotangents, where the first is
+            both = (lax.dynamic_slice_in_dim(r, i * chunk, chunk)
+                    for r in (drows, drows_up))
+            return lax.dynamic_update_slice_in_dim(
+                drows, sum(both), i * chunk, 0)
+
+        drows = lax.fori_loop(0, chunks, add_up, drows)
+        dw_gate = _grouped_outer(rows, dgate, group_sizes, jnp.float32)
+        dw_up = _grouped_outer(rows, dup, group_sizes, jnp.float32)
+    with jax.named_scope("moe_dispatch"):
+        dx = _add_window_to_tokens(
+            lambda lo, pairs, drows: (
+                pairs // top_k,
+                jnp.where(_is_routed(lo, pairs, routed), drows, 0)),
+            chunks, x, order, drows).astype(x.dtype)
+    return (dx, dgates.astype(gates.dtype), None, None,
+            *(dw.astype(w.dtype)
+              for dw, w in zip((dw_gate, dw_up, dw_down), weights)))
 
 
-_sorted_to_pairs.defvjp(_sorted_to_pairs_fwd, _sorted_to_pairs_bwd)
+_windowed_experts.defvjp(_windowed_experts_fwd, _windowed_experts_bwd)
 
 
 def held_experts_ffn(x, chosen, gates, w_gate, w_up, w_down, first_held: int):
-    """The part of an expert layer's result that the experts held here
-    give: ``sum over a token's chosen experts e in [first_held, first_held
-    + held) of gates_e * W_down[e](silu(W_gate[e] x) * W_up[e] x)``, for
-    ``x [tokens, d]``, ``chosen``/``gates [tokens, k]`` and weights
-    ``[held, d, f]``, ``[held, d, f]``, ``[held, f, d]``.
+    """``(y, rows_routed, rows_window)``. ``y`` is the part of an expert
+    layer's result that the experts held here give: ``sum over a token's
+    chosen experts e in [first_held, first_held + held) of gates_e *
+    W_down[e](silu(W_gate[e] x) * W_up[e] x)``, for ``x [tokens, d]``,
+    ``chosen``/``gates [tokens, k]`` and weights ``[held, d, f]``,
+    ``[held, d, f]``, ``[held, f, d]`` of any float type (the products
+    run in ``x``'s).
 
     Dropless with static shapes: all ``tokens * k`` pairs are sorted by
     held expert (pairs whose expert is held elsewhere last), so every
-    choice of every token may land here; the grouped matmuls visit only
-    the rows of the held experts' groups, so the work follows the rows
-    really routed here."""
-    tokens, k = chosen.shape
+    choice of every token may land here. The work follows the rows really
+    routed here, ``rows_routed``: the grouped matmuls visit only the held
+    groups' row tiles, and every other pass (the gather of ``x`` into
+    sorted rows, the activation, the weighted add of the rows into their
+    tokens' float32 sums, and their transposes) is a loop over
+    ``ceil(rows_routed / chunk)`` chunks of ``window_chunk(tokens * k)``
+    sorted rows. Rows past that window, ``rows_window`` rows long, are
+    never produced or read: the buffers between the passes hold ``tokens *
+    k`` rows and are written up to the window. Where every pair is held
+    here the window is all of them."""
     held = w_gate.shape[0]
     with jax.named_scope("moe_dispatch"):
         local = chosen - first_held
@@ -529,21 +725,10 @@ def held_experts_ffn(x, chosen, gates, w_gate, w_up, w_down, first_held: int):
         key = jnp.where(here, local, held).reshape(-1)
         order = checkpoint_name(
             jnp.argsort(key, stable=True).astype(jnp.int32), "moe_order")
-        inverse = checkpoint_name(
-            jnp.argsort(order).astype(jnp.int32), "moe_inverse")
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0,
             dtype=jnp.int32,
         )
-        rows = _rows_to_sorted(x, order, inverse, here)
-    with jax.named_scope("moe_experts"):
-        gate = _grouped_matmul(rows, w_gate, group_sizes)
-        up = _grouped_matmul(rows, w_up, group_sizes)
-    hidden = jax.nn.silu(gate) * up
-    with jax.named_scope("moe_experts"):
-        out = _grouped_matmul(hidden, w_down, group_sizes)
-    with jax.named_scope("moe_combine"):
-        pairs = _sorted_to_pairs(out, order, inverse, here)
-        return jnp.sum(
-            pairs.astype(jnp.float32) * gates[..., None], axis=1
-        ).astype(x.dtype)
+    y, rows_window = _windowed_experts(
+        x, gates, order, group_sizes, w_gate, w_up, w_down)
+    return y, jnp.sum(group_sizes), rows_window

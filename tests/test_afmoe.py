@@ -9,6 +9,7 @@ top-2, window 8 at seq 32, one dense and four expert layers [s, s, s, f, s].
 import dataclasses
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -182,6 +183,82 @@ def test_collapsed_routing_drops_no_row(favoured, rows_here):
     assert float(jnp.max(jnp.abs((bumped - out)[0, 0]))) > 0
 
 
+# ------------- (c') the dispatch's window follows the rows routed here
+
+_HELD = (2, 6)
+_ROWS = 2 * SEQ * 2  # tokens x top-2
+_CHUNK = _ROWS // 16
+
+
+def _routing_with(rows_here):
+    """``chosen``, ``gates [tokens, 2]`` with exactly ``rows_here`` (token,
+    choice) pairs on the experts 2..5, spread over them and over the
+    tokens; every token's two experts differ."""
+    tokens = 2 * SEQ
+    held = np.arange(*_HELD)
+    elsewhere = np.array([0, 1, 6, 7])
+    here = rows_here // tokens + (np.arange(tokens) < rows_here % tokens)
+    rng = np.random.default_rng(rows_here)
+    chosen = np.stack([
+        rng.permutation(np.concatenate([
+            np.roll(held, t)[:n], np.roll(elsewhere, t)[:2 - n]]))
+        for t, n in enumerate(here)])
+    gates = rng.uniform(0.2, 1.0, (tokens, 2))
+    return (jnp.asarray(chosen, jnp.int32),
+            jnp.asarray(gates / gates.sum(-1, keepdims=True), jnp.float32))
+
+
+@pytest.mark.parametrize("rows_here", [
+    0, 1, _CHUNK - 1, _CHUNK, _CHUNK + 1, 5 * _CHUNK + 3, _ROWS,
+], ids=["none", "one", "chunk-less-1", "chunk", "chunk-and-1", "ragged",
+        "every-pair"])
+def test_the_window_follows_the_routed_rows_and_drops_none(
+        rows_here, monkeypatch):
+    _, ref = _family()
+    cfg_json = dict(_config(_HELD), num_shared_experts=0)
+    layer = _expert_layer(cfg_json, shared=False)
+    x = _layer_input()
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    chosen, gates = _routing_with(rows_here)
+    assert moe.window_chunk(_ROWS) == _CHUNK
+
+    # the layer, told its routing: what it sows is what the loop took
+    monkeypatch.setattr(moe, "route_top_k", lambda *a, **k: (chosen, gates))
+    out, state = layer.apply({"params": params}, x, mutable=["intermediates"])
+    sown = {k: int(v[0]) for k, v in state["intermediates"].items()
+            if k.startswith("rows_")}
+    here = (chosen >= _HELD[0]) & (chosen < _HELD[1])
+    assert sown["rows_routed"] == int(here.sum()) == rows_here
+    assert sown["rows_window"] == -(-rows_here // _CHUNK) * _CHUNK
+
+    # output and every gradient against the float32 reference
+    weigh = jax.random.normal(jax.random.PRNGKey(2), (2 * SEQ, 64))
+
+    def mine(x, gates, w_gate, w_up, w_down):
+        y, _, _ = moe.held_experts_ffn(
+            x, chosen, gates, w_gate, w_up, w_down, _HELD[0])
+        return jnp.sum(y * weigh), y
+
+    def theirs(x, gates, w_gate, w_up, w_down):
+        monkeypatch.setattr(ref, "_route", lambda *a: (chosen, gates))
+        y, _ = ref._experts(x, dict(w_gate=w_gate, w_up=w_up, w_down=w_down),
+                            cfg_json, "float32")
+        return jnp.sum(y * weigh), y
+
+    args = (x.reshape(-1, 64), gates,
+            params["w_gate"], params["w_up"], params["w_down"])
+    (_, y), grads = jax.value_and_grad(mine, range(5), has_aux=True)(*args)
+    (_, want), want_grads = jax.value_and_grad(
+        theirs, range(5), has_aux=True)(*args)
+    np.testing.assert_allclose(y, want, atol=2e-6, rtol=0)
+    np.testing.assert_allclose(out.reshape(-1, 64), want, atol=2e-6, rtol=0)
+    for name, g, r in zip(("x", "gates", "w_gate", "w_up", "w_down"),
+                          grads, want_grads):
+        scale = float(jnp.max(jnp.abs(r)))
+        assert (scale > 0) == (rows_here > 0), name
+        assert float(jnp.max(jnp.abs(g - r))) <= GRAD_RTOL * scale, name
+
+
 def test_routing_weights_are_normalised_over_all_k_and_scaled():
     logits = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
     bias = jnp.zeros(8).at[3].set(5.0)
@@ -286,14 +363,14 @@ def test_a_page_table_on_the_new_kinds_says_what_is_missing():
 
 # over the five layers at 2 x SEQ float32 tokens: the flash kernels' q, o
 # (64 wide), k, v (32) and one lse a head, and in the four expert layers
-# top-2's chosen experts and two permutations; save_matmuls' further outputs of
+# top-2's chosen experts and sorted order; save_matmuls' further outputs of
 # the gate (64), W_o (64), the q and k/v projections that QK-norm reads
 # (64 + 64), the last feed-forward matmul that the sandwich norm reads (64),
 # and gate and up (2 x 96) in the dense layer, the router's logits (8, in
 # float32 whatever the model's dtype) and the shared expert's gate and up
 # (2 x 32) in the four expert layers
 _ATTENTION_KEPT = 2 * SEQ * (
-    5 * (4 * (64 + 64 + 32 + 32) + 4 * 4) + 4 * 3 * 2 * 4)
+    5 * (4 * (64 + 64 + 32 + 32) + 4 * 4) + 4 * 2 * 2 * 4)
 _MATMULS_KEPT = _ATTENTION_KEPT + 2 * SEQ * 4 * (
     5 * (64 + 64 + 64 + 64 + 64) + 2 * 96 + 4 * (8 + 2 * 32))
 
@@ -332,7 +409,7 @@ def test_remat_climbs_the_ladder_where_experts_are_held_and_says_so(
         "layer_kinds": "window/dense,window/experts,window/experts,"
                        "full-nope/experts,window/experts",
         "experts_total": 8, "experts_held": 4, "top_k": 2,
-        "moe_rows_capacity": 2 * SEQ * 2,
+        "moe_rows_capacity": 2 * SEQ * 2, "moe_rows_chunk": 2 * SEQ * 2 // 16,
     }
 
 
@@ -348,8 +425,8 @@ def test_the_cell_of_the_benchmark_keeps_the_kernels_residuals():
     limit = int(15.74 * 2**30)
     assert T._param_count(cfg) == 705_474_304
     # bfloat16 q and o at 32 heads of 128, k and v at 4, an lse a head;
-    # top-8's chosen experts and two permutations in the four expert layers
-    per_token = 5 * ((2 * 4096 + 2 * 512) * 2 + 4 * 32) + 4 * 3 * 8 * 4
+    # top-8's chosen experts and sorted order in the four expert layers
+    per_token = 5 * ((2 * 4096 + 2 * 512) * 2 + 4 * 32) + 4 * 2 * 8 * 4
     assert T.remat_plan(cfg, 2 * 8192, limit) == (
         "save_attention", 2 * 8192 * per_token)
     assert T.remat_plan(cfg, 2 * 8192, 1 << 40)[0] == "save_matmuls"
@@ -368,11 +445,15 @@ def test_remat_plan_reckons_the_new_widths_of_a_dense_model():
 
 def test_the_scopes_name_the_expert_layer_and_the_attention_kind():
     model, params, tokens, _ = _built(_config(), remat=False)
+    # the compiled step's op_names, which the benchmark reads: the
+    # dispatch's rules are jitted, and a jitted function's own text names
+    # its operations from its own top
     text = jax.jit(jax.grad(lambda p: model.apply(
-        p, tokens, train=True).sum())).lower(params).as_text(
-            debug_info=True)
+        p, tokens, train=True).sum())).lower(params).compile().as_text()
     for scope in ("moe_route", "moe_dispatch", "moe_experts", "moe_shared",
-                  "moe_combine", "attn_window", "attn_full"):
+                  "moe_combine"):
+        assert re.search(rf"/moe/(jit\(\w+\)/)?{scope}/", text), scope
+    for scope in ("attn_window", "attn_full"):
         assert f"/{scope}" in text, scope
 
 
