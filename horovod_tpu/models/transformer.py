@@ -16,7 +16,10 @@ The same ``Block`` also builds the sparse long-context decoders
 without RoPE, and a dense gated feed-forward or an expert layer that is
 told which experts of the deployment it holds (:class:`ExpertFFN`);
 RMSNorm in a sandwich, bias-free projections, QK-norm and a gated
-attention output are fields of the one ``TransformerConfig``.
+attention output are fields of the one ``TransformerConfig``. A third
+attention kind, ``latent``, is multi-head latent attention: keys and values
+expanded from one low-rank row a token, a rotation on part of each head,
+and a key wider than the value.
 
 The distributed execution path (tp/sp/pp/ep over a mesh) lives in
 horovod_tpu/parallel/ — this module is the single-chip / pure-DP model.
@@ -119,11 +122,31 @@ class TransformerConfig:
     # be num_layers); None = every layer alike, as the fields above say.
     # Attention: "window" (the causal band of ``sliding_window``) or
     # "full" (causal, no band), each with "-nope" appended where the
-    # layer carries no position rotation although ``rope`` is on.
+    # layer carries no position rotation although ``rope`` is on; or
+    # "latent" (causal, no band; the ``latent`` fields below).
     # Feed-forward: "dense" (``d_ff`` wide, gated where ``ffn_gated``)
     # or "experts" (:class:`ExpertFFN`, the ``moe_*`` fields below).
     # E.g. ("window/dense", "window/experts", "full-nope/experts").
     layer_kinds: Optional[tuple] = None
+    # "latent" layers (multi-head latent attention without a low-rank q):
+    # q is projected to ``num_heads`` heads of ``qk_nope_head_dim +
+    # qk_rope_head_dim``; one joint projection gives ``kv_lora_rank``
+    # latent columns, which are normed and expanded to every head's
+    # ``qk_nope_head_dim`` of key and ``v_head_dim`` of value, and
+    # ``qk_rope_head_dim`` columns of key that all heads share. Only the
+    # rope parts are rotated (``rope_base``; ``rope`` must be on), so a
+    # head's key is ``qk_nope_head_dim + qk_rope_head_dim`` wide, its
+    # value ``v_head_dim``, and the scores' scale is the key width's
+    # root. ``head_dim``, ``num_kv_heads``, ``qk_norm`` and
+    # ``attn_output_gate`` are the other kinds'.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Rotated pairs are (x0, x1), (x2, x3), ... and not (x[i], x[i + d/2]):
+    # apply_rope brings them to the half-split order first (and leaves them
+    # there: q and k are permuted alike, so the scores are the same).
+    rope_interleave: bool = False
     # Width of one attention head; None = d_model // num_heads. Set where
     # heads x head_dim is not d_model.
     head_dim: Optional[int] = None
@@ -177,12 +200,14 @@ class TransformerConfig:
                 f"num_layers is {self.num_layers}"
             )
         attn, _, ffn = self.layer_kinds[layer].partition("/")
-        if attn.removesuffix("-nope") not in ("window", "full") or ffn not in (
-            "dense", "experts"
-        ):
+        if (
+            attn.removesuffix("-nope") not in ("window", "full")
+            and attn != "latent"
+        ) or ffn not in ("dense", "experts"):
             raise ValueError(
                 f"layer kind {self.layer_kinds[layer]!r} is not "
-                "'<window|full>[-nope]/<dense|experts>'"
+                "'<window|full>[-nope]/<dense|experts>' or "
+                "'latent/<dense|experts>'"
             )
         return attn, ffn
 
@@ -193,6 +218,14 @@ class TransformerConfig:
             return self.sliding_window, self.rope
         if kind.startswith("window") and not self.sliding_window:
             raise ValueError("a 'window' layer needs sliding_window")
+        if kind == "latent" and not (
+            self.rope and self.kv_lora_rank and self.qk_nope_head_dim
+            and self.qk_rope_head_dim and self.v_head_dim
+        ):
+            raise ValueError(
+                "a 'latent' layer needs rope, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
+            )
         return (
             self.sliding_window if kind.startswith("window") else None,
             self.rope and not kind.endswith("-nope"),
@@ -201,6 +234,21 @@ class TransformerConfig:
     def expert_layers(self) -> int:
         """How many layers' feed-forward is an expert layer."""
         return sum(k.endswith("/experts") for k in self.layer_kinds or ())
+
+    def latent_layers(self) -> int:
+        """How many layers' attention is of the kind ``latent``."""
+        return sum(k.startswith("latent/") for k in self.layer_kinds or ())
+
+    def head_widths(self, kind: Optional[str] = None):
+        """``(key width, value width, key/value heads)`` of a layer of
+        an attention kind: q and k are as wide as the key."""
+        if kind == "latent":
+            return (
+                self.qk_nope_head_dim + self.qk_rope_head_dim,
+                self.v_head_dim, self.num_heads,
+            )
+        head_dim = self.dim_per_head()
+        return head_dim, head_dim, self.num_kv_heads or self.num_heads
 
     def wants_flash(self) -> bool:
         """The configuration half of the flash gate: ``True``/``False``
@@ -274,11 +322,15 @@ class TransformerConfig:
         )
 
 
-def apply_rope(x, base: float = 10000.0, offset=0):
+def apply_rope(x, base: float = 10000.0, offset=0, interleave: bool = False):
     """Rotate [batch, seq, heads, head_dim] q or k by absolute position
     (RoFormer). Pairs are (x[..., :d/2], x[..., d/2:]) — the
     'rotate-half' convention — so the op is two multiplies and one
-    concat, fully XLA-fusible. fp32 trig regardless of input dtype;
+    concat, fully XLA-fusible. With ``interleave`` the pairs are (x[...,
+    2i], x[..., 2i+1]): the i-th pair still turns by the i-th frequency
+    and the result is left in the half-split order (real parts, then
+    imaginary parts), as the published latent-attention models do. fp32
+    trig regardless of input dtype;
     ``offset`` shifts positions: a scalar (sequence-parallel shards
     pass their global start — may be a traced value, e.g.
     axis_index·t_local) or a ``[batch]`` array (incremental decode:
@@ -301,7 +353,15 @@ def apply_rope(x, base: float = 10000.0, offset=0):
     if angles.ndim == 2:  # scalar offset: broadcast over batch
         cos, sin = cos[None], sin[None]
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
+    if interleave:
+        # strided slices (x[..., 0::2] would be a gather, and its
+        # transpose a scatter-add)
+        x1, x2 = (
+            jax.lax.slice_in_dim(x, first, d, stride=2, axis=3)
+            for first in (0, 1)
+        )
+    else:
+        x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
@@ -310,7 +370,9 @@ def apply_rope(x, base: float = 10000.0, offset=0):
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len=None, dtype=None):
     """Allocate an empty decode KV cache: one ``{"k", "v"}`` dict per
-    layer, each ``[batch, max_len, num_kv_heads, head_dim]`` of zeros.
+    layer, each ``[batch, max_len, num_kv_heads, head_dim]`` of zeros (a
+    ``latent`` layer's hold its expanded heads: the key at its own
+    width, the value at its own).
 
     This is the model half of the serving contract
     (horovod_tpu/serving/): the cache rides
@@ -331,16 +393,17 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len=None, dtype=None):
             f"KV cache max_len ({seq}) exceeds the learned position "
             f"table ({cfg.max_len}); raise cfg.max_len or use rope=True"
         )
-    kv_heads = cfg.num_kv_heads or cfg.num_heads
-    head_dim = cfg.dim_per_head()
     dt = cfg.dtype if dtype is None else dtype
-    return [
-        {
-            "k": jnp.zeros((batch, seq, kv_heads, head_dim), dt),
-            "v": jnp.zeros((batch, seq, kv_heads, head_dim), dt),
-        }
-        for _ in range(cfg.num_layers)
-    ]
+    cache = []
+    for layer in range(cfg.num_layers):
+        k_width, v_width, kv_heads = cfg.head_widths(
+            cfg.layer_kind(layer)[0]
+        )
+        cache.append({
+            "k": jnp.zeros((batch, seq, kv_heads, k_width), dt),
+            "v": jnp.zeros((batch, seq, kv_heads, v_width), dt),
+        })
+    return cache
 
 
 def _norm(cfg: TransformerConfig, **kwargs):
@@ -362,14 +425,67 @@ class MultiHeadAttention(nn.Module):
                  cache_index=None, pages=None, paged_attn=False):
         cfg = self.cfg
         window, rope = cfg.attention_kind(self.kind)
-        with jax.named_scope("attn_window" if window else "attn_full"):
+        scope = "attn_latent" if self.kind == "latent" else (
+            "attn_window" if window else "attn_full"
+        )
+        with jax.named_scope(scope):
             return self._attend(x, mask, lengths, cache, cache_index,
                                 pages, paged_attn, window, rope)
+
+    def _latent_qkv(self, x, offset):
+        """``(q, k, v)`` of a ``latent`` layer, as wide as the kernels
+        take them: q and k ``[.., heads, nope + rope]``, v ``[.., heads,
+        v_head_dim]``. What latent attention adds to a plain layer's
+        projections runs under the scope ``latent_proj``: the joint
+        down-projection, the latent's norm, the up-projection, the
+        rotation of the rope parts (the key's is one head that all heads
+        share) and the concatenations."""
+        cfg = self.cfg
+        nope, rope_dim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        rank, heads = cfg.kv_lora_rank, cfg.num_heads
+        q = nn.DenseGeneral(
+            (heads, nope + rope_dim), dtype=cfg.dtype,
+            use_bias=cfg.use_bias, name="q",
+        )(x)
+        with jax.named_scope("latent_proj"):
+            c = nn.Dense(
+                rank + rope_dim, dtype=cfg.dtype, use_bias=cfg.use_bias,
+                name="kv_a",
+            )(x)
+            latent = _norm(cfg, name="kv_norm")(c[..., :rank])
+            kv = nn.DenseGeneral(
+                (heads, nope + cfg.v_head_dim), dtype=cfg.dtype,
+                use_bias=cfg.use_bias, name="kv_b",
+            )(latent.astype(cfg.dtype))
+            rotate = functools.partial(
+                apply_rope, base=cfg.rope_base, offset=offset,
+                interleave=cfg.rope_interleave,
+            )
+            q = jnp.concatenate(
+                [q[..., :nope], rotate(q[..., nope:])], axis=-1
+            )
+            # [b, t, 1, rope]: one head
+            k_rope = rotate(jnp.expand_dims(c[..., rank:], -2))
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, (*kv.shape[:-1], rope_dim))],
+                axis=-1,
+            )
+        return q, k, kv[..., nope:]
 
     def _attend(self, x, mask, lengths, cache, cache_index, pages,
                 paged_attn, window, rope):
         cfg = self.cfg
-        head_dim = cfg.dim_per_head()
+        latent = self.kind == "latent"
+        # q and k are head_dim wide, v and the heads' output v_dim
+        head_dim, v_dim, kv_heads = cfg.head_widths(self.kind)
+        if pages is not None and latent:
+            raise NotImplementedError(
+                "pages= on a latent layer: the page pool holds keys and "
+                "values of one head_dim and no compressed latent row, and "
+                "the paged kernel no key wider than its value (ROADMAP "
+                "B-M4); the dense cached path (pages=None) works"
+            )
         if pages is not None and self.kind is not None:
             raise NotImplementedError(
                 "pages= with layer_kinds: the paged kernel has no band "
@@ -390,7 +506,11 @@ class MultiHeadAttention(nn.Module):
             raise ValueError(
                 "pages= (the paged-KV page table) requires cache="
             )
-        if cfg.num_kv_heads:
+        if latent:
+            q, k, v = self._latent_qkv(
+                x, 0 if cache is None else cache_index
+            )
+        elif cfg.num_kv_heads:
             if cfg.num_heads % cfg.num_kv_heads:
                 raise ValueError(
                     f"num_kv_heads ({cfg.num_kv_heads}) must divide "
@@ -413,10 +533,10 @@ class MultiHeadAttention(nn.Module):
             q, k, v = (
                 qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
             )
-        if cfg.qk_norm:
+        if cfg.qk_norm and not latent:
             q = _norm(cfg, name="q_norm")(q).astype(cfg.dtype)
             k = _norm(cfg, name="k_norm")(k).astype(cfg.dtype)
-        if rope:
+        if rope and not latent:  # a latent layer rotated its rope parts
             rope_offset = 0 if cache is None else cache_index
             q = apply_rope(q, cfg.rope_base, offset=rope_offset)
             k = apply_rope(k, cfg.rope_base, offset=rope_offset)
@@ -425,7 +545,7 @@ class MultiHeadAttention(nn.Module):
             """W_o on the heads' output, gated where the model says."""
             if cfg.attn_output_gate:
                 gate = nn.DenseGeneral(
-                    (cfg.num_heads, head_dim), dtype=cfg.dtype,
+                    (cfg.num_heads, v_dim), dtype=cfg.dtype,
                     use_bias=False, name="gate",
                 )(x)
                 out = (
@@ -440,7 +560,8 @@ class MultiHeadAttention(nn.Module):
             return self._cached_attention(cfg, x, q, k, v, cache,
                                           cache_index, head_dim, project,
                                           window, pages=pages,
-                                          paged_attn=paged_attn)
+                                          paged_attn=paged_attn,
+                                          kv_heads=kv_heads)
         # lengths (right-padding) stays on the flash path — the kernels
         # take it natively; only ARBITRARY masks force dense.
         wanted = cfg.wants_flash()
@@ -467,10 +588,10 @@ class MultiHeadAttention(nn.Module):
                 lengths=lengths, window=window,
             )
             return project(out)
-        if cfg.num_kv_heads and cfg.num_kv_heads != cfg.num_heads:
+        if kv_heads != cfg.num_heads:
             # dense fallback materializes the head repeat the flash
             # path avoids
-            rep = cfg.num_heads // cfg.num_kv_heads
+            rep = cfg.num_heads // kv_heads
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         # scores in fp32 for softmax stability
@@ -509,7 +630,7 @@ class MultiHeadAttention(nn.Module):
 
     def _cached_attention(self, cfg, x, q, k, v, cache, cache_index,
                           head_dim, project, window, pages=None,
-                          paged_attn=False):
+                          paged_attn=False, *, kv_heads):
         """Incremental-decode attention: write this call's k/v into the
         per-slot cache at ``cache_index`` (each batch row at its own
         position — prefill passes t=prompt tokens at index 0, decode
@@ -591,7 +712,7 @@ class MultiHeadAttention(nn.Module):
         if pages is not None and paged_attn:
             from ..ops import paged_attention as _pa
 
-            r = cfg.num_heads // (cfg.num_kv_heads or cfg.num_heads)
+            r = cfg.num_heads // kv_heads
             reason = _pa.unsupported_reason(
                 head_dim, page_tokens, queries=t * r
             )
@@ -629,8 +750,8 @@ class MultiHeadAttention(nn.Module):
                 return g.reshape(b, seq, *pool.shape[2:])
 
             kk, vv = _gather(k_cache), _gather(v_cache)
-        if cfg.num_kv_heads and cfg.num_kv_heads != cfg.num_heads:
-            rep = cfg.num_heads // cfg.num_kv_heads
+        if kv_heads != cfg.num_heads:
+            rep = cfg.num_heads // kv_heads
             kk = jnp.repeat(kk, rep, axis=2)
             vv = jnp.repeat(vv, rep, axis=2)
         scores = jnp.einsum(
@@ -951,6 +1072,18 @@ def _param_count(cfg: TransformerConfig) -> int:
         + q_width * d + bias * d
         + (2 * per_norm * head_dim if cfg.qk_norm else 0)
     )
+    # a latent layer: q, the joint down-projection, the latent's norm,
+    # the up-projection to every head's key and value, the output
+    k_width, v_width, heads = cfg.head_widths("latent")
+    rank, rope_dim = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    latent = (
+        (d + bias) * (heads * k_width + rank + rope_dim)
+        + per_norm * rank
+        + (rank + bias) * heads * (k_width - rope_dim + v_width)
+        + (d * heads * v_width if cfg.attn_output_gate else 0)
+        + heads * v_width * d + bias * d
+    )
+    latent_layers = cfg.latent_layers()
     if cfg.moe_experts:  # MoEFFN: router and bank, biases always
         dense = cfg.moe_experts * (d + 1 + 2 * d * cfg.d_ff + cfg.d_ff + d)
     elif cfg.ffn_gated:
@@ -967,7 +1100,8 @@ def _param_count(cfg: TransformerConfig) -> int:
     expert_layers = cfg.expert_layers()
     return (
         cfg.vocab_size * d + (0 if cfg.rope else cfg.max_len * d)
-        + cfg.num_layers * (attention + norms)
+        + (cfg.num_layers - latent_layers) * attention
+        + latent_layers * latent + cfg.num_layers * norms
         + expert_layers * experts + (cfg.num_layers - expert_layers) * dense
         + per_norm * d + (d + bias) * cfg.vocab_size
     )
@@ -1018,6 +1152,14 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     window). Offered where the model rides the kernels
     (``cfg.wants_flash()``): the dense path has no such names.
 
+    A ``latent`` layer is reckoned at its own widths: by name q and k at
+    the key's width (the nope and rope parts together), v and the output
+    at the value's, every head its own; of its matmuls ``save_matmuls``
+    keeps the joint down-projection's output, which the latent's norm
+    reads, and the output projection's (q and the up-projection's output
+    reach the kernels through slices, a rotation and concatenations,
+    whose backward reads no value).
+
     ``recompute_all``: each block keeps its input alone, where no rung
     fits and where the limit cannot be read (CPU). ``off``:
     ``cfg.remat`` is not set."""
@@ -1030,6 +1172,12 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     kv_width = 2 * (cfg.num_kv_heads or cfg.num_heads) * cfg.dim_per_head()
     # q, k and v, the attention output, one float32 lse lane a head
     attention = (2 * q_width + kv_width) * itemsize + 4 * cfg.num_heads
+    # the same of a latent layer: q and k at the key's width, v and the
+    # output at the value's, every head its own
+    k_width, v_width, heads = cfg.head_widths("latent")
+    latent = 2 * heads * (k_width + v_width) * itemsize + 4 * heads
+    latent_layers = cfg.latent_layers()
+    plain_layers = cfg.num_layers - latent_layers
     # the output gate's and the output projection's outputs; the q and
     # k/v projections' where a norm reads them (through RoPE and the
     # head transpose alone the backward needs no value); the last
@@ -1037,6 +1185,14 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     matmuls = (
         (q_width if cfg.attn_output_gate else 0) + cfg.d_model
         + (q_width + kv_width if cfg.qk_norm else 0)
+        + (cfg.d_model if cfg.sandwich_norm else 0)
+    ) * itemsize
+    # of a latent layer: the joint down-projection's output, which the
+    # latent's norm reads (q and the up-projection's output reach the
+    # kernels through slices, a rotation and concatenations alone)
+    latent_matmuls = (
+        (heads * v_width if cfg.attn_output_gate else 0) + cfg.d_model
+        + cfg.kv_lora_rank + cfg.qk_rope_head_dim
         + (cfg.d_model if cfg.sandwich_norm else 0)
     ) * itemsize
     # the first feed-forward matmuls' outputs (gate and up of a gated
@@ -1048,12 +1204,14 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     # by name on both rungs: the kernels' residuals and, of an expert
     # layer, the chosen experts and the dispatch's sorted order
     named = (
-        cfg.num_layers * attention + expert_layers * 2 * 4 * cfg.moe_top_k
+        plain_layers * attention + latent_layers * latent
+        + expert_layers * 2 * 4 * cfg.moe_top_k
     )
     # bytes a token over all layers; 0: the rung is not offered
     rungs = {
         "save_matmuls": 0 if cfg.moe_experts else (
-            named + cfg.num_layers * matmuls + expert_layers * experts
+            named + plain_layers * matmuls + latent_layers * latent_matmuls
+            + expert_layers * experts
             + (cfg.num_layers - expert_layers) * dense
         ),
         "save_attention": named if cfg.wants_flash() else 0,
@@ -1137,6 +1295,10 @@ def _tag_layer_kinds(span, cfg: TransformerConfig, tokens: int):
         moe_rows_capacity=tokens * cfg.moe_top_k,
         moe_rows_chunk=window_chunk(tokens * cfg.moe_top_k),
     )
+    if cfg.latent_layers():
+        k_width, v_width, _ = cfg.head_widths("latent")
+        span.tag(qk_head_dim=k_width, v_head_dim=v_width,
+                 kv_lora_rank=cfg.kv_lora_rank)
 
 
 class Transformer(nn.Module):

@@ -148,7 +148,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         n_blocks = _length_bound(kv_len, block_k, n_blocks)
     if window is not None:
         start = _window_start(qi, block_q, block_k, window)
-    d = q_ref.shape[-1]
+    d_v = v_ref.shape[-1]  # the value's width, which may not be the key's
 
     def body(j, carry):
         m, l, acc = carry
@@ -178,7 +178,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     # 1-D vectors hit lowering gaps
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    acc0 = jnp.zeros((block_q, d_v), jnp.float32)
     m, l, acc = jax.lax.fori_loop(start, n_blocks, body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
@@ -296,7 +296,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             n_blocks,
             ((ki + 1) * block_k - 1 + window - 1) // block_q + 1,
         )
-    d = k_ref.shape[-1]
+    d, d_v = k_ref.shape[-1], v_ref.shape[-1]
 
     def member_body(gm, i, dk, dv):
         q = q_ref[gm, pl.dslice(i * block_q, block_q), :].astype(
@@ -356,7 +356,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         return dk, dv
 
     dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
+    dv0 = jnp.zeros((block_k, d_v), jnp.float32)
     dk, dv = jax.lax.fori_loop(start, n_blocks, body, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -422,6 +422,7 @@ def bwd_vmem_bytes(
     h_per_kv: int = 1,
     itemsize: int = 2,
     block_k: int = None,
+    d_v: int = None,
 ) -> int:
     """Per-program VMEM staging estimate for the dK/dV backward kernel
     — the family's largest stager. With grouped-query attention it
@@ -429,12 +430,16 @@ def bwd_vmem_bytes(
     blocks for q/do/o plus an (r, seq, lanes) fp32 lse), so the
     footprint grows r-fold on top of the whole-sequence staging the
     module header documents (ADVICE r4). e.g. r=8, seq=4096, d=128,
-    bf16: ~25 MiB. An estimate of what is staged, not of what Mosaic
-    allocates: see the budget's note for where the chip really stops."""
+    bf16: ~25 MiB. ``d`` is the width of q and k, ``d_v`` that of v, do
+    and o (None: ``d``). An estimate in the units of the gate's budget,
+    not of what Mosaic allocates (that is :func:`staged_vmem_bytes`, by
+    which the call's limit is set): see the budget's note."""
     lanes = _interchange_lanes()
+    d_v = d if d_v is None else d_v
     bk = _pick_block(seq, block_k if block_k else DEFAULT_BLOCK)
-    stage = h_per_kv * seq * (3 * d * itemsize + 4 * lanes)  # q/do/o+lse
-    stage += 4 * bk * d * itemsize  # k/v in-blocks + dk/dv out-blocks
+    # q, do, o and the lse
+    stage = h_per_kv * seq * ((d + 2 * d_v) * itemsize + 4 * lanes)
+    stage += 2 * bk * (d + d_v) * itemsize  # k/v in-blocks, dk/dv out-blocks
     return stage
 
 
@@ -444,6 +449,7 @@ def fits_vmem(
     h_per_kv: int = 1,
     itemsize: int = 2,
     block_k: int = None,
+    d_v: int = None,
 ) -> bool:
     """Whether the dK/dV kernel's whole-sequence staging fits the
     per-core VMEM budget (HOROVOD_FLASH_VMEM_BUDGET bytes, default
@@ -452,7 +458,7 @@ def fits_vmem(
     hop engines have no blocked variant, fall back to their dense
     engines, and ``ring_flash_attention`` warns."""
     return (
-        bwd_vmem_bytes(seq, d, h_per_kv, itemsize, block_k)
+        bwd_vmem_bytes(seq, d, h_per_kv, itemsize, block_k, d_v)
         <= _vmem_budget()
     )
 
@@ -469,6 +475,47 @@ def _warn_vmem(seq, d, h_per_kv, itemsize, block_k=None, what=""):
         f" — use ring attention over more chips, more KV heads, or the"
         f" dense path.",
         stacklevel=3,
+    )
+
+
+# What Mosaic holds in VMEM, as the v5e's compiler counts it (compiled for a
+# described chip at the shapes of tests/test_tpu_compile.py, PR 31). This is
+# the one count of Mosaic's bytes here; :func:`bwd_vmem_bytes` is in the
+# units of the gate's budget, which are not bytes of VMEM (ROADMAP A9).
+# Unless it is told otherwise a kernel may hold 16 MiB (the "scoped" limit).
+# Beside its whole-sequence operands a kernel here held 1.0-4.8 MiB (its
+# blocks in and out, the score tile, the float32 temporaries). The largest
+# staging that compiled at the default was 10 MiB (dK/dV, 4096 x 128); all
+# three kernels were refused at 2 x 8192 x 32 heads of a 192-wide key and a
+# 128-wide value (forward and dQ: 12 MiB staged, 16.03 MiB held; dK/dV: 24
+# and 25.5), and so was the whole-sequence dK/dV kernel at 4096 x 192/128
+# (12 and 16.84) and at 8192 x 128 or x 64 with one head a group (20 and
+# 21), which :func:`fits_vmem` has always let through.
+_SCOPED_VMEM_DEFAULT = 16 * 2**20
+_VMEM_BESIDE_STAGED = 6 * 2**20  # the largest read, 4.84 MiB, and room
+
+
+def staged_vmem_bytes(rows: int, *operands) -> int:
+    """What Mosaic holds for ``rows`` rows of whole-sequence ``operands``,
+    each ``(last dimension, itemsize)``: every one twice (the pipeline's
+    two buffers), its rows rounded up to 128 lanes, so that a 192-wide
+    bf16 row and a width-1 float32 lse row take 512 bytes each."""
+    return 2 * rows * sum(
+        -(-width // 128) * 128 * itemsize for width, itemsize in operands
+    )
+
+
+def _staging_params(rows: int, *operands):
+    """Compiler parameters for a kernel that stages ``operands``
+    (:func:`staged_vmem_bytes`). None, the kernel as it always was, while
+    Mosaic's default limit holds them: the calls of every shape that ran
+    before PR 31 compile to what they were. Past it the limit is raised
+    to what is staged and as much again (the chip's VMEM is 128 MiB)."""
+    staged = staged_vmem_bytes(rows, *operands)
+    if staged + _VMEM_BESIDE_STAGED <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=2 * staged + _VMEM_BESIDE_STAGED
     )
 
 
@@ -500,8 +547,11 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
     """``h_per_kv`` > 1 = grouped-query attention: k/v carry bh//r rows
     (r = h_per_kv) and each q row p reads kv row p // r — exact because
     rows are batch-major/head-minor with kv-head groups contiguous.
-    ``window`` = causal sliding window width (requires causal)."""
+    ``window`` = causal sliding window width (requires causal). v may be
+    narrower or wider than q and k: the scores contract over q's width,
+    the output is as wide as v."""
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     scale = 1.0 / (d ** 0.5)
     n_q = seq // block_q
     lanes = _interchange_lanes()
@@ -514,7 +564,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, seq, d), lambda b, i: (b // r, 0, 0)),
-        pl.BlockSpec((1, seq, d), lambda b, i: (b // r, 0, 0)),
+        pl.BlockSpec((1, seq, d_v), lambda b, i: (b // r, 0, 0)),
     ]
     operands = [q, k, v]
     if lens is not None:
@@ -525,15 +575,18 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
         grid=(bh, n_q),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec(
                 (1, block_q, lanes), lambda b, i: (b, i, 0)
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, seq, lanes), jnp.float32),
         ],
+        compiler_params=_staging_params(
+            seq, (d, k.dtype.itemsize), (d_v, v.dtype.itemsize)
+        ),
         interpret=_interpret(),
         name="flash_fwd",
     )(*operands)
@@ -610,6 +663,7 @@ def _flash_bwd_impl(
             lse_lane[..., None], (*lse_lane.shape, lanes)
         )
     bh, seq, d = q.shape
+    d_v = v.shape[-1]  # v, o and do; q, k and their gradients are d wide
     scale = 1.0 / (d ** 0.5)
     n_q = seq // block_q
     n_k = seq // block_k
@@ -619,9 +673,9 @@ def _flash_bwd_impl(
     dq_in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, seq, d), lambda b, i: (b // r, 0, 0)),
-        pl.BlockSpec((1, seq, d), lambda b, i: (b // r, 0, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        pl.BlockSpec((1, seq, d_v), lambda b, i: (b // r, 0, 0)),
+        pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
+        pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
         pl.BlockSpec(
             (1, block_q, lanes), lambda b, i: (b, i, 0)
         ),
@@ -633,9 +687,9 @@ def _flash_bwd_impl(
     dkv_in_specs = [
         pl.BlockSpec((r, seq, d), lambda b, i: (b, 0, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((r, seq, d), lambda b, i: (b, 0, 0)),
-        pl.BlockSpec((r, seq, d), lambda b, i: (b, 0, 0)),
+        pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
+        pl.BlockSpec((r, seq, d_v), lambda b, i: (b, 0, 0)),
+        pl.BlockSpec((r, seq, d_v), lambda b, i: (b, 0, 0)),
         pl.BlockSpec(
             (r, seq, lanes), lambda b, i: (b, 0, 0)
         ),
@@ -658,10 +712,13 @@ def _flash_bwd_impl(
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_staging_params(
+            seq, (d, k.dtype.itemsize), (d_v, v.dtype.itemsize)
+        ),
         interpret=_interpret(),
         name="flash_dq",
     )(*dq_operands)
-    if not fits_vmem(seq, d, r, q.dtype.itemsize, block_k):
+    if not fits_vmem(seq, d, r, q.dtype.itemsize, block_k, d_v):
         dk, dv = _dkv_blocked(
             q, k, v, do, o, lse, lens[::r] if padded else None,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
@@ -678,12 +735,17 @@ def _flash_bwd_impl(
         in_specs=dkv_in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        # the group's q, do, o and float32 lse
+        compiler_params=_staging_params(
+            r * seq, (d, q.dtype.itemsize), (d_v, do.dtype.itemsize),
+            (d_v, o.dtype.itemsize), (lanes, 4),
+        ),
         interpret=_interpret(),
         name="flash_dkv",
     )(*dkv_operands)
@@ -783,6 +845,12 @@ def flash_attention(
     recompute). Sequence length must be divisible by the chosen block
     sizes; blocks shrink automatically for short sequences.
 
+    v may have a head width of its own (latent attention: a 192-wide key
+    with a 128-wide value): d is the width of q and k, over which the
+    scores contract and whose root scales them; the output, ``dO`` and
+    ``dV`` are as wide as v, and nothing is padded to the wider of the
+    two.
+
     ``lengths`` ([batch] int): per-sequence valid token counts for
     right-padded batches — keys at or beyond a sequence's length are
     masked out of its softmax, outputs at padded query positions are
@@ -809,6 +877,11 @@ def flash_attention(
     and block by block within the band where it does not. Composes
     with lengths and GQA."""
     b, t, h, d = q.shape
+    if k.shape[-1] != d:
+        raise ValueError(
+            f"q and k must share their head width: q={d}, k={k.shape[-1]}"
+        )
+    d_v = v.shape[-1]
     if window is not None:
         if not causal:
             raise ValueError("window= requires causal=True")
@@ -828,8 +901,8 @@ def flash_attention(
     block_k = _pick_block(t, block_k)
 
     def to_bhtd(x):
-        hh = x.shape[2]
-        return x.transpose(0, 2, 1, 3).reshape(b * hh, t, d)
+        hh, width = x.shape[2:]
+        return x.transpose(0, 2, 1, 3).reshape(b * hh, t, width)
 
     if lengths is None:
         if h_per_kv == 1:
@@ -842,7 +915,7 @@ def flash_attention(
                 to_bhtd(q), to_bhtd(k), to_bhtd(v),
                 causal, block_q, block_k, h_per_kv, window,
             )
-        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        return out.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
 
     lens = jnp.asarray(lengths, jnp.int32)
     if lens.shape != (b,):
@@ -860,7 +933,7 @@ def flash_attention(
             to_bhtd(q), to_bhtd(k), to_bhtd(v), lens_bh,
             causal, block_q, block_k, h_per_kv, window,
         )
-    out = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
     # Zero padded QUERY rows OUTSIDE the custom_vjp. The kernel's raw
     # output there is ordinary finite attention over the valid keys
     # (rows are never masked, only columns) — zeroing is the API
@@ -981,6 +1054,7 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
     kv row or None. Same operands and results as the whole-sequence
     call in :func:`_flash_bwd_impl`."""
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     lanes = lse.shape[-1]
     n_q, n_k = seq // block_q, seq // block_k
     steps = _dkv_band_blocks(seq, block_q, block_k, causal, window)
@@ -996,9 +1070,9 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
     in_specs = [
         pl.BlockSpec((1, block_q, d), q_block),
         pl.BlockSpec((1, block_k, d), kv_block),
-        pl.BlockSpec((1, block_k, d), kv_block),
-        pl.BlockSpec((1, block_q, d), q_block),
-        pl.BlockSpec((1, block_q, d), q_block),
+        pl.BlockSpec((1, block_k, d_v), kv_block),
+        pl.BlockSpec((1, block_q, d_v), q_block),
+        pl.BlockSpec((1, block_q, d_v), q_block),
         pl.BlockSpec((1, block_q, lanes), q_block),
     ]
     operands = [q, k, v, do, o, lse]
@@ -1015,7 +1089,7 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), kv_block),
-            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d_v), kv_block),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -1023,7 +1097,7 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")

@@ -34,17 +34,22 @@ def float32_products():
         yield
 
 
-def _config(held=(0, 4)):
-    with open(os.path.join(HERE, "benchmark", "data", "tiny-afmoe.json")) as f:
+# the key that counts the experts held here, by model family
+_HELD_KEY = {"afmoe": "num_experts", "deepseek_v3": "n_routed_experts"}
+
+
+def _config(held=(0, 4), family="afmoe"):
+    name = "tiny-" + family.replace("_", "-") + ".json"
+    with open(os.path.join(HERE, "benchmark", "data", name)) as f:
         cfg = json.load(f)
     cfg["experts_held"] = list(held)
-    cfg["num_experts"] = held[1] - held[0]
+    cfg[_HELD_KEY[family]] = held[1] - held[0]
     return cfg
 
 
-def _family():
-    return (manifest.load_module("models", "afmoe"),
-            manifest.load_module("references", "afmoe"))
+def _family(family="afmoe"):
+    return (manifest.load_module("models", family),
+            manifest.load_module("references", family))
 
 
 def _built(cfg, seed=7, remat=True):
@@ -122,7 +127,7 @@ def _layer_input(seed=3):
 
 
 def _expert_layer(cfg_json, shared=True):
-    family, _ = _family()
+    family, _ = _family(cfg_json["model"])
     cfg = family.build_model(cfg_json).cfg
     if not shared:
         cfg = dataclasses.replace(cfg, moe_shared_d_ff=0)
@@ -136,9 +141,12 @@ def _share_of(params, held):
     return sliced
 
 
-def test_all_shares_and_the_shared_expert_once_make_the_uncut_layer():
-    _, ref = _family()
-    whole = _config((0, 8))
+@pytest.mark.parametrize("family", ["afmoe", "deepseek_v3"])
+def test_all_shares_and_the_shared_expert_once_make_the_uncut_layer(family):
+    # top-2 of 8 with one shared expert 32 wide, or two that are one MLP
+    # of 48: the same ExpertFFN under either family's keys
+    _, ref = _family(family)
+    whole = _config((0, 8), family)
     x = _layer_input()
     full = _expert_layer(whole).init(jax.random.PRNGKey(1), x)["params"]
     full = jax.tree.map(  # not the zero bias of init: let it choose
@@ -147,7 +155,7 @@ def test_all_shares_and_the_shared_expert_once_make_the_uncut_layer():
     uncut, _ = ref._experts(x.reshape(-1, 64), full, whole, "float32")
     total = ref._gated_mlp(x.reshape(-1, 64), full["shared"], "float32")
     for held in ((0, 2), (2, 4), (4, 6), (6, 8)):
-        part = _expert_layer(_config(held), shared=False).apply(
+        part = _expert_layer(_config(held, family), shared=False).apply(
             {"params": _share_of(full, held)}, x)
         assert float(jnp.max(jnp.abs(part))) > 0
         total = total + part.reshape(-1, 64)

@@ -60,6 +60,59 @@ def test_flash_kernels_compile_at_b2_s8192_gqa8_head128(
     assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
 
 
+@pytest.mark.parametrize("staging", ["whole-sequence", "by-block"])
+def test_flash_kernels_compile_at_b2_s8192_key192_value128(
+        staging, one_chip, as_on_the_chip, monkeypatch):
+    """Latent attention's shapes: 32 heads, each with its own 192-wide key
+    and 128-wide value. K and V whole-sequence, twice, are 12 MiB (a
+    192-wide row takes 256 lanes), the dK/dV kernel's q, do, o and lse 24:
+    all three kernels compile only with Mosaic's scoped limit raised
+    (``_staging_params``; refused at 16.03, 16.03 and 25.5 MiB against
+    16 without)."""
+    assert fa.staged_vmem_bytes(8192, (192, 2), (128, 2)) == 12 * 2**20
+    assert fa.staged_vmem_bytes(
+        8192, (192, 2), (128, 2), (128, 2), (1, 4)) == 24 * 2**20
+    if staging == "by-block":
+        monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET", "1")
+    shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                              sharding=one_chip)
+    qk, v = shape((2, 8192, 32, 192)), shape((2, 8192, 32, 128))
+    assert fa.fits_vmem(8192, 192, 1, 2, 512, 128) == (
+        staging == "whole-sequence")
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32))
+
+    # forward, dQ, dK/dV
+    assert _compiled(jax.grad(loss, (0, 1, 2)), qk, qk, v) == 3
+
+
+@pytest.mark.parametrize("seq, d, d_v, staged_mib, raised", [
+    (8192, 128, 128, 20, True), (8192, 64, 64, 20, True),
+    (4096, 192, 128, 12, True), (4096, 128, 128, 10, False)])
+def test_whole_sequence_dkv_compiles_wherever_the_gate_lets_it_through(
+        seq, d, d_v, staged_mib, raised, one_chip, as_on_the_chip):
+    """One head a group, so ``fits_vmem`` keeps the whole-sequence dK/dV
+    kernel; Mosaic holds q, do, o and the lse twice and lane-rounded, and
+    refused the first three at its default limit (21, 21 and 16.84 MiB
+    held against 16) until the call asked for more; 10 MiB staged is the
+    most that compiles without."""
+    assert fa.fits_vmem(seq, d, 1, 2, 512, d_v)
+    operands = ((d, 2), (d_v, 2), (d_v, 2), (1, 4))
+    assert fa.staged_vmem_bytes(seq, *operands) == staged_mib * 2**20
+    assert (fa._staging_params(seq, *operands) is not None) == raised
+    shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                              sharding=one_chip)
+    qk, v = shape((2, seq, 32, d)), shape((2, seq, 32, d_v))
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32))
+
+    assert _compiled(jax.grad(loss, (0, 1, 2)), qk, qk, v) == 3
+
+
 def test_held_experts_compile_at_16384_tokens_top8_16_of_128(
         one_chip, as_on_the_chip):
     def shape(dims, dtype=jnp.bfloat16):
