@@ -9,7 +9,8 @@ activations for recomputation in the backward; how much it keeps is
 decided from the shapes and the device's memory (:func:`remat_plan`),
 the richest that fits a share of what the parameters' state leaves of
 the device: the matmul and flash-kernel outputs, else what the flash
-kernels' backward reads, else only each block's input.
+kernels' backward reads, else the flash forward kernel's outputs, else
+only each block's input.
 
 The same ``Block`` also builds the sparse long-context decoders
 (``layer_kinds``): per layer a window or a full attention, with or
@@ -39,6 +40,7 @@ from ..common import tracing as _tracing
 from ..common.logging import get_logger
 from ..common.metrics import registry as _metrics
 from ..ops.flash_attention import DEFAULT_BLOCK as _DEFAULT_FLASH_BLOCK
+from ..ops.flash_attention import OUTPUT_NAMES as _FLASH_OUTPUT_NAMES
 from ..ops.flash_attention import RESIDUAL_NAMES as _FLASH_RESIDUAL_NAMES
 
 _log = get_logger("models.transformer")
@@ -1041,11 +1043,16 @@ class LMHead(nn.Module):
 # keeps 4.09 GB of the 12.03 GB its state leaves, 34.0%, at a peak of
 # 12.31 of 15.74 GiB (PR 26). ``save_attention``: Trinity-Mini's five
 # layers at 2 x 8192 keep 1.53 GB of the 8.44 GB left, 18.1% (PR 28).
+# ``save_attention_out``: Kanana's six latent layers at 2 x 8192 keep
+# 0.82 GB of the 8.65 GB left, 9.5% (PR 32); a larger value needs a chip
+# run of its own.
 # The poorer rung's share is the smaller because it keeps fewer bytes a
 # token: at one share the step would hold more tokens, and the step's
 # other temporaries grow with the tokens (0.26-0.30 MB a token in both
 # models) and need what the share leaves.
-REMAT_SAVE_SHARE = {"save_matmuls": 0.35, "save_attention": 0.2}
+REMAT_SAVE_SHARE = {
+    "save_matmuls": 0.35, "save_attention": 0.2, "save_attention_out": 0.1,
+}
 # What training holds for each parameter, reckoned: float32 weight,
 # gradient and one optimizer moment. (The 705.5M parameters of PR 27's
 # cell measured 5.644 GB of weights and momentum as the step's
@@ -1160,6 +1167,20 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     reach the kernels through slices, a rotation and concatenations,
     whose backward reads no value).
 
+    ``save_attention_out``: each block keeps the flash forward kernel's
+    outputs and no more, by name (``ops/flash_attention.py:
+    OUTPUT_NAMES``): the attention output and one lane of ``lse``, at
+    every head (a latent layer's at the value's width); of an expert
+    layer the routing's integer results as above. q, k and v are the
+    kernel's inputs: the second forward remakes them as under
+    ``recompute_all`` (every projection, norm, rotation and head
+    transpose again), and with its outputs kept nothing reads the
+    forward kernel, so it is not run a second time, nor ``top_k`` and
+    the sort. For a model whose K/V heads are many and whose state
+    leaves little (every head of a latent layer has its own 192-wide key
+    and 128-wide value: q, k and v are four fifths of the five
+    residuals). Offered where ``save_attention`` is.
+
     ``recompute_all``: each block keeps its input alone, where no rung
     fits and where the limit cannot be read (CPU). ``off``:
     ``cfg.remat`` is not set."""
@@ -1170,12 +1191,15 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     itemsize = jnp.dtype(cfg.dtype).itemsize
     q_width = cfg.num_heads * cfg.dim_per_head()
     kv_width = 2 * (cfg.num_kv_heads or cfg.num_heads) * cfg.dim_per_head()
-    # q, k and v, the attention output, one float32 lse lane a head
-    attention = (2 * q_width + kv_width) * itemsize + 4 * cfg.num_heads
+    # the forward kernel's outputs: the attention output and one float32
+    # lse lane a head; with its inputs q, k and v the five residuals
+    attention_out = q_width * itemsize + 4 * cfg.num_heads
+    attention = attention_out + (q_width + kv_width) * itemsize
     # the same of a latent layer: q and k at the key's width, v and the
     # output at the value's, every head its own
     k_width, v_width, heads = cfg.head_widths("latent")
-    latent = 2 * heads * (k_width + v_width) * itemsize + 4 * heads
+    latent_out = heads * v_width * itemsize + 4 * heads
+    latent = latent_out + heads * (2 * k_width + v_width) * itemsize
     latent_layers = cfg.latent_layers()
     plain_layers = cfg.num_layers - latent_layers
     # the output gate's and the output projection's outputs; the q and
@@ -1201,11 +1225,14 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
     dense = cfg.d_ff * (2 if cfg.ffn_gated else 1) * itemsize
     experts = 4 * cfg.moe_experts_total + 2 * cfg.moe_shared_d_ff * itemsize
     expert_layers = cfg.expert_layers()
-    # by name on both rungs: the kernels' residuals and, of an expert
-    # layer, the chosen experts and the dispatch's sorted order
-    named = (
-        plain_layers * attention + latent_layers * latent
-        + expert_layers * 2 * 4 * cfg.moe_top_k
+    # by name on every saving rung, of an expert layer: the chosen
+    # experts and the dispatch's sorted order
+    routing = expert_layers * 2 * 4 * cfg.moe_top_k
+    # by name on the two richer rungs: the kernels' five residuals
+    named = plain_layers * attention + latent_layers * latent + routing
+    # by name on the poorest: the forward kernel's outputs alone
+    outputs = (
+        plain_layers * attention_out + latent_layers * latent_out + routing
     )
     # bytes a token over all layers; 0: the rung is not offered
     rungs = {
@@ -1215,6 +1242,7 @@ def remat_plan(cfg: TransformerConfig, tokens: int, bytes_limit):
             + (cfg.num_layers - expert_layers) * dense
         ),
         "save_attention": named if cfg.wants_flash() else 0,
+        "save_attention_out": outputs if cfg.wants_flash() else 0,
     }
     room = bytes_limit - REMAT_STATE_BYTES_PER_PARAM * _param_count(cfg)
     for mode, per_token in rungs.items():
@@ -1236,22 +1264,26 @@ def _device_bytes_limit() -> Optional[int]:
 
 def _remat_policy(mode: str):
     """Checkpoint policy of a rung of :func:`remat_plan`. The flash
-    kernels' residuals go by name on both saving rungs, since a policy
+    kernels' residuals go by name on every saving rung, since a policy
     on primitives does not see through a ``pallas_call``, and with
-    them an expert layer's routing (``parallel/moe.py: ROUTING_NAMES``);
-    ``save_matmuls`` adds the weight matmuls, the ``dot_general``s
-    without batch dimensions (the dense attention fallback's einsums
-    have them, and are recomputed). An output the backward does not
-    read (the second feed-forward matmul's, the qkv projection's where
-    q, k and v are kept by name, unless a norm follows) is not kept.
-    ``recompute_all``: None, nothing but the block's input."""
+    them an expert layer's routing (``parallel/moe.py: ROUTING_NAMES``):
+    all five on the two richer rungs, the forward kernel's two outputs
+    on ``save_attention_out``; ``save_matmuls`` adds the weight
+    matmuls, the ``dot_general``s without batch dimensions (the dense
+    attention fallback's einsums have them, and are recomputed). An
+    output the backward does not read (the second feed-forward
+    matmul's, the qkv projection's where q, k and v are kept by name,
+    unless a norm follows) is not kept. ``recompute_all``: None,
+    nothing but the block's input."""
     from ..parallel.moe import ROUTING_NAMES
 
     policies = jax.checkpoint_policies
-    names = policies.save_only_these_names(
-        *_FLASH_RESIDUAL_NAMES, *ROUTING_NAMES
+    flash = (
+        _FLASH_OUTPUT_NAMES if mode == "save_attention_out"
+        else _FLASH_RESIDUAL_NAMES
     )
-    if mode == "save_attention":
+    names = policies.save_only_these_names(*flash, *ROUTING_NAMES)
+    if mode in ("save_attention", "save_attention_out"):
         return names
     if mode == "save_matmuls":
         return policies.save_from_both_policies(

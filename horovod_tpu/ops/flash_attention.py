@@ -597,6 +597,9 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
 # residuals by (``save_only_these_names(*RESIDUAL_NAMES)``): a policy on
 # primitives does not see through a ``pallas_call``.
 RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_o", "flash_lse")
+# The forward kernel's outputs among them: a policy that keeps these two
+# alone lets the second forward remake q, k and v and run no kernel.
+OUTPUT_NAMES = RESIDUAL_NAMES[3:]
 
 
 def _named_residuals(q, k, v, o, lse):

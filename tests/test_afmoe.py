@@ -381,6 +381,9 @@ _ATTENTION_KEPT = 2 * SEQ * (
     5 * (4 * (64 + 64 + 32 + 32) + 4 * 4) + 4 * 2 * 2 * 4)
 _MATMULS_KEPT = _ATTENTION_KEPT + 2 * SEQ * 4 * (
     5 * (64 + 64 + 64 + 64 + 64) + 2 * 96 + 4 * (8 + 2 * 32))
+# the forward kernel's outputs alone: o (64 wide) and one lse a head, and
+# the routing's integers
+_OUTPUTS_KEPT = 2 * SEQ * (5 * (4 * 64 + 4 * 4) + 4 * 2 * 2 * 4)
 
 
 def _state_bytes(cfg):
@@ -390,11 +393,16 @@ def _state_bytes(cfg):
 @pytest.mark.parametrize("flash,room,want", [
     (True, 1 << 40, ("save_matmuls", _MATMULS_KEPT)),
     (True, 5 * _ATTENTION_KEPT, ("save_attention", _ATTENTION_KEPT)),
-    (True, 5 * _ATTENTION_KEPT - 8, ("recompute_all", 0)),
+    # eight bytes under the kernels' residuals' share: their outputs alone,
+    # down to a tenth of the room
+    (True, 5 * _ATTENTION_KEPT - 8, ("save_attention_out", _OUTPUTS_KEPT)),
+    (True, 10 * _OUTPUTS_KEPT, ("save_attention_out", _OUTPUTS_KEPT)),
+    (True, 10 * _OUTPUTS_KEPT - 8, ("recompute_all", 0)),
     # off the kernels nothing goes by name
     (False, 5 * _ATTENTION_KEPT, ("recompute_all", 0)),
     (None, None, ("recompute_all", 0)),
-], ids=["save_matmuls", "save_attention", "too-little-room", "dense-path",
+], ids=["save_matmuls", "save_attention", "under-save_attention",
+        "save_attention_out", "too-little-room", "dense-path",
         "limit-unknown"])
 def test_remat_climbs_the_ladder_where_experts_are_held_and_says_so(
         flash, room, want, monkeypatch):
@@ -421,15 +429,20 @@ def test_remat_climbs_the_ladder_where_experts_are_held_and_says_so(
     }
 
 
-def test_the_cell_of_the_benchmark_keeps_the_kernels_residuals():
-    """Trinity-Mini's share at 2 x 8192 tokens beside a v5e's limit: the
-    matmuls' outputs are over their share of what 8.47 GB of state leave,
-    the kernels' residuals are not (PERF.md section 6, PR 28)."""
+def _cell_cfg():
+    """Trinity-Mini's share as the benchmark's cell builds it on the chip."""
     family, _ = _family()
     with open(os.path.join(HERE, "..", "benchmark", "configs",
                            "trinity-mini.json")) as f:
         cfg = family.build_model(json.load(f), remat=True).cfg
-    cfg = dataclasses.replace(cfg, flash_attention=True)  # as on the chip
+    return dataclasses.replace(cfg, flash_attention=True)
+
+
+def test_the_cell_of_the_benchmark_keeps_the_kernels_residuals():
+    """Trinity-Mini's share at 2 x 8192 tokens beside a v5e's limit: the
+    matmuls' outputs are over their share of what 8.47 GB of state leave,
+    the kernels' residuals are not (PERF.md section 6, PR 28)."""
+    cfg = _cell_cfg()
     limit = int(15.74 * 2**30)
     assert T._param_count(cfg) == 705_474_304
     # bfloat16 q and o at 32 heads of 128, k and v at 4, an lse a head;
@@ -438,6 +451,29 @@ def test_the_cell_of_the_benchmark_keeps_the_kernels_residuals():
     assert T.remat_plan(cfg, 2 * 8192, limit) == (
         "save_attention", 2 * 8192 * per_token)
     assert T.remat_plan(cfg, 2 * 8192, 1 << 40)[0] == "save_matmuls"
+
+
+def test_the_cell_of_the_benchmark_stays_above_the_kernels_outputs_rung():
+    """The twin of Kanana's test (tests/test_deepseek_v3.py): Trinity-Mini's
+    share is decided above ``save_attention_out`` on the ladder, so the rung
+    of PR 32 leaves its step as it was: 1,524,629,504 bytes kept, 18.1% of
+    the 8,435,004,661 that the state leaves, under the 0.2 of its rung."""
+    cfg = _cell_cfg()
+    limit = int(15.74 * 2**30)
+    room = limit - _state_bytes(cfg)
+    assert room == 8_435_004_661
+    assert T.remat_plan(cfg, 2 * 8192, limit) == (
+        "save_attention", 1_524_629_504)
+    assert 1_524_629_504 <= T.REMAT_SAVE_SHARE["save_attention"] * room
+    # what the poorer rung would keep of it: o at 32 heads of 128 and an lse
+    # a head in five layers, the routing's integers in four
+    outputs = 2 * 8192 * (5 * (4096 * 2 + 4 * 32) + 4 * 2 * 8 * 4)
+    assert outputs == 685_768_704
+    # it is taken only where the richer one no longer fits: a device with
+    # eight bytes less than save_attention's share of room
+    under = _state_bytes(cfg) + 5 * 1_524_629_504 - 8
+    assert T.remat_plan(cfg, 2 * 8192, under) == (
+        "save_attention_out", outputs)
 
 
 def test_remat_plan_reckons_the_new_widths_of_a_dense_model():

@@ -290,13 +290,21 @@ _ATTENTION_KEPT = 2 * SEQ * (
     4 * (4 * 2 * 4 * (18 + 12) + 4 * 4) + 3 * 2 * 2 * 4)
 _MATMULS_KEPT = _ATTENTION_KEPT + 2 * SEQ * 4 * (
     4 * (64 + 38) + 2 * 192 + 3 * (8 + 2 * 48))
+# the forward kernel's outputs alone: o (4 heads of 12) and one lse a head,
+# and the routing's integers
+_OUTPUTS_KEPT = 2 * SEQ * (4 * (4 * 4 * 12 + 4 * 4) + 3 * 2 * 2 * 4)
 
 
 @pytest.mark.parametrize("room,want", [
     (1 << 40, ("save_matmuls", _MATMULS_KEPT)),
     (5 * _ATTENTION_KEPT, ("save_attention", _ATTENTION_KEPT)),
-    (5 * _ATTENTION_KEPT - 8, ("recompute_all", 0)),
-], ids=["save_matmuls", "save_attention", "too-little-room"])
+    # eight bytes under the five residuals' share: the kernel's outputs
+    # alone, down to a tenth of the room
+    (5 * _ATTENTION_KEPT - 8, ("save_attention_out", _OUTPUTS_KEPT)),
+    (10 * _OUTPUTS_KEPT, ("save_attention_out", _OUTPUTS_KEPT)),
+    (10 * _OUTPUTS_KEPT - 8, ("recompute_all", 0)),
+], ids=["save_matmuls", "save_attention", "under-save_attention",
+        "save_attention_out", "too-little-room"])
 def test_remat_reckons_the_unequal_residuals_and_the_span_says_so(
         room, want, monkeypatch):
     monkeypatch.setenv("HOROVOD_TRACE", "0")
@@ -321,10 +329,12 @@ def test_remat_reckons_the_unequal_residuals_and_the_span_says_so(
     }
 
 
-def test_the_cell_of_the_benchmark_recomputes_all():
+def test_the_cell_of_the_benchmark_keeps_the_kernels_outputs():
     """Kanana's share at 2 x 8192 tokens beside a v5e's limit: the kernels'
     residuals, 672 MB a layer, are over their share of what 8.25 GB of
-    state leave (ISSUE 31; ROADMAP B-M4's training rung)."""
+    state leave (ISSUE 31); the forward kernel's outputs alone, 136 MB a
+    layer, are 9.5% of it and under theirs (ISSUE 32; ROADMAP B-M4's
+    training rung)."""
     family, _ = _family()
     cfg = family.build_model(_published(), remat=True).cfg
     cfg = dataclasses.replace(cfg, flash_attention=True)  # as on the chip
@@ -334,8 +344,25 @@ def test_the_cell_of_the_benchmark_recomputes_all():
     per_token = 6 * (2 * 32 * (192 + 128) * 2 + 4 * 32) + 5 * 2 * 6 * 4
     assert 6 * 2 * 8192 * (2 * 32 * (192 + 128) * 2 + 4 * 32) == 4_039_114_752
     limit = int(15.74 * 2**30)
-    assert T.remat_plan(cfg, 2 * 8192, limit) == ("recompute_all", 0)
     assert 2 * 8192 * per_token == 4_043_046_912
+    state = T.REMAT_STATE_BYTES_PER_PARAM * T._param_count(cfg)
+    room = limit - state
+    assert (state, room) == (8_250_035_712, 8_650_660_597)
+    assert 4_043_046_912 > T.REMAT_SAVE_SHARE["save_attention"] * room
+    # of them the forward kernel's outputs: bfloat16 o at 32 heads of 128
+    # and an lse a head in six layers, 8,320 bytes a token and layer, and
+    # the routing's integers, 48 a token, in five
+    outputs = 6 * 2 * 8192 * (32 * 128 * 2 + 4 * 32) + 5 * 2 * 8192 * 2 * 6 * 4
+    assert outputs == 817_889_280 + 3_932_160 == 821_821_440
+    assert outputs <= T.REMAT_SAVE_SHARE["save_attention_out"] * room
+    assert round(outputs / room, 3) == 0.095
+    assert T.remat_plan(cfg, 2 * 8192, limit) == (
+        "save_attention_out", 821_821_440)
+    # eight bytes under a tenth of the room nothing is kept
+    assert T.remat_plan(cfg, 2 * 8192, state + 10 * outputs) == (
+        "save_attention_out", outputs)
+    assert T.remat_plan(cfg, 2 * 8192, state + 10 * outputs - 8) == (
+        "recompute_all", 0)
     # the matmuls' outputs beside them: W_o's and the joint
     # down-projection's in six layers, gate and up of the dense layer, the
     # router's float32 logits and the shared experts' gate and up in five
@@ -347,7 +374,6 @@ def test_the_cell_of_the_benchmark_recomputes_all():
     assert T.remat_plan(cfg, 2 * 8192, 1 << 36) == (
         "save_matmuls", 2 * 8192 * (per_token + matmuls))
     room = int(4_043_046_912 / T.REMAT_SAVE_SHARE["save_attention"])
-    state = T.REMAT_STATE_BYTES_PER_PARAM * T._param_count(cfg)
     assert T.remat_plan(cfg, 2 * 8192, state + room)[0] == "save_matmuls"
 
 

@@ -38,6 +38,12 @@ def _attention_bytes(tokens):
     return 24 * tokens * (4 * 1024 * 2 + 16 * 4)
 
 
+def _output_bytes(tokens):
+    # the attention output at d_model and one fp32 lse a head: what the
+    # forward kernel writes
+    return 24 * tokens * (1024 * 2 + 16 * 4)
+
+
 @pytest.mark.parametrize("cfg,tokens,limit,want", [
     # the benchmark's GPT-2 cells: 8 x 512 a chip
     (_gpt2m(), 8 * 512, V5E_LIMIT, ("save_matmuls", _cell_saved_bytes(4096))),
@@ -50,8 +56,16 @@ def _attention_bytes(tokens):
     # the dense path has no names to keep by
     (_gpt2m(flash_attention=False), 19 * 512, V5E_LIMIT,
      ("recompute_all", 0)),
-    # 24 x 512: the kernels' residuals are past their share too
-    (_gpt2m(flash_attention=True), 24 * 512, V5E_LIMIT, ("recompute_all", 0)),
+    # 24 x 512: the kernels' residuals are past their share too, the
+    # forward kernel's outputs alone (a quarter of them) are not
+    (_gpt2m(flash_attention=True), 24 * 512, V5E_LIMIT,
+     ("save_attention_out", _output_bytes(24 * 512))),
+    (_gpt2m(flash_attention=False), 24 * 512, V5E_LIMIT,
+     ("recompute_all", 0)),
+    # 46 x 512 is the largest step whose outputs fit a tenth of the room
+    (_gpt2m(flash_attention=True), 46 * 512, V5E_LIMIT,
+     ("save_attention_out", _output_bytes(46 * 512))),
+    (_gpt2m(flash_attention=True), 47 * 512, V5E_LIMIT, ("recompute_all", 0)),
     # the limit cannot be read (CPU): the parent's behaviour
     (_gpt2m(), 8 * 512, None, ("recompute_all", 0)),
     (_gpt2m(), 8 * 512, 0, ("recompute_all", 0)),
@@ -61,11 +75,15 @@ def _attention_bytes(tokens):
      ("recompute_all", 0)),
     (_gpt2m(moe_experts=4, flash_attention=True), 8 * 512, V5E_LIMIT,
      ("save_attention", _attention_bytes(4096))),
+    (_gpt2m(moe_experts=4, flash_attention=True), 16 * 512, V5E_LIMIT,
+     ("save_attention_out", _output_bytes(16 * 512))),
     # not asked for
     (T.TransformerConfig.gpt2_medium(), 8 * 512, V5E_LIMIT, ("off", 0)),
 ], ids=["cell-fits", "b18-largest-measured", "b19-keeps-attention",
-        "b19-dense-path", "b24-too-large", "limit-unknown", "limit-zero",
-        "moe-dense-path", "moe-keeps-attention", "remat-off"])
+        "b19-dense-path", "b24-keeps-the-outputs", "b24-dense-path",
+        "b46-largest-outputs", "b47-too-large", "limit-unknown", "limit-zero",
+        "moe-dense-path", "moe-keeps-attention", "moe-keeps-the-outputs",
+        "remat-off"])
 def test_remat_plan_decides_from_shapes_and_the_memory_limit(
         cfg, tokens, limit, want):
     assert T.remat_plan(cfg, tokens, limit) == want
@@ -74,7 +92,9 @@ def test_remat_plan_decides_from_shapes_and_the_memory_limit(
 @pytest.mark.parametrize("mode,saved,below", [
     ("save_matmuls", _cell_saved_bytes(4096),
      ("save_attention", _attention_bytes(4096))),
-    ("save_attention", _attention_bytes(4096), ("recompute_all", 0)),
+    ("save_attention", _attention_bytes(4096),
+     ("save_attention_out", _output_bytes(4096))),
+    ("save_attention_out", _output_bytes(4096), ("recompute_all", 0)),
 ])
 def test_each_rung_turns_exactly_at_its_share_of_what_the_state_leaves(
         mode, saved, below):
@@ -88,7 +108,11 @@ def test_each_rung_turns_exactly_at_its_share_of_what_the_state_leaves(
 
 
 def test_the_rungs_are_richest_first():
-    assert list(T.REMAT_SAVE_SHARE) == ["save_matmuls", "save_attention"]
+    assert list(T.REMAT_SAVE_SHARE) == [
+        "save_matmuls", "save_attention", "save_attention_out"]
+    # a poorer rung keeps fewer bytes a token, so its share is no larger
+    shares = list(T.REMAT_SAVE_SHARE.values())
+    assert shares == sorted(shares, reverse=True)
 
 
 def test_remat_plan_counts_shared_kv_heads_once():
@@ -151,8 +175,9 @@ def _limit_giving(mode, cfg, tokens):
         return None
     if mode == "save_matmuls":
         return 1 << 40
-    return _state_bytes(cfg) + int(np.ceil(
-        _kernel_residual_bytes(cfg, tokens) / T.REMAT_SAVE_SHARE[mode]))
+    kept = {"save_attention": _kernel_residual_bytes,
+            "save_attention_out": _kernel_output_bytes}[mode](cfg, tokens)
+    return _state_bytes(cfg) + int(np.ceil(kept / T.REMAT_SAVE_SHARE[mode]))
 
 
 def _kernel_residual_bytes(cfg, tokens):
@@ -164,6 +189,17 @@ def _kernel_residual_bytes(cfg, tokens):
     row = (2 * heads + 2 * kv_heads) * cfg.dim_per_head()
     return tokens * (
         cfg.num_layers * (row * jnp.dtype(cfg.dtype).itemsize + 4 * heads)
+        + cfg.expert_layers() * 2 * 4 * cfg.moe_top_k)
+
+
+def _kernel_output_bytes(cfg, tokens):
+    """What goes by name on the poorest saving rung: the attention output
+    and one float32 lse at every head, over all layers; of an expert layer
+    the routing's integers as above."""
+    row = cfg.num_heads * cfg.dim_per_head()
+    return tokens * (
+        cfg.num_layers * (
+            row * jnp.dtype(cfg.dtype).itemsize + 4 * cfg.num_heads)
         + cfg.expert_layers() * 2 * 4 * cfg.moe_top_k)
 
 
@@ -198,7 +234,8 @@ def limit(monkeypatch):
 
 @pytest.mark.parametrize("flash,mode", [
     (True, "save_matmuls"), (False, "save_matmuls"), (True, "save_attention"),
-], ids=["flash", "dense", "flash-save_attention"])
+    (True, "save_attention_out"),
+], ids=["flash", "dense", "flash-save_attention", "flash-save_attention_out"])
 @pytest.mark.parametrize("kind", list(_KINDS))
 def test_saving_changes_no_loss_and_no_gradient(
         kind, flash, mode, batch, limit):
@@ -264,7 +301,8 @@ def _count(jaxpr, counts):
 
 @pytest.mark.parametrize("kind", list(_KINDS))
 @pytest.mark.parametrize(
-    "mode", ["save_matmuls", "save_attention", "recompute_all"])
+    "mode", ["save_matmuls", "save_attention", "save_attention_out",
+             "recompute_all"])
 def test_the_rematted_backward_repeats_only_what_the_plan_says(
         kind, mode, batch, limit):
     cfg = _tiny(kind, True)
@@ -289,6 +327,10 @@ def test_the_rematted_backward_repeats_only_what_the_plan_says(
         "save_attention": {
             "pallas_call": 2,
             "weight_matmul": 3 * matmuls - 1 - projections},
+        # no forward kernel, whose outputs are kept; every projection
+        # again on the way to q, k and v, as under recompute_all
+        "save_attention_out": {
+            "pallas_call": 2, "weight_matmul": 3 * matmuls - 1},
         # the parent's: the forward kernel again, and every forward
         # matmul whose output something reads (the last one's feeds
         # only the residual sum)
@@ -298,7 +340,8 @@ def test_the_rematted_backward_repeats_only_what_the_plan_says(
         assert counts == want
 
 
-@pytest.mark.parametrize("mode", ["save_matmuls", "save_attention"])
+@pytest.mark.parametrize(
+    "mode", ["save_matmuls", "save_attention", "save_attention_out"])
 @pytest.mark.parametrize("kind", list(_KINDS))
 def test_the_plan_reckons_the_bytes_the_backward_is_handed(
         kind, mode, batch, limit):
@@ -316,6 +359,8 @@ def test_the_plan_reckons_the_bytes_the_backward_is_handed(
     assert saving - recomputing == saved_bytes
     if mode == "save_attention":
         assert saved_bytes == _kernel_residual_bytes(cfg, batch[0].size)
+    if mode == "save_attention_out":
+        assert saved_bytes == _kernel_output_bytes(cfg, batch[0].size)
 
 
 # ----------------------------------- (c') a small model that holds experts
@@ -352,10 +397,18 @@ def test_an_expert_models_state_counts_the_experts_it_holds(held, batch):
     kept = _kernel_residual_bytes(cfg, 2 * SEQ)
     assert at == T.REMAT_STATE_BYTES_PER_PARAM * count + 5 * kept  # 1 / 0.2
     assert T.remat_plan(cfg, 2 * SEQ, at) == ("save_attention", kept)
+    # under it the forward kernel's outputs, down to a tenth of the room
+    outputs = _kernel_output_bytes(cfg, 2 * SEQ)
+    assert T.remat_plan(cfg, 2 * SEQ, at - 8) == (
+        "save_attention_out", outputs)
+    at = _limit_giving("save_attention_out", cfg, 2 * SEQ)
+    assert at == T.REMAT_STATE_BYTES_PER_PARAM * count + 10 * outputs
+    assert T.remat_plan(cfg, 2 * SEQ, at) == ("save_attention_out", outputs)
     assert T.remat_plan(cfg, 2 * SEQ, at - 8) == ("recompute_all", 0)
 
 
-@pytest.mark.parametrize("mode", ["save_matmuls", "save_attention"])
+@pytest.mark.parametrize(
+    "mode", ["save_matmuls", "save_attention", "save_attention_out"])
 def test_an_expert_models_backward_is_handed_what_the_plan_reckons(
         mode, batch, limit):
     cfg = _tiny_experts()
@@ -393,14 +446,17 @@ def test_an_expert_models_backward_is_handed_what_the_plan_reckons(
     assert abs(handed - saved_bytes) <= 0.005 * saved_bytes
 
 
+@pytest.mark.parametrize("mode", ["save_attention", "save_attention_out"])
 def test_keeping_the_kernels_residuals_where_experts_are_held_changes_no_bit(
-        batch, limit):
+        mode, batch, limit):
     cfg = _tiny_experts()
     model, loss = _loss_fn(cfg, *batch)
     params = model.init(jax.random.PRNGKey(0), batch[0], train=False)
     limit(None)
     want_loss, want = jax.jit(jax.value_and_grad(loss))(params)
-    limit(_limit_giving("save_attention", cfg, batch[0].size))
+    value = _limit_giving(mode, cfg, batch[0].size)
+    assert T.remat_plan(cfg, batch[0].size, value)[0] == mode
+    limit(value)
     got_loss, got = jax.jit(jax.value_and_grad(loss))(params)
     assert float(got_loss) == float(want_loss)
     for (path, g), w in zip(
@@ -448,7 +504,11 @@ def ring(monkeypatch):
     (True, True, _limit_giving(
         "save_attention", _tiny("causal-mha", True), 2 * SEQ),
      {"remat": "save_attention"}),
-], ids=["off", "recompute_all", "save_matmuls", "save_attention"])
+    (True, True, _limit_giving(
+        "save_attention_out", _tiny("causal-mha", True), 2 * SEQ),
+     {"remat": "save_attention_out"}),
+], ids=["off", "recompute_all", "save_matmuls", "save_attention",
+        "save_attention_out"])
 def test_the_trace_model_span_says_what_remat_does(
         remat, flash, value, want, batch, limit, ring):
     limit(value)
