@@ -187,6 +187,20 @@ class TransformerConfig:
     moe_score: str = "sigmoid"
     moe_route_norm: bool = True
     moe_route_scale: float = 1.0
+    # False: a router with no selection bias. The expert layer has no
+    # ``select_bias`` leaf and the k experts are chosen on the scores alone.
+    moe_select_bias: bool = True
+    # Block-diffusion training: the block length B (0 = off). A call takes
+    # ``[rows, 2L]`` token ids, a noised copy of every row of L tokens and
+    # then the clean row; both copies of token i sit at position i; every
+    # attention layer keeps (query, key) under the three-part mask of
+    # ``ops.flash_attention`` (noised sees its own noised block and the
+    # clean blocks before it, clean sees the clean blocks up to its own),
+    # in the kernels; the final norm and the head run over the noised half
+    # and the logits are ``[rows, L, vocab]``. B must divide L and the
+    # kernels' tiles. Refused beside ``cache=``, ``lengths=``, ``mask=``, a
+    # window or a latent layer.
+    block_diffusion: int = 0
 
     def dim_per_head(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
@@ -266,8 +280,8 @@ class TransformerConfig:
         if mask is not None:
             return (
                 "an arbitrary padding mask was passed (the kernels mask "
-                "causal and lengths= only; pass lengths for right-padded "
-                "batches)"
+                "causal, sliding_window, lengths= and block_diffusion "
+                "only; pass lengths for right-padded batches)"
             )
         if seq is None:
             return None
@@ -324,7 +338,8 @@ class TransformerConfig:
         )
 
 
-def apply_rope(x, base: float = 10000.0, offset=0, interleave: bool = False):
+def apply_rope(x, base: float = 10000.0, offset=0, interleave: bool = False,
+               period: Optional[int] = None):
     """Rotate [batch, seq, heads, head_dim] q or k by absolute position
     (RoFormer). Pairs are (x[..., :d/2], x[..., d/2:]) — the
     'rotate-half' convention — so the op is two multiplies and one
@@ -336,16 +351,21 @@ def apply_rope(x, base: float = 10000.0, offset=0, interleave: bool = False):
     ``offset`` shifts positions: a scalar (sequence-parallel shards
     pass their global start — may be a traced value, e.g.
     axis_index·t_local) or a ``[batch]`` array (incremental decode:
-    every cache slot sits at its own position)."""
+    every cache slot sits at its own position). ``period``: the positions
+    start again every so many tokens (block-diffusion training: two
+    copies of a row, token i of either at position i)."""
     b, t, h, d = x.shape
     half = d // 2
     if d % 2:
         raise ValueError(f"RoPE needs an even head_dim, got {d}")
     # offset + iota rather than arange(offset, ...) so traced offsets
     # (SP shards, decode cache indices) work
-    pos = jnp.asarray(offset, jnp.float32)[..., None] + jnp.arange(
-        t, dtype=jnp.float32
-    )  # [t] for scalar offsets, [b, t] for per-slot offsets
+    # [t] for scalar offsets, [b, t] for per-slot offsets
+    pos = jnp.asarray(offset, jnp.float32)[..., None]
+    if period is None:
+        pos = pos + jnp.arange(t, dtype=jnp.float32)
+    else:
+        pos = pos + (jnp.arange(t) % period).astype(jnp.float32)
     inv_freq = base ** (
         -jnp.arange(0, half, dtype=jnp.float32) / half
     )
@@ -368,6 +388,24 @@ def apply_rope(x, base: float = 10000.0, offset=0, interleave: bool = False):
     return jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
     ).astype(x.dtype)
+
+
+def block_diffusion_mask(length: int, block: int):
+    """``[2L, 2L]`` bool, the pairs (query, key) that block-diffusion
+    training keeps over a noised copy of a row of ``length`` tokens and
+    then the clean row, in blocks of ``block``: noised sees its own noised
+    block and the clean blocks before it, clean sees the clean blocks up to
+    its own, and never a noised key. The dense path's mask; the kernels
+    build theirs tile by tile (``ops/flash_attention.py``)."""
+    at = jnp.arange(2 * length)
+    noised = at < length
+    blocks = at % length // block
+    bi, bj = blocks[:, None], blocks[None, :]
+    return jnp.where(
+        noised[:, None],
+        jnp.where(noised[None, :], bi == bj, bj < bi),
+        ~noised[None, :] & (bj <= bi),
+    )
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len=None, dtype=None):
@@ -430,6 +468,14 @@ class MultiHeadAttention(nn.Module):
         scope = "attn_latent" if self.kind == "latent" else (
             "attn_window" if window else "attn_full"
         )
+        if cfg.block_diffusion:
+            # (cache=, mask= and lengths= are Transformer.__call__'s to refuse)
+            if window or self.kind == "latent":
+                raise ValueError(
+                    "block_diffusion is a full layer's training mask: no "
+                    "window or latent layer"
+                )
+            scope = "attn_blockdiff"
         with jax.named_scope(scope):
             return self._attend(x, mask, lengths, cache, cache_index,
                                 pages, paged_attn, window, rope)
@@ -540,8 +586,12 @@ class MultiHeadAttention(nn.Module):
             k = _norm(cfg, name="k_norm")(k).astype(cfg.dtype)
         if rope and not latent:  # a latent layer rotated its rope parts
             rope_offset = 0 if cache is None else cache_index
-            q = apply_rope(q, cfg.rope_base, offset=rope_offset)
-            k = apply_rope(k, cfg.rope_base, offset=rope_offset)
+            # both copies of a block-diffusion row at the row's positions
+            period = x.shape[1] // 2 if cfg.block_diffusion else None
+            q = apply_rope(q, cfg.rope_base, offset=rope_offset,
+                           period=period)
+            k = apply_rope(k, cfg.rope_base, offset=rope_offset,
+                           period=period)
 
         def project(out):
             """W_o on the heads' output, gated where the model says."""
@@ -571,6 +621,13 @@ class MultiHeadAttention(nn.Module):
             cfg.flash_decline_reason(mask, seq=x.shape[1]) if wanted else None
         )
         use_flash = wanted and declined is None
+        if wanted and not use_flash and cfg.block_diffusion:
+            # 2L x 2L dense scores are what the mode exists to avoid
+            raise ValueError(
+                f"block_diffusion rides the flash kernels or is refused: "
+                f"{declined} (flash_attention=False runs the dense path "
+                "on purpose)"
+            )
         if wanted and not use_flash:
             # the shape dispatch stays, but never silently: a model
             # that was meant to run the kernels and runs dense attention
@@ -585,9 +642,10 @@ class MultiHeadAttention(nn.Module):
             from ..ops.flash_attention import flash_attention
 
             out = flash_attention(
-                q, k, v, causal=cfg.causal,
+                q, k, v, causal=cfg.causal and not cfg.block_diffusion,
                 block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
                 lengths=lengths, window=window,
+                block_diffusion=cfg.block_diffusion or None,
             )
             return project(out)
         if kv_heads != cfg.num_heads:
@@ -600,7 +658,12 @@ class MultiHeadAttention(nn.Module):
         scores = jnp.einsum(
             "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
         ) / jnp.sqrt(head_dim).astype(jnp.float32)
-        if cfg.causal:
+        if cfg.block_diffusion:
+            scores = jnp.where(
+                block_diffusion_mask(x.shape[1] // 2, cfg.block_diffusion),
+                scores, -1e30,
+            )
+        elif cfg.causal:
             t = x.shape[1]
             causal_mask = jnp.tril(jnp.ones((t, t), bool))
             if window:
@@ -840,8 +903,9 @@ class ExpertFFN(nn.Module):
     """An expert layer that is told which experts of the deployment it
     holds (``cfg.moe_experts_held``): the router scores all
     ``cfg.moe_experts_total`` experts in float32 and picks ``moe_top_k`` a
-    token on score + ``select_bias``; the gates are the unbiased scores,
-    renormalised over all k chosen (held here or not) and scaled; the
+    token on score + ``select_bias`` (on the score alone, and no such
+    leaf, where ``cfg.moe_select_bias`` is off); the gates are the unbiased
+    scores, renormalised over all k chosen (held here or not) and scaled; the
     layer returns ``shared(x) + sum over the chosen experts held here of
     gate_e * expert_e(x)``. Dropless with static shapes: the chosen
     (token, expert) pairs are sorted by held expert; the grouped matmuls
@@ -885,7 +949,7 @@ class ExpertFFN(nn.Module):
             )(tokens.astype(jnp.float32))
             select_bias = self.param(
                 "select_bias", nn.initializers.zeros, (total,), jnp.float32
-            )
+            ) if cfg.moe_select_bias else None
             chosen, gates = _moe.route_top_k(
                 logits, select_bias, cfg.moe_top_k, score=cfg.moe_score,
                 norm=cfg.moe_route_norm, scale=cfg.moe_route_scale,
@@ -1099,7 +1163,8 @@ def _param_count(cfg: TransformerConfig) -> int:
         dense = 2 * d * cfg.d_ff + bias * (cfg.d_ff + d)
     first, last = cfg.moe_experts_held or (0, cfg.moe_experts_total)
     experts = (
-        (d + 1) * cfg.moe_experts_total  # router and select_bias
+        # router and select_bias
+        (d + int(cfg.moe_select_bias)) * cfg.moe_experts_total
         + (last - first) * 3 * d * cfg.moe_d_ff
         + (gated(cfg.moe_shared_d_ff) if cfg.moe_shared_d_ff else 0)
     )
@@ -1344,6 +1409,23 @@ class Transformer(nn.Module):
         cache=None, cache_index=None, pages=None, paged_attn=False,
     ):
         cfg = self.cfg
+        # block-diffusion training: L, the row's length (tokens holds a
+        # noised copy and then the clean row), else None
+        half = None
+        if cfg.block_diffusion:
+            if cache is not None or lengths is not None or mask is not None:
+                raise ValueError(
+                    "block_diffusion takes [rows, 2L] token ids alone: no "
+                    "cache=, lengths= or mask= (decoding block by block is "
+                    "the serving engine's to do, ROADMAP B-M9)"
+                )
+            half, odd = divmod(tokens.shape[1], 2)
+            if odd or half % cfg.block_diffusion:
+                raise ValueError(
+                    f"block_diffusion={cfg.block_diffusion} takes a noised "
+                    f"and a clean copy of whole blocks, got "
+                    f"{tokens.shape[1]} positions a row"
+                )
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype)(tokens)
         if cfg.embed_scale != 1.0:
             x = x * cfg.embed_scale
@@ -1352,7 +1434,9 @@ class Transformer(nn.Module):
             for i in range(cfg.num_layers)
         ]
         if not cfg.rope:
-            if cache is None:
+            if half:
+                positions = (jnp.arange(2 * half) % half)[None]
+            elif cache is None:
                 positions = jnp.arange(tokens.shape[1])[None]
             else:
                 # incremental decode: each cache slot sits at its own
@@ -1402,10 +1486,20 @@ class Transformer(nn.Module):
                 _tag_layer_kinds(
                     span, cfg, tokens.shape[0] * tokens.shape[1]
                 )
+            if half:
+                span.tag(
+                    block_length=cfg.block_diffusion, positions=2 * half,
+                    head_positions=half,
+                    # (query, key) pairs a head keeps of a row
+                    score_pairs_kept=half * (half + cfg.block_diffusion),
+                )
         for i in range(cfg.num_layers):
             x = block(cfg, layers[i], name=f"block_{i}")(
                 x, mask, train, lengths
             )
+        if half:
+            # the clean copy is there to be seen; the noised half is read
+            x = x[:, :half]
         x = _norm(cfg)(x)
         if return_hidden:
             # pre-head activations for the chunked fused loss

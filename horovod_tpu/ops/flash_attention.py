@@ -21,6 +21,24 @@ Kernels run in interpret mode off-TPU, so CPU tests exercise the same
 code path bit-for-bit (tests/test_flash_attention.py checks fwd+grads
 against the dense jnp oracle).
 
+The masks the kernels take, each applied in the kernel with the loops
+clamped to the tiles it leaves anything in (forward and dQ loop over K
+blocks, dK/dV over Q blocks; ``flash_attention``'s docstring has the
+arguments):
+
+* ``causal``: forward/dQ end at the diagonal (``_causal_bound``), dK/dV
+  starts there;
+* ``window`` (with causal): forward/dQ also start at the band's first block
+  (``_window_start``), dK/dV also ends at its last;
+* ``lengths`` (right padding): all three end at the valid length
+  (``_length_bound``);
+* ``block_diffusion`` (a noised and a clean copy of every row under one
+  three-part mask; alone): two ranges a program where the others have one
+  (``_blockdiff_k_ranges``, ``_blockdiff_q_ranges``).
+
+Any other mask is the dense path's (``TransformerConfig.
+flash_decline_reason``).
+
 Used by models.Transformer when ``TransformerConfig.flash_attention``
 is on (default: auto — enabled when no padding mask is passed).
 """
@@ -129,8 +147,112 @@ def _length_bound(kv_len, block_k, n_blocks):
     return jnp.minimum(n_blocks, (kv_len + block_k - 1) // block_k)
 
 
+def _pick(cond, a, b):
+    """``a if cond else b`` for a Python ``cond`` (the loop bounds counted
+    from plain numbers), ``jnp.where`` for a traced one (in a kernel)."""
+    return (a if cond else b) if isinstance(cond, bool) else jnp.where(
+        cond, a, b)
+
+
+# The block-diffusion mask. The sequence is two copies of a row of ``half``
+# tokens in blocks of ``blk``: a noised copy (positions < half) and the clean
+# one after it. With i, j the tokens' indices in their own copies and b(i) =
+# i // blk, query r keeps key c iff
+#   noised sees noised:  b(i) == b(j)     noised sees clean:  b(j) < b(i)
+#   clean sees clean:    b(j) <= b(i)     clean sees noised:  never
+# ``half`` is a whole number of tiles of either size and ``blk`` divides
+# both tiles, so a tile lies in one copy and a block in one tile.
+
+
+def _blockdiff_k_ranges(qi, block_q, block_k, half, blk):
+    """``((first, last), (first, last))``: the K tiles that Q tile ``qi``
+    keeps anything of. A noised tile: the noised tiles of its own blocks
+    (first, so that every row's running maximum is a kept score before a
+    tile that holds none of the row's), then the clean tiles of the blocks
+    before its last. A clean tile: no noised tile; the clean tiles up to its
+    own."""
+    q0 = qi * block_q
+    noised = q0 < half
+    own = q0 // block_k
+    own_end = _pick(noised, (q0 + block_q + block_k - 1) // block_k, own)
+    # clean tokens seen: those before the last row's block, or through it
+    seen = _pick(noised, q0 + block_q - blk, q0 + block_q - half)
+    clean = half // block_k
+    return (own, own_end), (clean, clean + (seen + block_k - 1) // block_k)
+
+
+def _blockdiff_q_ranges(ki, block_q, block_k, half, blk):
+    """``((first, last), (first, last))``: the Q tiles that keep anything
+    of K tile ``ki``. A noised tile: the noised tiles of its own blocks,
+    and no other. A clean tile: the noised tiles from the block after its
+    first on, and the clean tiles from its own on."""
+    k0 = ki * block_k
+    noised = k0 < half
+    j0 = _pick(noised, k0, k0 - half)
+    n_half = half // block_q
+    first = _pick(noised, k0 // block_q, (j0 + blk) // block_q)
+    last = _pick(noised, (k0 + block_k + block_q - 1) // block_q, n_half)
+    clean = n_half + j0 // block_q
+    return (first, last), (clean, _pick(noised, clean, 2 * n_half))
+
+
+def blockdiff_tiles(seq: int, block_q: int, block_k: int, blk: int):
+    """``(forward and dQ, dK/dV)``: the (Q tile, K tile) pairs a head's
+    loops visit under the block-diffusion mask of a ``seq`` = 2 x half
+    sequence, counted from the loop bounds."""
+    half = seq // 2
+    fwd = sum(
+        last - first
+        for qi in range(seq // block_q)
+        for first, last in _blockdiff_k_ranges(
+            qi, block_q, block_k, half, blk)
+    )
+    dkv = sum(
+        last - first
+        for ki in range(seq // block_k)
+        for first, last in _blockdiff_q_ranges(
+            ki, block_q, block_k, half, blk)
+    )
+    return fwd, dkv
+
+
+def _apply_blockdiff_mask(s, qi, kj, block_q, block_k, half, blk):
+    """The three-part mask on the score tile of Q tile ``qi`` and K tile
+    ``kj``: the block of every row and of every column, each from a vector
+    of its own, then two comparisons over the tile."""
+    q0, k0 = qi * block_q, kj * block_k
+    q_noised, k_noised = q0 < half, k0 < half
+    bi = jax.lax.div(
+        q0 - jnp.where(q_noised, 0, half)
+        + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0),
+        jnp.int32(blk),
+    )
+    bj = jax.lax.div(
+        k0 - jnp.where(k_noised, 0, half)
+        + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1),
+        jnp.int32(blk),
+    )
+    # kept: lowest <= b(j) <= highest. Noised on noised: the row's own
+    # block; noised on clean: the blocks before it; clean on clean: up to
+    # it; clean on noised: none
+    highest = jnp.where(
+        q_noised, jnp.where(k_noised, bi, bi - 1),
+        jnp.where(k_noised, -1, bi),
+    )
+    lowest = jnp.where(q_noised & k_noised, bi, 0)
+    return jnp.where((bj <= highest) & (bj >= lowest), s, _NEG_INF)
+
+
+def _loop_ranges(ranges, body, init):
+    """``body`` over each ``(first, last)`` of ``ranges`` in turn."""
+    for first, last in ranges:
+        init = jax.lax.fori_loop(first, last, body, init)
+    return init
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                block_q, block_k, padded=False, window=None):
+                block_q, block_k, padded=False, window=None,
+                blockdiff=None):
     if padded:
         len_ref, o_ref, lse_ref = rest
         kv_len = len_ref[pl.program_id(0)]
@@ -148,6 +270,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         n_blocks = _length_bound(kv_len, block_k, n_blocks)
     if window is not None:
         start = _window_start(qi, block_q, block_k, window)
+    ranges = ((start, n_blocks),)
+    if blockdiff is not None:
+        ranges = _blockdiff_k_ranges(
+            qi, block_q, block_k, seq_k // 2, blockdiff
+        )
     d_v = v_ref.shape[-1]  # the value's width, which may not be the key's
 
     def body(j, carry):
@@ -164,6 +291,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             s = _apply_length_mask(s, j, block_k, kv_len)
         if window is not None:
             s = _apply_window_mask(s, qi, j, block_q, block_k, window)
+        if blockdiff is not None:
+            s = _apply_blockdiff_mask(
+                s, qi, j, block_q, block_k, seq_k // 2, blockdiff
+            )
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -179,7 +310,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, d_v), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(start, n_blocks, body, (m0, l0, acc0))
+    m, l, acc = _loop_ranges(ranges, body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     # lane width comes from the out spec: 128 broadcast lanes or the
@@ -191,7 +322,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
                scale, causal, block_q, block_k, padded=False,
-               window=None):
+               window=None, blockdiff=None):
     if padded:
         len_ref, dq_ref = rest
         kv_len = len_ref[pl.program_id(0)]
@@ -216,6 +347,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
         n_blocks = _length_bound(kv_len, block_k, n_blocks)
     if window is not None:
         start = _window_start(qi, block_q, block_k, window)
+    ranges = ((start, n_blocks),)
+    if blockdiff is not None:
+        ranges = _blockdiff_k_ranges(
+            qi, block_q, block_k, seq_k // 2, blockdiff
+        )
     d = q_ref.shape[-1]
 
     def body(j, dq):
@@ -231,6 +367,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
             s = _apply_length_mask(s, j, block_k, kv_len)
         if window is not None:
             s = _apply_window_mask(s, qi, j, block_q, block_k, window)
+        if blockdiff is not None:
+            s = _apply_blockdiff_mask(
+                s, qi, j, block_q, block_k, seq_k // 2, blockdiff
+            )
         p = jnp.exp(s - lse)
         if padded:
             # Defense in depth, NOT load-bearing: padded query rows
@@ -256,15 +396,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
             preferred_element_type=jnp.float32,
         )
 
-    dq = jax.lax.fori_loop(
-        start, n_blocks, body, jnp.zeros((block_q, d), jnp.float32)
+    dq = _loop_ranges(
+        ranges, body, jnp.zeros((block_q, d), jnp.float32)
     )
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                 *rest, scale, causal, block_q, block_k, padded=False,
-                group=1, window=None):
+                group=1, window=None, blockdiff=None):
     """dK/dV over one K block. With grouped-query attention
     (``group`` = q heads per kv head > 1) the q/do/o/lse blocks carry
     the kv head's whole GROUP of q heads in their leading dim, and
@@ -296,6 +436,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             n_blocks,
             ((ki + 1) * block_k - 1 + window - 1) // block_q + 1,
         )
+    ranges = ((start, n_blocks),)
+    if blockdiff is not None:
+        ranges = _blockdiff_q_ranges(
+            ki, block_q, block_k, seq_q // 2, blockdiff
+        )
     d, d_v = k_ref.shape[-1], v_ref.shape[-1]
 
     def member_body(gm, i, dk, dv):
@@ -325,6 +470,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             s = _apply_length_mask(s, ki, block_k, kv_len)
         if window is not None:
             s = _apply_window_mask(s, i, ki, block_q, block_k, window)
+        if blockdiff is not None:
+            s = _apply_blockdiff_mask(
+                s, i, ki, block_q, block_k, seq_q // 2, blockdiff
+            )
         p = jnp.exp(s - lse)
         if padded:
             # Same defense-in-depth row zeroing as _dq_kernel (see the
@@ -357,7 +506,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     dk0 = jnp.zeros((block_k, d), jnp.float32)
     dv0 = jnp.zeros((block_k, d_v), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start, n_blocks, body, (dk0, dv0))
+    dk, dv = _loop_ranges(ranges, body, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -543,7 +692,7 @@ def _flash_bhtd_padded(q, k, v, lens, causal, block_q, block_k, window):
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
-               window=None):
+               window=None, blockdiff=None):
     """``h_per_kv`` > 1 = grouped-query attention: k/v carry bh//r rows
     (r = h_per_kv) and each q row p reads kv row p // r — exact because
     rows are batch-major/head-minor with kv-head groups contiguous.
@@ -559,7 +708,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, padded=lens is not None,
-        window=window,
+        window=window, blockdiff=blockdiff,
     )
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -653,7 +802,7 @@ def _flash_bwd_vjp_padded(causal, block_q, block_k, window, res, do):
 
 def _flash_bwd_impl(
     q, k, v, o, lse_lane, do, causal, block_q, block_k, lens=None,
-    h_per_kv=1, window=None,
+    h_per_kv=1, window=None, blockdiff=None,
 ):
     lanes = _interchange_lanes()
     if lanes == 1:
@@ -709,7 +858,7 @@ def _flash_bwd_impl(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, padded=padded,
-            window=window,
+            window=window, blockdiff=blockdiff,
         ),
         grid=(bh, n_q),
         in_specs=dq_in_specs,
@@ -725,14 +874,14 @@ def _flash_bwd_impl(
         dk, dv = _dkv_blocked(
             q, k, v, do, o, lse, lens[::r] if padded else None,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            group=r, window=window,
+            group=r, window=window, blockdiff=blockdiff,
         )
         return dq, dk, dv
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, padded=padded, group=r,
-            window=window,
+            window=window, blockdiff=blockdiff,
         ),
         grid=(kv_rows, n_k),
         in_specs=dkv_in_specs,
@@ -833,6 +982,40 @@ _flash_bhtd_gqa_padded.defvjp(
 )
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bhtd_blockdiff(q, k, v, block_q, block_k, h_per_kv, blockdiff):
+    """The block-diffusion mask's entry (any ``h_per_kv``): a rule of its
+    own, so that every other mask's keeps its arity."""
+    o, _ = _flash_fwd(
+        q, k, v, False, block_q, block_k, h_per_kv=h_per_kv,
+        blockdiff=blockdiff,
+    )
+    return o
+
+
+def _flash_fwd_vjp_blockdiff(q, k, v, block_q, block_k, h_per_kv,
+                             blockdiff):
+    q, k, v, o, lse_lane = _named_residuals(q, k, v, *_flash_fwd(
+        q, k, v, False, block_q, block_k, h_per_kv=h_per_kv,
+        blockdiff=blockdiff,
+    ))
+    return o, (q, k, v, o, lse_lane)
+
+
+def _flash_bwd_vjp_blockdiff(block_q, block_k, h_per_kv, blockdiff, res,
+                             do):
+    q, k, v, o, lse_lane = res
+    return _flash_bwd_impl(
+        q, k, v, o, lse_lane, do, False, block_q, block_k,
+        h_per_kv=h_per_kv, blockdiff=blockdiff,
+    )
+
+
+_flash_bhtd_blockdiff.defvjp(
+    _flash_fwd_vjp_blockdiff, _flash_bwd_vjp_blockdiff
+)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -842,6 +1025,7 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK,
     lengths: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Attention over [batch, seq, heads, head_dim] tensors (the model
     layout), softmax scale 1/√d. Differentiable (custom VJP, blockwise
@@ -878,7 +1062,19 @@ def flash_attention(
     ring attention for the memory win. The dK/dV kernel stages its q
     group whole-sequence where that fits the budget (:func:`fits_vmem`)
     and block by block within the band where it does not. Composes
-    with lengths and GQA."""
+    with lengths and GQA.
+
+    ``block_diffusion`` (int, the block length B): the sequence is a
+    noised copy of a row of ``t / 2`` tokens and then the clean row, and a
+    query keeps the keys that block-diffusion training lets it see
+    (:func:`_blockdiff_k_ranges`): in the noised copy its own block, both
+    ways, and the clean blocks before it; in the clean copy the clean
+    blocks up to its own, both ways inside a block. Masked in-kernel, two
+    loop ranges a program, so the tiles visited follow the ``(t/2)^2 + t/2
+    x B`` kept pairs (:func:`blockdiff_tiles`) and q, k and v are read as
+    they are. It is a mask of its own: ``causal``, ``window`` and
+    ``lengths`` are refused beside it. ``t / 2`` must be whole tiles and B
+    divide the tiles. Composes with GQA and a narrower v."""
     b, t, h, d = q.shape
     if k.shape[-1] != d:
         raise ValueError(
@@ -900,13 +1096,39 @@ def flash_attention(
             f"k={k.shape[2]}, v={v.shape[2]}"
         )
     h_per_kv = h // kv_h
-    block_q = _pick_block(t, block_q)
-    block_k = _pick_block(t, block_k)
+    if block_diffusion is None:
+        block_q = _pick_block(t, block_q)
+        block_k = _pick_block(t, block_k)
 
     def to_bhtd(x):
         hh, width = x.shape[2:]
         return x.transpose(0, 2, 1, 3).reshape(b * hh, t, width)
 
+    if block_diffusion is not None:
+        if causal or window is not None or lengths is not None:
+            raise ValueError(
+                "block_diffusion= is a mask of its own: causal, window= "
+                "and lengths= do not compose with it"
+            )
+        blk = int(block_diffusion)
+        if blk < 1 or t % 2 or (t // 2) % blk:
+            raise ValueError(
+                f"block_diffusion={blk} needs a sequence of two copies of "
+                f"a whole number of blocks, got {t} positions"
+            )
+        # tiles of one copy, so that none straddles the two
+        block_q = _pick_block(t // 2, block_q)
+        block_k = _pick_block(t // 2, block_k)
+        if block_q % blk or block_k % blk:
+            raise ValueError(
+                f"block_diffusion={blk} must divide the kernels' tiles "
+                f"({block_q} x {block_k})"
+            )
+        out = _flash_bhtd_blockdiff(
+            to_bhtd(q), to_bhtd(k), to_bhtd(v),
+            block_q, block_k, h_per_kv, blk,
+        )
+        return out.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
     if lengths is None:
         if h_per_kv == 1:
             out = _flash_bhtd(
@@ -971,9 +1193,16 @@ def _dkv_q_range(ki, block_q, block_k, n_q, causal, window):
     return first, last
 
 
-def _dkv_band_blocks(seq, block_q, block_k, causal, window):
+def _dkv_band_blocks(seq, block_q, block_k, causal, window,
+                     blockdiff=None):
     """The most q blocks any K block's band holds (static)."""
     n_q = seq // block_q
+    if blockdiff is not None:
+        return max(
+            sum(last - first for first, last in _blockdiff_q_ranges(
+                ki, block_q, block_k, seq // 2, blockdiff))
+            for ki in range(seq // block_k)
+        )
     if window is None or not causal:
         return n_q
     first_row = lambda ki: (ki * block_k // block_q) * block_q
@@ -984,9 +1213,22 @@ def _dkv_band_blocks(seq, block_q, block_k, causal, window):
     return min(n_q, -(-span // block_q))
 
 
+def _blockdiff_step_block(ki, step, block_q, block_k, n_q, blockdiff):
+    """``(q block, whether it is in the band)`` of step ``step`` of K block
+    ``ki``'s band under the block-diffusion mask: the first range's blocks
+    and then the second's. A step past the band names the band's last
+    block again, which is fetched already."""
+    (a0, a1), (b0, b1) = _blockdiff_q_ranges(
+        ki, block_q, block_k, n_q * block_q // 2, blockdiff
+    )
+    i = jnp.where(step < a1 - a0, a0 + step, b0 + step - (a1 - a0))
+    within = step < a1 - a0 + b1 - b0
+    return jnp.where(within, i, jnp.where(b1 > b0, b1, a1) - 1), within
+
+
 def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                         *rest, scale, causal, block_q, block_k, padded,
-                        steps, n_q, window):
+                        steps, n_q, window, blockdiff=None):
     if padded:
         len_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
         kv_len = len_ref[pl.program_id(0)]
@@ -995,17 +1237,24 @@ def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         kv_len = None
     ki = pl.program_id(1)
     t = pl.program_id(2)
-    first, last = _dkv_q_range(ki, block_q, block_k, n_q, causal, window)
-    if padded:
-        last = _length_bound(kv_len, block_q, last)
-    i = first + t % steps
+    if blockdiff is None:
+        first, last = _dkv_q_range(
+            ki, block_q, block_k, n_q, causal, window
+        )
+        if padded:
+            last = _length_bound(kv_len, block_q, last)
+        i = first + t % steps
+    else:
+        i, within = _blockdiff_step_block(
+            ki, t % steps, block_q, block_k, n_q, blockdiff
+        )
 
     @pl.when(t == 0)
     def _zero():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(i < last)
+    @pl.when(i < last if blockdiff is None else within)
     def _accumulate():
         k = k_ref[0].astype(jnp.float32)  # [BK, D]
         v = v_ref[0].astype(jnp.float32)
@@ -1025,6 +1274,10 @@ def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             s = _apply_length_mask(s, ki, block_k, kv_len)
         if window is not None:
             s = _apply_window_mask(s, i, ki, block_q, block_k, window)
+        if blockdiff is not None:
+            s = _apply_blockdiff_mask(
+                s, i, ki, block_q, block_k, n_q * block_q // 2, blockdiff
+            )
         p = jnp.exp(s - lse)
         if padded:
             rows = i * block_q + jax.lax.broadcasted_iota(
@@ -1052,7 +1305,7 @@ def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 
 def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
-                 block_k, group, window):
+                 block_k, group, window, blockdiff=None):
     """``(dk, dv)`` through :func:`_dkv_kernel_blocked`; ``lens`` is per
     kv row or None. Same operands and results as the whole-sequence
     call in :func:`_flash_bwd_impl`."""
@@ -1060,10 +1313,17 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
     d_v = v.shape[-1]
     lanes = lse.shape[-1]
     n_q, n_k = seq // block_q, seq // block_k
-    steps = _dkv_band_blocks(seq, block_q, block_k, causal, window)
+    steps = _dkv_band_blocks(
+        seq, block_q, block_k, causal, window, blockdiff
+    )
     r = group
 
     def q_block(b, ki, t):
+        if blockdiff is not None:
+            i, _ = _blockdiff_step_block(
+                ki, t % steps, block_q, block_k, n_q, blockdiff
+            )
+            return b * r + t // steps, i, 0
         first, _ = _dkv_q_range(ki, block_q, block_k, n_q, causal, window)
         return b * r + t // steps, jnp.minimum(first + t % steps, n_q - 1), 0
 
@@ -1086,7 +1346,7 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
         functools.partial(
             _dkv_kernel_blocked, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, padded=lens is not None,
-            steps=steps, n_q=n_q, window=window,
+            steps=steps, n_q=n_q, window=window, blockdiff=blockdiff,
         ),
         grid=(bh // r, n_k, r * steps),
         in_specs=in_specs,

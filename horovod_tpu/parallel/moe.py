@@ -417,7 +417,8 @@ def route_top_k(logits, select_bias, top_k: int, *, score: str = "sigmoid",
     router's float32 ``logits [tokens, experts]``. The scores are
     ``sigmoid`` or ``softmax`` of the logits; the k experts are chosen on
     score + ``select_bias`` (the load-balancing bias, which selection
-    alone reads: no gradient reaches it); the gates are the unbiased
+    alone reads: no gradient reaches it; None: a router without one, the
+    scores alone choose); the gates are the unbiased
     scores of the chosen, divided by their sum where ``norm``, times
     ``scale``."""
     logits = logits.astype(jnp.float32)
@@ -427,7 +428,10 @@ def route_top_k(logits, select_bias, top_k: int, *, score: str = "sigmoid",
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"score {score!r} is not sigmoid or softmax")
-    _, chosen = lax.top_k(scores + lax.stop_gradient(select_bias), top_k)
+    ranked = scores
+    if select_bias is not None:
+        ranked = scores + lax.stop_gradient(select_bias)
+    _, chosen = lax.top_k(ranked, top_k)
     chosen = checkpoint_name(chosen.astype(jnp.int32), "moe_chosen")
     # the chosen experts' scores, picked by comparison: the same values as
     # a gather along the experts, which on the chip costs 1 ms a layer for

@@ -60,6 +60,31 @@ def test_flash_kernels_compile_at_b2_s8192_gqa8_head128(
     assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
 
 
+def test_flash_kernels_compile_at_s16384_gqa8_head128_block_diffusion(
+        one_chip, as_on_the_chip):
+    """Block-diffusion training's shapes: one row of 8192 tokens twice, 32
+    query heads on 4 key/value heads of 128, blocks of 4 tokens. K and V
+    whole-sequence, twice, are 16 MiB, so the forward and dQ kernels
+    compile with Mosaic's scoped limit raised (``_staging_params``); the
+    dK/dV kernel stages by block, 32 steps a group member (what the first
+    clean K tile's band holds). The mask's vectors of one column and of
+    one row, and the integer division by the block length, are what
+    interpret mode cannot vouch for."""
+    shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                              sharding=one_chip)
+    q, kv = shape((1, 16384, 32, 128)), shape((1, 16384, 4, 128))
+    assert not fa.fits_vmem(16384, 128, 8, 2, 512)
+    assert fa.staged_vmem_bytes(16384, (128, 2), (128, 2)) == 16 * 2**20
+    assert fa._dkv_band_blocks(16384, 512, 512, False, None, 4) == 32
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, block_diffusion=4).astype(jnp.float32))
+
+    # forward, dQ, dK/dV
+    assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
+
+
 @pytest.mark.parametrize("staging", ["whole-sequence", "by-block"])
 def test_flash_kernels_compile_at_b2_s8192_key192_value128(
         staging, one_chip, as_on_the_chip, monkeypatch):
