@@ -37,11 +37,12 @@ need no request: their parent is the thread's active span or the
 process root context that ``hvd.init`` mints once. They land in the
 same ring in the same record shape. Names are ``hvd.<layer>.<what>``
 (``hvd.init.*``, ``hvd.trainer.*``, ``hvd.exchange.*``,
-``hvd.batcher.*``, ``hvd.engine.*``; docs/observability.md has the
-table). ``span`` is for work that happens a bounded number of times a
-process (init, placement, trace time) and always records;
-``hot_span`` is for work per scheduler round or per idle stretch and
-records only under ``HOROVOD_TRACE``, at ``HOROVOD_TRACE_SAMPLE``.
+``hvd.kernels.*``, ``hvd.batcher.*``, ``hvd.engine.*``;
+docs/observability.md has the table). ``span`` is for work that happens
+a bounded number of times a process (init, placement, trace time) and
+always records; ``hot_span`` is for work per scheduler round or per idle
+stretch and records only under ``HOROVOD_TRACE``, at
+``HOROVOD_TRACE_SAMPLE``.
 
 **The profiler's clock.** Every span that is *entered* (``with span``)
 while a ``jax.profiler`` session runs is also written into that session

@@ -23,8 +23,9 @@ against the dense jnp oracle).
 
 The masks the kernels take, each applied in the kernel with the loops
 clamped to the tiles it leaves anything in (forward and dQ loop over K
-blocks, dK/dV over Q blocks; ``flash_attention``'s docstring has the
-arguments):
+blocks, dK/dV over Q blocks; where dK/dV stages its q group by block its
+grid lists those tiles and no other, :func:`_dkv_steps`;
+``flash_attention``'s docstring has the arguments):
 
 * ``causal``: forward/dQ end at the diagonal (``_causal_bound``), dK/dV
   starts there;
@@ -50,9 +51,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..common import tracing as _tracing
 
 
 def _lens_spec():
@@ -547,13 +551,13 @@ def supports_seq(
 
 # What the dK/dV kernel may stage whole-sequence (:func:`bwd_vmem_bytes`):
 # up to it the kernel fetches the kv row's whole q-head group once and
-# loops over it in place; past it the q group is staged block by block
-# within the band (:func:`_dkv_blocked`), so the footprint follows block
-# and window and no shape is sent to the dense path for VMEM. A guess
-# made before the chip could be asked, and conservative: on the v5e
-# (scripts/chip_roster.py --vmem-sweep, d=128 bf16, PR 21) the
-# whole-sequence kernel compiled and ran up to an ESTIMATE of 48.8 MiB
-# and ran out of VMEM at 97 MiB.
+# loops over it in place; past it the q group is staged block by block, a
+# grid step for each tile the mask keeps (:func:`_dkv_blocked`), so the
+# footprint follows the block and no shape is sent to the dense path for
+# VMEM. A guess made before the chip could be asked, and conservative: on
+# the v5e (scripts/chip_roster.py --vmem-sweep, d=128 bf16, PR 21) the
+# whole-sequence kernel compiled and ran up to an ESTIMATE of 48.8 MiB and
+# ran out of VMEM at 97 MiB.
 _VMEM_BUDGET_DEFAULT = 12 * 2**20
 
 
@@ -1061,8 +1065,10 @@ def flash_attention(
     VMEM footprint remains O(seq) — at extreme sequence lengths use
     ring attention for the memory win. The dK/dV kernel stages its q
     group whole-sequence where that fits the budget (:func:`fits_vmem`)
-    and block by block within the band where it does not. Composes
-    with lengths and GQA.
+    and block by block where it does not: its grid is then a table, made
+    at trace time, of the (K tile, group member, Q tile) steps that this
+    mask keeps a pair in (:func:`_dkv_steps`), under any of the masks
+    here. Composes with lengths and GQA.
 
     ``block_diffusion`` (int, the block length B): the sequence is a
     noised copy of a row of ``t / 2`` tokens and then the clean row, and a
@@ -1070,11 +1076,12 @@ def flash_attention(
     (:func:`_blockdiff_k_ranges`): in the noised copy its own block, both
     ways, and the clean blocks before it; in the clean copy the clean
     blocks up to its own, both ways inside a block. Masked in-kernel, two
-    loop ranges a program, so the tiles visited follow the ``(t/2)^2 + t/2
-    x B`` kept pairs (:func:`blockdiff_tiles`) and q, k and v are read as
-    they are. It is a mask of its own: ``causal``, ``window`` and
-    ``lengths`` are refused beside it. ``t / 2`` must be whole tiles and B
-    divide the tiles. Composes with GQA and a narrower v."""
+    loop ranges a program (in the blocked dK/dV kernel: the table's steps),
+    so the tiles visited follow the ``(t/2)^2 + t/2 x B`` kept pairs
+    (:func:`blockdiff_tiles`) and q, k and v are read as they are. It is a
+    mask of its own: ``causal``, ``window`` and ``lengths`` are refused
+    beside it. ``t / 2`` must be whole tiles and B divide the tiles.
+    Composes with GQA and a narrower v."""
     b, t, h, d = q.shape
     if k.shape[-1] != d:
         raise ValueError(
@@ -1172,89 +1179,104 @@ def flash_attention(
 
 # dK/dV with the q group staged block by block (ROADMAP A9). The
 # whole-sequence kernel above fetches (group, seq, d) blocks of q, do and
-# o: 48 MiB at group 8, seq 8192, d 128. Here the grid gains an innermost
-# "arbitrary" dimension over (group member, q block within the K block's
-# band); each step fetches one (block_q, d) block of q, do, o and lse and
-# adds its part to dk/dv held in VMEM scratch, so the footprint follows
-# block_q and block_k alone. Steps past a K block's band (the causal
-# mask's short rows, a window's end) compute nothing and fetch nothing
-# new: their index clamps to the last block that was fetched.
+# o: 48 MiB at group 8, seq 8192, d 128. Here the grid is (kv row, step):
+# a step is one (K tile, group member, Q tile) that the mask keeps a pair
+# in, and fetches one (block_q, d) block of q, do, o and lse and adds its
+# part to dk/dv held in VMEM scratch, so the footprint follows block_q and
+# block_k alone. Every mask but ``lens`` is known at trace time, so the
+# steps are a table (:func:`_dkv_steps`) that the index maps and the
+# kernel read from scalar memory (scalar prefetch): a K tile's steps are
+# contiguous, within it group member outermost and Q tiles ascending, the
+# first zeroes the accumulators and the last stores them. The grid holds
+# no step that computes nothing, whatever the bands' widths (under the
+# block-diffusion mask they run from 1 to 32 Q tiles a K tile).
+#
+# A step is one int32 word, K tile | Q tile | group member | edge bits: the
+# v5e's scalar memory is 1 MiB (compiled for a described chip, a table of
+# 262,144 words was refused), and four columns of their own would end at
+# 65,000 steps: 46,000 tokens unmasked at group 8, which the forward and
+# dQ kernels' staging still takes.
+
+_FIRST, _LAST = 1, 2  # the edge bits: a K tile's first step, its last
+_MEMBER_SHIFT, _Q_SHIFT, _K_SHIFT = 2, 8, 20
+_MAX_GROUP = 1 << (_Q_SHIFT - _MEMBER_SHIFT)
+_MAX_TILES = 1 << (31 - _K_SHIFT)  # the K tile stops short of the sign
+
+
+def _step_fields(word):
+    """``(K tile, Q tile, group member, edge bits)`` of a step's word (of
+    a whole table's, given an array)."""
+    return (
+        word >> _K_SHIFT,
+        (word >> _Q_SHIFT) & ((1 << (_K_SHIFT - _Q_SHIFT)) - 1),
+        (word >> _MEMBER_SHIFT) & (_MAX_GROUP - 1),
+        word & (_FIRST | _LAST),
+    )
 
 
 def _dkv_q_range(ki, block_q, block_k, n_q, causal, window):
     """``[first, last)`` q blocks that see K block ``ki`` (before any
-    length bound): the same bounds as :func:`_dkv_kernel`'s loop."""
+    length bound), as plain numbers: the same bounds as
+    :func:`_dkv_kernel`'s loop."""
     first = ki * block_k // block_q if causal else 0
     last = n_q
     if window is not None:
-        last = jnp.minimum(
-            n_q, ((ki + 1) * block_k - 1 + window - 1) // block_q + 1
-        )
+        last = min(n_q, ((ki + 1) * block_k - 1 + window - 1) // block_q + 1)
     return first, last
 
 
-def _dkv_band_blocks(seq, block_q, block_k, causal, window,
-                     blockdiff=None):
-    """The most q blocks any K block's band holds (static)."""
-    n_q = seq // block_q
-    if blockdiff is not None:
-        return max(
-            sum(last - first for first, last in _blockdiff_q_ranges(
-                ki, block_q, block_k, seq // 2, blockdiff))
-            for ki in range(seq // block_k)
+def _dkv_steps(seq, block_q, block_k, group, causal, window, blockdiff=None):
+    """``(steps, kept)``: the blocked dK/dV kernel's steps a kv row, an
+    int32 word each (:func:`_step_fields`), and how many of them are
+    tiles the mask keeps a pair in (all of them, unless a K tile's band is
+    empty: it gets a step a group member on a tile the mask empties, so
+    that its zeros are written)."""
+    n_q, n_k = seq // block_q, seq // block_k
+    if max(n_q, n_k) > _MAX_TILES or group > _MAX_GROUP:
+        raise ValueError(
+            f"the dK/dV kernel's table of steps holds {_MAX_TILES} tiles a "
+            f"sequence and {_MAX_GROUP} query heads a kv head: got "
+            f"{max(n_q, n_k)} tiles (seq {seq}, blocks {block_q} x "
+            f"{block_k}) and {group} heads"
         )
-    if window is None or not causal:
-        return n_q
-    first_row = lambda ki: (ki * block_k // block_q) * block_q
-    span = max(
-        min(seq, (ki + 1) * block_k - 1 + window) - first_row(ki)
-        for ki in range(seq // block_k)
-    )
-    return min(n_q, -(-span // block_q))
+    tiles, kept = [], 0
+    for ki in range(n_k):
+        if blockdiff is not None:
+            ranges = _blockdiff_q_ranges(
+                ki, block_q, block_k, seq // 2, blockdiff
+            )
+        else:
+            ranges = (_dkv_q_range(ki, block_q, block_k, n_q, causal, window),)
+        band = np.concatenate([np.arange(*r) for r in ranges])
+        kept += group * len(band)
+        if not len(band):
+            band = np.zeros(1, int)
+        steps = (
+            ki << _K_SHIFT | np.tile(band, group) << _Q_SHIFT
+            | np.repeat(np.arange(group), len(band)) << _MEMBER_SHIFT
+        )
+        steps[0] |= _FIRST
+        steps[-1] |= _LAST
+        tiles.append(steps)
+    return np.concatenate(tiles).astype(np.int32), kept
 
 
-def _blockdiff_step_block(ki, step, block_q, block_k, n_q, blockdiff):
-    """``(q block, whether it is in the band)`` of step ``step`` of K block
-    ``ki``'s band under the block-diffusion mask: the first range's blocks
-    and then the second's. A step past the band names the band's last
-    block again, which is fetched already."""
-    (a0, a1), (b0, b1) = _blockdiff_q_ranges(
-        ki, block_q, block_k, n_q * block_q // 2, blockdiff
-    )
-    i = jnp.where(step < a1 - a0, a0 + step, b0 + step - (a1 - a0))
-    within = step < a1 - a0 + b1 - b0
-    return jnp.where(within, i, jnp.where(b1 > b0, b1, a1) - 1), within
-
-
-def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                        *rest, scale, causal, block_q, block_k, padded,
-                        steps, n_q, window, blockdiff=None):
+def _dkv_kernel_blocked(steps_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
+                        lse_ref, *rest, scale, causal, block_q, block_k,
+                        padded, n_q, window, blockdiff=None):
     if padded:
         len_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
         kv_len = len_ref[pl.program_id(0)]
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
         kv_len = None
-    ki = pl.program_id(1)
-    t = pl.program_id(2)
-    if blockdiff is None:
-        first, last = _dkv_q_range(
-            ki, block_q, block_k, n_q, causal, window
-        )
-        if padded:
-            last = _length_bound(kv_len, block_q, last)
-        i = first + t % steps
-    else:
-        i, within = _blockdiff_step_block(
-            ki, t % steps, block_q, block_k, n_q, blockdiff
-        )
+    ki, i, _, edge = _step_fields(steps_ref[pl.program_id(1)])
 
-    @pl.when(t == 0)
+    @pl.when(edge & _FIRST != 0)
     def _zero():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(i < last if blockdiff is None else within)
     def _accumulate():
         k = k_ref[0].astype(jnp.float32)  # [BK, D]
         v = v_ref[0].astype(jnp.float32)
@@ -1298,7 +1320,14 @@ def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(t == pl.num_programs(2) - 1)
+    if padded:
+        # the one bound the table cannot hold: Q tiles wholly past the
+        # valid length (do == 0 there, zeroed by the wrapper)
+        pl.when(i < _length_bound(kv_len, block_q, n_q))(_accumulate)
+    else:
+        _accumulate()
+
+    @pl.when(edge & _LAST != 0)
     def _store():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -1312,23 +1341,18 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
     bh, seq, d = q.shape
     d_v = v.shape[-1]
     lanes = lse.shape[-1]
-    n_q, n_k = seq // block_q, seq // block_k
-    steps = _dkv_band_blocks(
-        seq, block_q, block_k, causal, window, blockdiff
-    )
     r = group
+    steps, kept = _dkv_steps(
+        seq, block_q, block_k, r, causal, window, blockdiff
+    )
+    grid = (bh // r, len(steps))
 
-    def q_block(b, ki, t):
-        if blockdiff is not None:
-            i, _ = _blockdiff_step_block(
-                ki, t % steps, block_q, block_k, n_q, blockdiff
-            )
-            return b * r + t // steps, i, 0
-        first, _ = _dkv_q_range(ki, block_q, block_k, n_q, causal, window)
-        return b * r + t // steps, jnp.minimum(first + t % steps, n_q - 1), 0
+    def q_block(b, step, steps_ref):
+        _, i, member, _ = _step_fields(steps_ref[step])
+        return b * r + member, i, 0
 
-    def kv_block(b, ki, t):
-        return b, ki, 0
+    def kv_block(b, step, steps_ref):
+        return b, _step_fields(steps_ref[step])[0], 0
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), q_block),
@@ -1342,29 +1366,37 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
     if lens is not None:
         in_specs.append(_lens_spec())
         operands.append(lens)
-    return pl.pallas_call(
-        functools.partial(
-            _dkv_kernel_blocked, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, padded=lens is not None,
-            steps=steps, n_q=n_q, window=window, blockdiff=blockdiff,
-        ),
-        grid=(bh // r, n_k, r * steps),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), kv_block),
-            pl.BlockSpec((1, block_k, d_v), kv_block),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d_v), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=_interpret(),
-        name="flash_dkv",
-    )(*operands)
+    # trace time only: what the grid holds beside what the mask keeps
+    with _tracing.trace_time_span(
+        "hvd.kernels.flash_dkv_grid", q, grid_steps=grid[0] * grid[1],
+        kept_tiles=grid[0] * kept, kv_rows=grid[0], seq=seq, group=r,
+    ):
+        return pl.pallas_call(
+            functools.partial(
+                _dkv_kernel_blocked, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k, padded=lens is not None,
+                n_q=seq // block_q, window=window, blockdiff=blockdiff,
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=in_specs,
+                out_specs=[
+                    pl.BlockSpec((1, block_k, d), kv_block),
+                    pl.BlockSpec((1, block_k, d_v), kv_block),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((block_k, d), jnp.float32),
+                    pltpu.VMEM((block_k, d_v), jnp.float32),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
+            ),
+            interpret=_interpret(),
+            name="flash_dkv",
+        )(steps, *operands)

@@ -333,8 +333,11 @@ def test_the_8k_shape_rides_the_kernels_with_its_q_group_staged_by_block():
     # whole-sequence staging would be 48.8 MiB: the backward stages by block
     assert fa.bwd_vmem_bytes(8192, 128, group, 2, 512) > 48 * 2**20
     assert not fa.fits_vmem(8192, 128, group, 2, 512)
-    assert fa._dkv_band_blocks(8192, 512, 512, True, 2048) == 5
-    assert fa._dkv_band_blocks(8192, 512, 512, True, None) == 16
+    # a grid step for each tile the mask keeps and group member: bands of
+    # 5 Q tiles and 4, 3, 2, 1 at the end; 16, 15, ..., 1
+    for window, tiles in ((2048, 12 * 5 + 10), (None, 136)):
+        steps, kept = fa._dkv_steps(8192, 512, 512, group, True, window)
+        assert kept == len(steps) == tiles * group
     # GPT-2's and BERT's shapes keep the kernel they had
     assert fa.fits_vmem(512, 64, 1, 2, 512)
 

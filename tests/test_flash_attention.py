@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.flash_attention import flash_attention
 
 
@@ -428,3 +429,230 @@ def test_sliding_window_requires_causal():
     q = _rand((1, 16, 2, 8), 0)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, q, q, causal=False, window=8)
+
+
+# ------------- the blocked dK/dV kernel: its table of steps, and its values
+
+def _keep(mask, seq, arg):
+    """Which key (column) a query (row) keeps, by comparison of indices."""
+    r, c = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    if mask == "block_diffusion":
+        half = seq // 2
+        bi, bj = r % half // arg, c % half // arg
+        return np.where(r < half, np.where(c < half, bi == bj, bj < bi),
+                        (c >= half) & (bj <= bi))
+    if mask == "full":
+        return np.ones((seq, seq), bool)
+    return (c <= r) & ((r - c < arg) if arg else True)
+
+
+def _steps(mask, seq, block_q, block_k, arg, group):
+    return fa._dkv_steps(
+        seq, block_q, block_k, group, mask in ("causal", "window"),
+        arg if mask == "window" else None,
+        arg if mask == "block_diffusion" else None)
+
+
+@pytest.mark.parametrize("mask, seq, block_q, block_k, arg, group, tiles", [
+    # block-diffusion training's shapes (SDAR), and Trinity's two layer
+    # kinds: counted, not compared with a 16,384 x 16,384 mask
+    ("block_diffusion", 16384, 512, 512, 4, 8, 288),
+    ("causal", 8192, 512, 512, None, 8, 136),
+    ("window", 8192, 512, 512, 2048, 8, 70),
+    ("block_diffusion", 128, 16, 16, 4, 8, None),
+    ("block_diffusion", 128, 16, 16, 16, 1, None),
+    ("block_diffusion", 128, 32, 16, 4, 2, None),
+    ("block_diffusion", 128, 8, 32, 8, 2, None),
+    ("block_diffusion", 96, 16, 8, 4, 1, None),
+    ("causal", 64, 16, 16, None, 1, None),
+    ("causal", 64, 16, 16, None, 4, None),
+    ("causal", 96, 32, 16, None, 2, None),
+    ("causal", 96, 16, 48, None, 2, None),
+    ("window", 64, 16, 16, 8, 2, None),
+    ("window", 64, 16, 16, 24, 1, None),
+    ("window", 128, 16, 32, 40, 8, None),
+    ("window", 128, 32, 16, 17, 2, None),
+    ("full", 64, 16, 32, None, 2, None),
+])
+def test_the_dkv_step_table_lists_the_tiles_the_mask_keeps(
+        mask, seq, block_q, block_k, arg, group, tiles):
+    """Exactly the tiles that keep a pair, each once a group member; a K
+    tile's steps contiguous, group member outermost and Q tiles ascending,
+    the first and the last marked: nothing for the grid to skip."""
+    steps, kept = _steps(mask, seq, block_q, block_k, arg, group)
+    assert steps.dtype == np.int32 and steps.shape == (kept,)
+    k_tile, q_tile, member, edge = fa._step_fields(steps)
+    n_q, n_k = seq // block_q, seq // block_k
+    if tiles is not None:
+        assert kept == tiles * group
+        bands = [int(np.sum(k_tile == ki)) // group for ki in range(n_k)]
+        if mask == "block_diffusion":
+            assert bands == [1] * 16 + list(range(32, 0, -2))
+        elif mask == "causal":
+            assert bands == list(range(16, 0, -1))
+        else:
+            assert bands == [5] * 12 + [4, 3, 2, 1]
+    else:
+        keeps = _keep(mask, seq, arg).reshape(
+            n_q, block_q, n_k, block_k).any(axis=(1, 3))
+        assert kept == group * int(keeps.sum())
+    start = 0
+    for ki in range(n_k):
+        (at,) = np.nonzero(k_tile == ki)
+        assert list(at) == list(range(start, start + len(at))), ki
+        start += len(at)
+        band = sorted(set(q_tile[at]))
+        if tiles is None:
+            assert band == list(np.flatnonzero(keeps[:, ki])), ki
+        assert list(q_tile[at]) == band * group
+        assert list(member[at]) == [
+            m for m in range(group) for _ in band]
+        want = np.zeros(len(at), int)
+        want[0] |= fa._FIRST
+        want[-1] |= fa._LAST
+        assert list(edge[at]) == list(want), ki
+    assert start == kept
+
+
+def test_a_k_tile_with_an_empty_band_still_gets_a_step_and_writes_zeros(
+        monkeypatch):
+    """No mask of today's empties a K tile's band; were one to, the table
+    gives the tile a step a group member on a tile that the mask empties,
+    so the kernel zeroes and stores its accumulators as for any other."""
+    real = fa._dkv_q_range
+
+    def emptied(ki, *args):
+        first, last = real(ki, *args)
+        return (first, first) if ki == 2 else (first, last)
+
+    t, heads, d = 64, 4, 8
+    q = _rand((1, t, heads, d), 50)
+    k, v = _rand((1, t, 2, d), 51), _rand((1, t, 2, d), 52)
+
+    def grads():
+        return jax.grad(lambda k, v: flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=16).sum(), (0, 1))(k, v)
+
+    monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET", "1")
+    untouched = grads()
+    monkeypatch.setattr(fa, "_dkv_q_range", emptied)
+    steps, kept = fa._dkv_steps(t, 16, 16, 2, True, None)
+    k_tile, q_tile, _, edge = fa._step_fields(steps)
+    # bands of 4, 3, (2 ->) 0, 1 Q tiles, and the steps that stand in
+    assert kept == 2 * 8 and len(steps) == kept + 2
+    (at,) = np.nonzero(k_tile == 2)
+    assert list(edge[at]) == [fa._FIRST, fa._LAST]
+    assert (q_tile[at] < 2).all()  # above the diagonal: nothing is kept
+    for mine, theirs in zip(grads(), untouched):
+        assert float(jnp.abs(mine[:, 32:48]).max()) == 0.0
+        np.testing.assert_array_equal(mine[:, :32], theirs[:, :32])
+        np.testing.assert_array_equal(mine[:, 48:], theirs[:, 48:])
+
+
+def test_the_step_table_refuses_what_its_words_cannot_hold():
+    # the largest it takes: 2,048 tiles (bands of two under this window,
+    # of one at the end), 64 heads a group
+    steps, kept = fa._dkv_steps(2048 * 8, 8, 8, 64, True, 8)
+    k_tile, q_tile, member, _ = fa._step_fields(steps)
+    assert (steps >= 0).all() and kept == len(steps) == 64 * (2 * 2048 - 1)
+    assert (k_tile.max(), q_tile.max(), member.max()) == (2047, 2047, 63)
+    with pytest.raises(ValueError, match="2049 tiles"):
+        fa._dkv_steps(2049 * 8, 8, 8, 1, True, 8)
+    with pytest.raises(ValueError, match="65 heads"):
+        fa._dkv_steps(64, 16, 16, 65, True, None)
+
+
+def _blocked_case(mask, arg, heads, kv_heads, lengths):
+    return pytest.param(mask, arg, heads, kv_heads, lengths, id="-".join(
+        [mask, f"{heads}on{kv_heads}"] + (["padded"] if lengths else [])))
+
+
+@pytest.mark.parametrize("mask, arg, heads, kv_heads, lengths", [
+    _blocked_case("causal", None, 2, 2, None),
+    _blocked_case("causal", None, 8, 1, None),
+    _blocked_case("window", 24, 2, 2, None),
+    _blocked_case("window", 40, 4, 1, None),
+    _blocked_case("block_diffusion", 4, 2, 2, None),
+    _blocked_case("block_diffusion", 8, 8, 2, None),
+    _blocked_case("full", None, 4, 2, None),
+    _blocked_case("full", None, 2, 2, [96, 37]),
+    _blocked_case("causal", None, 4, 1, [50, 96]),
+    _blocked_case("window", 24, 4, 2, [96, 17]),
+])
+def test_blocked_dkv_equals_the_whole_sequence_kernel(
+        mask, arg, heads, kv_heads, lengths, monkeypatch):
+    """The two forms of the dK/dV kernel on the same inputs: the same
+    products of the same tiles, summed Q tile by Q tile in one and group
+    member by group member in the other; and both against dense attention
+    under the mask."""
+    t, d = 96, 16
+    q = _rand((2, t, heads, d), 60)
+    k, v = _rand((2, t, kv_heads, d), 61), _rand((2, t, kv_heads, d), 62)
+    w = _rand((2, t, heads, d), 63)
+    kwargs = dict(block_q=16, block_k=16)
+    if mask == "block_diffusion":
+        kwargs["block_diffusion"] = arg
+    else:
+        kwargs.update(causal=mask != "full",
+                      window=arg if mask == "window" else None)
+    lens = None if lengths is None else jnp.asarray(lengths, jnp.int32)
+
+    def grads(budget):
+        monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET", budget)
+        assert fa.fits_vmem(t, d, heads // kv_heads, 4, 16) == (
+            budget != "1")
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, lengths=lens, **kwargs) * w), (0, 1, 2))(q, k, v)
+
+    def dense(q, k, v):
+        group = heads // kv_heads
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, group, 2))
+        keep = jnp.asarray(_keep(mask, t, arg))[None, None]
+        if lens is not None:
+            keep = keep & (jnp.arange(t) < lens[:, None])[:, None, None, :]
+        p = jax.nn.softmax(jnp.where(keep, s / np.sqrt(d), -1e30), -1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, group, 2))
+        if lens is not None:
+            o = jnp.where((jnp.arange(t) < lens[:, None])[..., None, None],
+                          o, 0.0)
+        return jnp.sum(o * w)
+
+    blocked, whole = grads("1"), grads(str(2**30))
+    want = jax.grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_array_equal(blocked[0], whole[0])  # dQ: one kernel
+    for name, mine, theirs, ref in zip("kv", blocked[1:], whole[1:],
+                                       want[1:]):
+        # float32 sums of up to 8 x 6 tiles' products in two orders
+        np.testing.assert_allclose(mine, theirs, atol=2e-6, rtol=2e-6,
+                                   err_msg=name)
+        np.testing.assert_allclose(mine, ref, atol=2e-5, rtol=1e-5,
+                                   err_msg=name)
+
+
+def test_a_traced_blocked_call_records_what_its_grid_holds(monkeypatch):
+    """``hvd.kernels.flash_dkv_grid``: a span a blocked dK/dV call while
+    JAX traces it, tagged with the grid's steps and the tiles the mask
+    keeps; an eager call records none."""
+    from horovod_tpu.common import tracing
+
+    monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET", "1")
+    q = _rand((2, 128, 4, 8), 70)
+    kv = _rand((2, 128, 2, 8), 71)
+
+    def grad(q, k, v):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, block_q=16, block_k=16, block_diffusion=4).sum(),
+            (1, 2))(q, k, v)
+
+    def spans():
+        return [r for r in tracing.recorder().spans()
+                if r["name"] == "hvd.kernels.flash_dkv_grid"]
+
+    before = len(spans())
+    jax.block_until_ready(jax.jit(grad)(q, kv, kv))
+    (span,) = spans()[before:]
+    # 4 noised K tiles of 1 Q tile, clean bands of 8, 6, 4, 2: 24 tiles
+    # for each of 2 group members and 4 kv rows (the rectangle held 8
+    # steps a K tile: 4 x 8 x 2 x 8 = 512)
+    assert span["tags"] == {"grid_steps": 192, "kept_tiles": 192,
+                            "kv_rows": 4, "seq": 128, "group": 2}

@@ -136,6 +136,11 @@ def test_the_loops_visit_the_tiles_the_mask_leaves_anything_in(
         # 16 noised tiles on themselves, and twice the 136 of a
         # block-causal row of 16 tiles: not the 1,024 of the square
         assert fwd == dkv == 16 + 2 * 136 == 288
+        # and the blocked dK/dV kernel's grid holds just those, a group
+        # member: 2,304 steps where the widest band's rectangle held 8,192
+        steps, kept = fa._dkv_steps(2 * length, block_q, block_k, 8, False,
+                                    None, block)
+        assert kept == len(steps) == 288 * 8
         return
     keep = _mask(length, block).reshape(n_q, block_q, n_k, block_k)
     kept = keep.any(axis=(1, 3))
@@ -148,18 +153,18 @@ def test_the_loops_visit_the_tiles_the_mask_leaves_anything_in(
         visited = {i for first, last in fa._blockdiff_q_ranges(
             ki, block_q, block_k, length, block) for i in range(first, last)}
         assert visited == set(np.flatnonzero(kept[:, ki])), ki
-    # the blocked dK/dV kernel's steps: the widest band, and past a
-    # narrower one the band's last block again
-    steps = fa._dkv_band_blocks(2 * length, block_q, block_k, False, None,
-                                block)
-    assert steps == int(kept.sum(axis=0).max())
+    # the blocked dK/dV kernel's grid: a step for each of those tiles and
+    # group member, a K tile's together, and no step beside them
+    steps, tiles = fa._dkv_steps(
+        2 * length, block_q, block_k, 2, False, None, block)
+    assert tiles == len(steps) == 2 * int(kept.sum())
+    k_tile, q_tile, member, _ = fa._step_fields(steps)
     for ki in range(n_k):
         band = list(np.flatnonzero(kept[:, ki]))
-        for step in range(steps):
-            i, within = fa._blockdiff_step_block(
-                jnp.int32(ki), jnp.int32(step), block_q, block_k, n_q, block)
-            assert bool(within) == (step < len(band))
-            assert int(i) == (band[step] if step < len(band) else band[-1])
+        (at,) = np.nonzero(k_tile == ki)
+        assert list(at) == list(range(at[0], at[0] + 2 * len(band)))
+        assert list(q_tile[at]) == band + band
+        assert list(member[at]) == [0] * len(band) + [1] * len(band)
 
 
 def test_the_mask_is_refused_beside_another_and_off_its_tiles():

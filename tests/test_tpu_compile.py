@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from horovod_tpu.common import tracing
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel import moe
 
@@ -44,9 +45,16 @@ def _compiled(fn, *args):
     return text.count('custom_call_target="tpu_custom_call"')
 
 
-@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def _dkv_grids():
+    """What the blocked dK/dV calls traced so far said of their grids."""
+    return [r["tags"] for r in tracing.recorder().spans()
+            if r["name"] == "hvd.kernels.flash_dkv_grid"]
+
+
+@pytest.mark.parametrize("window, tiles", [(2048, 70), (None, 136)],
+                         ids=["window", "full"])
 def test_flash_kernels_compile_at_b2_s8192_gqa8_head128(
-        window, one_chip, as_on_the_chip):
+        window, tiles, one_chip, as_on_the_chip):
     shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
                               sharding=one_chip)
     q, kv = shape((2, 8192, 32, 128)), shape((2, 8192, 4, 128))
@@ -57,7 +65,13 @@ def test_flash_kernels_compile_at_b2_s8192_gqa8_head128(
             q, k, v, causal=True, window=window).astype(jnp.float32))
 
     # forward, dQ, dK/dV
+    traced = len(_dkv_grids())
     assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
+    # the dK/dV grid (its steps' table in scalar memory): the kept tiles of
+    # 8 kv rows and 8 group members, where the widest band's rectangle
+    # held 8 x 16 x 8 x (5 | 16) = 5,120 | 16,384
+    (grid,) = _dkv_grids()[traced:]
+    assert grid["grid_steps"] == grid["kept_tiles"] == 8 * 8 * tiles
 
 
 def test_flash_kernels_compile_at_s16384_gqa8_head128_block_diffusion(
@@ -66,23 +80,28 @@ def test_flash_kernels_compile_at_s16384_gqa8_head128_block_diffusion(
     query heads on 4 key/value heads of 128, blocks of 4 tokens. K and V
     whole-sequence, twice, are 16 MiB, so the forward and dQ kernels
     compile with Mosaic's scoped limit raised (``_staging_params``); the
-    dK/dV kernel stages by block, 32 steps a group member (what the first
-    clean K tile's band holds). The mask's vectors of one column and of
-    one row, and the integer division by the block length, are what
+    dK/dV kernel stages by block, a grid step for each of the 288 tiles
+    the mask keeps and group member (a table of 2,304 int32 in scalar
+    memory). The mask's vectors of one column and of one row, the integer
+    division by the block length and the scalar prefetch are what
     interpret mode cannot vouch for."""
     shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
                               sharding=one_chip)
     q, kv = shape((1, 16384, 32, 128)), shape((1, 16384, 4, 128))
     assert not fa.fits_vmem(16384, 128, 8, 2, 512)
     assert fa.staged_vmem_bytes(16384, (128, 2), (128, 2)) == 16 * 2**20
-    assert fa._dkv_band_blocks(16384, 512, 512, False, None, 4) == 32
 
     def loss(q, k, v):
         return jnp.sum(fa.flash_attention(
             q, k, v, block_diffusion=4).astype(jnp.float32))
 
     # forward, dQ, dK/dV
+    traced = len(_dkv_grids())
     assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
+    # 4 kv rows x 8 group members x 288 tiles, where the rectangle of the
+    # widest band (32 Q tiles) held 4 x 32 x 8 x 32 = 32,768 steps
+    (grid,) = _dkv_grids()[traced:]
+    assert grid["grid_steps"] == grid["kept_tiles"] == 9216
 
 
 @pytest.mark.parametrize("staging", ["whole-sequence", "by-block"])
