@@ -313,6 +313,25 @@ EXE_CACHE_METRICS = (
     "driver.standby.swapins",
 )
 
+# The compile ledger's counters (common/compile_cache.py; they answer
+# "did this process recompile after start-up, and did the persistent
+# cache have it": docs/observability.md):
+#   jit.compiles                  backend compiles JAX asked for, cache
+#                                 retrievals included (counter)
+#   jit.cache_hits / cache_misses the persistent cache's answers as JAX
+#                                 reports them: a program loaded / one
+#                                 compiled and written (counters; a
+#                                 compile under JAX's floors for keeping
+#                                 is neither)
+#   jit.compile_s                 seconds in those compiles and
+#                                 retrievals (counter)
+JIT_METRICS = (
+    "jit.compiles",
+    "jit.cache_hits",
+    "jit.cache_misses",
+    "jit.compile_s",
+)
+
 
 class MetricsRegistry:
     def __init__(self) -> None:

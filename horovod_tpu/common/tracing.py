@@ -241,6 +241,13 @@ class Span:
             }
         )
 
+    def discard(self) -> None:
+        """Leave an entered span without a record: the thread's stack and
+        a profiler session see it end, the ring does not (the compile
+        ledger's events under its floor)."""
+        self._done = True
+        self.__exit__(None, None, None)
+
     # -- thread-local active-span stack (for retry annotations) --
 
     def __enter__(self) -> "Span":
@@ -525,6 +532,16 @@ def trace_time_span(name: str, probe, **tags):
     if jax is None or not isinstance(probe, jax.core.Tracer):
         return _NO_SPAN
     return span(name, **tags)
+
+
+def record(name: str, start: float, end: float, **tags) -> None:
+    """A process span for an interval that is already over (``start`` and
+    ``end`` in ``time.time()`` seconds): a child of this thread's active
+    span like :func:`span`, in the ring alone. For work whose start was
+    not worth a live span (the compile ledger's events inside another)."""
+    s = span(name, **tags)
+    s.ts, s._t0 = start, time.monotonic() - (end - start)
+    s.end()
 
 
 def root_span(name: str, ctx: Optional[TraceContext], **tags):

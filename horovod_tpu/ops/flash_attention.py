@@ -672,6 +672,34 @@ def _staging_params(rows: int, *operands):
     )
 
 
+def _call_span(kernel, q, v, grid, *, causal, window, padded, blockdiff,
+               block_q, block_k, group, whole=None, **more):
+    """The span ``hvd.kernels.flash_call`` around one ``pl.pallas_call``
+    of this file while JAX traces it (the kernel body's jaxpr is made
+    inside the call), else a null context: the call's plan as tags.
+    ``whole`` is what the call stages whole-sequence, ``(rows,
+    *operands)`` as :func:`staged_vmem_bytes` and
+    :func:`_staging_params` take them; None for a blocked call."""
+    if blockdiff is not None:
+        mask = "block_diffusion"
+    else:
+        mask = "window" if window is not None else (
+            "causal" if causal else "none")
+        if padded:
+            mask = "lengths" if mask == "none" else mask + "+lengths"
+    params = _staging_params(*whole) if whole else None
+    return _tracing.trace_time_span(
+        "hvd.kernels.flash_call", q, kernel=kernel,
+        staging="whole" if whole else "blocked", mask=mask,
+        block_q=block_q, block_k=block_k, seq=q.shape[1], heads=q.shape[0],
+        group=group, d_qk=q.shape[2], d_v=v.shape[2],
+        grid_steps=grid[0] * grid[1],
+        staged_vmem_bytes=staged_vmem_bytes(*whole) if whole else 0,
+        vmem_limit_bytes=getattr(params, "vmem_limit_bytes", None) or 0,
+        **more,
+    )
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
 )
@@ -723,26 +751,30 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, lens=None, h_per_kv=1,
     if lens is not None:
         in_specs.append(_lens_spec())
         operands.append(lens)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, n_q),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec(
-                (1, block_q, lanes), lambda b, i: (b, i, 0)
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq, lanes), jnp.float32),
-        ],
-        compiler_params=_staging_params(
-            seq, (d, k.dtype.itemsize), (d_v, v.dtype.itemsize)
-        ),
-        interpret=_interpret(),
-        name="flash_fwd",
-    )(*operands)
+    kv = (seq, (d, k.dtype.itemsize), (d_v, v.dtype.itemsize))
+    with _call_span(
+        "flash_fwd", q, v, (bh, n_q), causal=causal, window=window,
+        padded=lens is not None, blockdiff=blockdiff, block_q=block_q,
+        block_k=block_k, group=r, whole=kv,
+    ):
+        o, lse = pl.pallas_call(
+            kernel,
+            grid=(bh, n_q),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
+                pl.BlockSpec(
+                    (1, block_q, lanes), lambda b, i: (b, i, 0)
+                ),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
+                jax.ShapeDtypeStruct((bh, seq, lanes), jnp.float32),
+            ],
+            compiler_params=_staging_params(*kv),
+            interpret=_interpret(),
+            name="flash_fwd",
+        )(*operands)
     return o, lse
 
 
@@ -858,22 +890,28 @@ def _flash_bwd_impl(
         # per-KV-row lengths: every r-th q row's entry (lengths are
         # per-batch, so the group's rows all agree)
         dkv_operands.append(lens[::r])
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, padded=padded,
-            window=window, blockdiff=blockdiff,
-        ),
-        grid=(bh, n_q),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_staging_params(
-            seq, (d, k.dtype.itemsize), (d_v, v.dtype.itemsize)
-        ),
-        interpret=_interpret(),
-        name="flash_dq",
-    )(*dq_operands)
+    plan = dict(
+        causal=causal, window=window, padded=padded, blockdiff=blockdiff,
+        block_q=block_q, block_k=block_k, group=r,
+    )
+    kv = (seq, (d, k.dtype.itemsize), (d_v, v.dtype.itemsize))
+    with _call_span("flash_dq", q, v, (bh, n_q), whole=kv, **plan):
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k, padded=padded,
+                window=window, blockdiff=blockdiff,
+            ),
+            grid=(bh, n_q),
+            in_specs=dq_in_specs,
+            out_specs=pl.BlockSpec(
+                (1, block_q, d), lambda b, i: (b, i, 0)
+            ),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            compiler_params=_staging_params(*kv),
+            interpret=_interpret(),
+            name="flash_dq",
+        )(*dq_operands)
     if not fits_vmem(seq, d, r, q.dtype.itemsize, block_k, d_v):
         dk, dv = _dkv_blocked(
             q, k, v, do, o, lse, lens[::r] if padded else None,
@@ -881,30 +919,34 @@ def _flash_bwd_impl(
             group=r, window=window, blockdiff=blockdiff,
         )
         return dq, dk, dv
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, padded=padded, group=r,
-            window=window, blockdiff=blockdiff,
-        ),
-        grid=(kv_rows, n_k),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        # the group's q, do, o and float32 lse
-        compiler_params=_staging_params(
-            r * seq, (d, q.dtype.itemsize), (d_v, do.dtype.itemsize),
-            (d_v, o.dtype.itemsize), (lanes, 4),
-        ),
-        interpret=_interpret(),
-        name="flash_dkv",
-    )(*dkv_operands)
+    # the group's q, do, o and float32 lse
+    group_rows = (
+        r * seq, (d, q.dtype.itemsize), (d_v, do.dtype.itemsize),
+        (d_v, o.dtype.itemsize), (lanes, 4),
+    )
+    with _call_span(
+        "flash_dkv", q, v, (kv_rows, n_k), whole=group_rows, **plan
+    ):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _dkv_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k, padded=padded, group=r,
+                window=window, blockdiff=blockdiff,
+            ),
+            grid=(kv_rows, n_k),
+            in_specs=dkv_in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+            ],
+            compiler_params=_staging_params(*group_rows),
+            interpret=_interpret(),
+            name="flash_dkv",
+        )(*dkv_operands)
     return dq, dk, dv
 
 
@@ -1366,10 +1408,12 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
     if lens is not None:
         in_specs.append(_lens_spec())
         operands.append(lens)
-    # trace time only: what the grid holds beside what the mask keeps
-    with _tracing.trace_time_span(
-        "hvd.kernels.flash_dkv_grid", q, grid_steps=grid[0] * grid[1],
-        kept_tiles=grid[0] * kept, kv_rows=grid[0], seq=seq, group=r,
+    # what the grid holds beside what the mask keeps
+    with _call_span(
+        "flash_dkv", q, v, grid, causal=causal,
+        window=window, padded=lens is not None, blockdiff=blockdiff,
+        block_q=block_q, block_k=block_k, group=r,
+        kept_tiles=grid[0] * kept, kv_rows=grid[0],
     ):
         return pl.pallas_call(
             functools.partial(

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..common import tracing as _tracing
 from ..common.topology import WORLD_AXIS
 from ..common.process_sets import ProcessSet
 from .reduction_ops import Average, Sum, Adasum, Min, Max, Product, resolve_op
@@ -48,6 +49,46 @@ from .reduction_ops import Average, Sum, Adasum, Min, Max, Product, resolve_op
 EXCHANGE_SCOPE = "hvd_exchange"
 UPDATE_SCOPE = "hvd_update"
 ACCUMULATE_SCOPE = "hvd_accumulate"
+
+
+def exchange_plan(grads, *, world, op, wire_dtype, wire=None, buckets=0,
+                  collectives=None):
+    """The span ``hvd.exchange.plan`` around an exchange of ``grads``
+    while JAX traces it (whoever opens ``EXCHANGE_SCOPE`` opens it),
+    else a null context: what the step will hand to its collectives, as
+    tags. ``world`` is the size of the axis (or group) reduced over (or
+    a function that gives it, called only while tracing: outside a trace
+    the axis may be unbound),
+    ``wire_dtype(dtype)`` the dtype a leaf of ``dtype`` has on the wire
+    and ``wire`` the wire's name where the dtypes do not say it (a
+    quantized wire). ``bytes`` is a chip's payload a step: the sum over
+    the leaves as they go on the wire (a quantized wire's scales, four
+    bytes a block or a leaf, are not counted). ``collectives`` is the
+    number of calls the path issues: one a leaf unless given."""
+    leaves = jax.tree_util.tree_leaves(grads)
+    span = _tracing.trace_time_span(
+        "hvd.exchange.plan", leaves[0] if leaves else None
+    )
+    if isinstance(span, _tracing.Span):
+        on_wire = {}
+        for leaf in leaves:
+            if leaf.dtype not in on_wire:
+                on_wire[leaf.dtype] = jnp.dtype(wire_dtype(leaf.dtype))
+        span.tag(
+            world=int(world() if callable(world) else world),
+            op=getattr(op, "name", str(op)).lower(),
+            leaves=len(leaves),
+            bytes=sum(
+                int(np.prod(leaf.shape, dtype=np.int64))
+                * on_wire[leaf.dtype].itemsize
+                for leaf in leaves
+            ),
+            wire=wire or "+".join(sorted({d.name for d in on_wire.values()})),
+            buckets=int(buckets),
+            collectives=len(leaves) if collectives is None else collectives,
+        )
+    return span
+
 
 # ``jax.lax``'s collectives, each under the scope ``collective``: the one
 # way this package puts a collective into a traced program.

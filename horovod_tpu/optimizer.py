@@ -198,6 +198,35 @@ def _allreduce_grads(
     return jax.tree_util.tree_map(one, grads)
 
 
+def _exchange_plan(grads, compression, *, world, op, buckets=0,
+                   min_bucket_bytes=0):
+    """:func:`traced.exchange_plan` for ``compression``'s wire on the
+    traced path: the dtype ``compress`` gives each leaf, or int8 for a
+    quantized wire; one collective a bucket of ``overlap``'s schedule,
+    else one a leaf."""
+    quantized = getattr(compression, "quantized_wire", False)
+
+    def wire_dtype(dtype):
+        if quantized:
+            return jnp.int8
+        return jax.eval_shape(
+            lambda x: compression.compress(x)[0],
+            jax.ShapeDtypeStruct((), dtype),
+        ).dtype
+
+    collectives = None
+    if buckets:
+        leaves, treedef = jax.tree_util.tree_flatten(grads)
+        collectives = len(overlap.schedule_for(
+            leaves, treedef, buckets, min_bucket_bytes
+        ).buckets)
+    return traced.exchange_plan(
+        grads, world=world, op=op, wire_dtype=wire_dtype,
+        wire=compression.wire_format if quantized else None,
+        buckets=buckets, collectives=collectives,
+    )
+
+
 class _AccumulationState(NamedTuple):
     inner: Any
     accum: Any  # running local gradient sum
@@ -419,6 +448,14 @@ def DistributedOptimizer(
             )
         )
         eff_op, pre, post = reduce_op_factors(n)
+        # trace time only: what the step hands to its collectives
+        with _exchange_plan(
+            grads, compression, world=n, op=eff_op, buckets=overlap_buckets,
+            min_bucket_bytes=overlap_min_bytes,
+        ):
+            return exchange(grads, seed, residuals, groups, eff_op, pre, post)
+
+    def exchange(grads, seed, residuals, groups, eff_op, pre, post):
         if overlap_buckets:
             out = overlap.bucketed_allreduce(
                 grads, op=eff_op, n_buckets=overlap_buckets,
@@ -857,7 +894,13 @@ def value_and_grad(
             )
             return vg2(*args, **kwargs)
         val, grads = vg(*args, **kwargs)
-        with jax.named_scope(traced.EXCHANGE_SCOPE):
+        with jax.named_scope(traced.EXCHANGE_SCOPE), _exchange_plan(
+            grads, compression, op=op, world=lambda: (
+                process_set.size
+                if process_set is not None and process_set.process_set_id != 0
+                else jax.lax.axis_size(axis_name)
+            ),
+        ):
             grads = _allreduce_grads(
                 grads, op, compression, 1.0, 1.0, process_set, axis_name,
                 seed=seed,

@@ -688,6 +688,13 @@ class ShardedDistributedOptimizer:
         return inner, guard_rows, wire_rows, local_rows
 
     # -- gradient classification -------------------------------------------
+    def _on_wire(self, dtype):
+        """The dtype a gradient leaf of ``dtype`` has on this optimizer's
+        wire (``auto`` is settled bucket by bucket: counted full width)."""
+        narrow = {"bf16": jnp.bfloat16, "int8": jnp.int8}.get(self._wire)
+        floating = jnp.issubdtype(dtype, jnp.floating)
+        return narrow if narrow is not None and floating else dtype
+
     def _grads_are_shards(self, grads, params, n) -> bool:
         """Static (trace-time) classification: did ``grads`` come from
         the in-backprop scatter boundary (per-leaf shard slices) or
@@ -796,51 +803,61 @@ class ShardedDistributedOptimizer:
         from .ops import overlap as _overlap
 
         new_rs_res = None
-        if shard_in:
-            g_sh = grads
-        elif self._overlap_buckets or self._wire != "fp32":
-            buckets = max(self._overlap_buckets, 1)
-            if self._ef:
-                g_sh, new_rs_res = _overlap.bucketed_reduce_scatter(
-                    grads, op=self._op, n_buckets=buckets,
-                    axis_name=self._axis, wire=self._wire,
-                    wire_block=self._wire_block, seed=wire_seed,
-                    residuals=local_wire["rs"],
-                    min_bucket_bytes=self._overlap_min_bytes,
-                    hier_stages=self._hier_arg,
-                    groups=intra_groups,
-                )
-            else:
-                g_sh = _overlap.bucketed_reduce_scatter(
-                    grads, op=self._op, n_buckets=buckets,
-                    axis_name=self._axis, wire=self._wire,
-                    wire_block=self._wire_block, seed=wire_seed,
-                    min_bucket_bytes=self._overlap_min_bytes,
-                    hier_stages=self._hier_arg,
-                    groups=intra_groups,
-                )
-        else:
-            # 0-d leaves (scalar temperature etc.) stay replicated —
-            # exactly like init's _shard_host — so state shapes are
-            # stable step-over-step (a shape flip would force a retrace
-            # and break donation)
-            @jax.named_scope(_traced.EXCHANGE_SCOPE)
-            def rs(g):
-                if g.ndim == 0:
-                    red = _traced.clax.psum(
-                        g, self._axis, axis_index_groups=intra_groups
+        # trace time only: what this reduce-scatter hands to its
+        # collectives (grads that arrive as shards were exchanged by
+        # ``opt.value_and_grad``: no plan here)
+        bucketed = bool(self._overlap_buckets or self._wire != "fp32")
+        buckets = max(self._overlap_buckets, 1)
+        with _traced.exchange_plan(
+            () if shard_in else grads, world=width, op=self._op,
+            wire_dtype=self._on_wire, wire=self._wire,
+            buckets=self._overlap_buckets,
+            collectives=buckets if bucketed else None,
+        ):
+            if shard_in:
+                g_sh = grads
+            elif bucketed:
+                if self._ef:
+                    g_sh, new_rs_res = _overlap.bucketed_reduce_scatter(
+                        grads, op=self._op, n_buckets=buckets,
+                        axis_name=self._axis, wire=self._wire,
+                        wire_block=self._wire_block, seed=wire_seed,
+                        residuals=local_wire["rs"],
+                        min_bucket_bytes=self._overlap_min_bytes,
+                        hier_stages=self._hier_arg,
+                        groups=intra_groups,
                     )
-                    return red / width if self._op == Average else red
-                flat = _pad_to(g.reshape(-1), width).reshape(width, -1)
-                red = _traced.clax.psum_scatter(
-                    flat, self._axis, scatter_dimension=0, tiled=False,
-                    axis_index_groups=intra_groups,
-                )
-                if self._op == Average:
-                    red = red / width
-                return red
+                else:
+                    g_sh = _overlap.bucketed_reduce_scatter(
+                        grads, op=self._op, n_buckets=buckets,
+                        axis_name=self._axis, wire=self._wire,
+                        wire_block=self._wire_block, seed=wire_seed,
+                        min_bucket_bytes=self._overlap_min_bytes,
+                        hier_stages=self._hier_arg,
+                        groups=intra_groups,
+                    )
+            else:
+                # 0-d leaves (scalar temperature etc.) stay replicated —
+                # exactly like init's _shard_host — so state shapes are
+                # stable step-over-step (a shape flip would force a retrace
+                # and break donation)
+                @jax.named_scope(_traced.EXCHANGE_SCOPE)
+                def rs(g):
+                    if g.ndim == 0:
+                        red = _traced.clax.psum(
+                            g, self._axis, axis_index_groups=intra_groups
+                        )
+                        return red / width if self._op == Average else red
+                    flat = _pad_to(g.reshape(-1), width).reshape(width, -1)
+                    red = _traced.clax.psum_scatter(
+                        flat, self._axis, scatter_dimension=0, tiled=False,
+                        axis_index_groups=intra_groups,
+                    )
+                    if self._op == Average:
+                        red = red / width
+                    return red
 
-            g_sh = jax.tree_util.tree_map(rs, grads)
+                g_sh = jax.tree_util.tree_map(rs, grads)
 
         finite = None
         if self._guard_on:

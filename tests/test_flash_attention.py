@@ -629,12 +629,17 @@ def test_blocked_dkv_equals_the_whole_sequence_kernel(
                                    err_msg=name)
 
 
-def test_a_traced_blocked_call_records_what_its_grid_holds(monkeypatch):
-    """``hvd.kernels.flash_dkv_grid``: a span a blocked dK/dV call while
-    JAX traces it, tagged with the grid's steps and the tiles the mask
-    keeps; an eager call records none."""
+def _flash_calls():
     from horovod_tpu.common import tracing
 
+    return [r["tags"] for r in tracing.recorder().spans()
+            if r["name"] == "hvd.kernels.flash_call"]
+
+
+def test_a_traced_blocked_call_records_what_its_grid_holds(monkeypatch):
+    """``hvd.kernels.flash_call`` of a blocked dK/dV call: a span while
+    JAX traces it, tagged with the grid's steps and the tiles the mask
+    keeps."""
     monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET", "1")
     q = _rand((2, 128, 4, 8), 70)
     kv = _rand((2, 128, 2, 8), 71)
@@ -644,15 +649,96 @@ def test_a_traced_blocked_call_records_what_its_grid_holds(monkeypatch):
             q, k, v, block_q=16, block_k=16, block_diffusion=4).sum(),
             (1, 2))(q, k, v)
 
-    def spans():
-        return [r for r in tracing.recorder().spans()
-                if r["name"] == "hvd.kernels.flash_dkv_grid"]
-
-    before = len(spans())
+    before = len(_flash_calls())
     jax.block_until_ready(jax.jit(grad)(q, kv, kv))
-    (span,) = spans()[before:]
+    (span,) = [t for t in _flash_calls()[before:]
+               if t["kernel"] == "flash_dkv"]
     # 4 noised K tiles of 1 Q tile, clean bands of 8, 6, 4, 2: 24 tiles
     # for each of 2 group members and 4 kv rows (the rectangle held 8
     # steps a K tile: 4 x 8 x 2 x 8 = 512)
-    assert span["tags"] == {"grid_steps": 192, "kept_tiles": 192,
-                            "kv_rows": 4, "seq": 128, "group": 2}
+    assert span == {
+        "kernel": "flash_dkv", "staging": "blocked",
+        "mask": "block_diffusion", "block_q": 16, "block_k": 16,
+        "seq": 128, "heads": 8, "group": 2, "d_qk": 8, "d_v": 8,
+        "grid_steps": 192, "kept_tiles": 192, "kv_rows": 4,
+        "staged_vmem_bytes": 0, "vmem_limit_bytes": 0}
+
+
+# seq 128 in tiles of 16: 8 x 8 tiles. What the blocked dK/dV grid keeps of
+# them a kv row and group member: the lower triangle; the band of a
+# 40-wide window (a K tile is seen by its own Q tile and the three after
+# it: 5 x 4, then 3, 2, 1); all of them (a length bound is a ``pl.when``
+# inside a kept step); the block-diffusion mask's 24 (the test above).
+_KEPT = {"causal": 36, "window": 26, "none": 64, "lengths": 64,
+         "block_diffusion": 24}
+_MASKS = {
+    "causal": dict(causal=True),
+    "window": dict(causal=True, window=40),
+    "none": dict(),
+    "lengths": dict(lengths=True),
+    "block_diffusion": dict(block_diffusion=4),
+}
+
+
+@pytest.mark.parametrize("staging", ["whole", "blocked"])
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+def test_every_kernel_call_leaves_its_plan(mask, staging, monkeypatch):
+    """``hvd.kernels.flash_call``: one span a ``pallas_call`` of the
+    gradient (forward, dQ, dK/dV), in either staging of dK/dV, under each
+    mask, with the plan's numbers as reckoned by hand: 2 rows x 4 query
+    heads on 2 kv heads, seq 128, blocks of 16, a 16-wide float32 key and
+    an 8-wide value."""
+    monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET",
+                       "1" if staging == "blocked" else str(2**30))
+    q = _rand((2, 128, 4, 16), 80)
+    k = _rand((2, 128, 2, 16), 81)
+    v = _rand((2, 128, 2, 8), 82)
+    kwargs = dict(_MASKS[mask], block_q=16, block_k=16)
+    if kwargs.pop("lengths", False):
+        kwargs["lengths"] = jnp.asarray([128, 100], jnp.int32)
+
+    def grad(q, k, v):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, **kwargs).sum(), (0, 1, 2))(q, k, v)
+
+    before = len(_flash_calls())
+    jax.block_until_ready(jax.jit(grad)(q, k, v))
+    fwd, dq, dkv = _flash_calls()[before:]
+    shared = {"mask": mask, "block_q": 16, "block_k": 16, "seq": 128,
+              "heads": 8, "group": 2, "d_qk": 16, "d_v": 8,
+              "vmem_limit_bytes": 0}
+    # K and V whole-sequence, twice buffered, each row rounded up to 128
+    # float32 lanes: 2 x 128 x (512 + 512) bytes
+    kv_staged = 2 * 128 * 1024
+    for span, kernel in ((fwd, "flash_fwd"), (dq, "flash_dq")):
+        assert span == dict(
+            shared, kernel=kernel, staging="whole", grid_steps=8 * 8,
+            staged_vmem_bytes=kv_staged)
+    if staging == "whole":
+        # the group's q, do, o and lse: 2 x 128 rows of four 512-byte
+        # rows, twice; a K tile a step of each of 4 kv rows
+        assert dkv == dict(
+            shared, kernel="flash_dkv", staging="whole", grid_steps=4 * 8,
+            staged_vmem_bytes=2 * 256 * 2048)
+    else:
+        steps = 4 * 2 * _KEPT[mask]
+        assert dkv == dict(
+            shared, kernel="flash_dkv", staging="blocked",
+            grid_steps=steps, kept_tiles=steps, kv_rows=4,
+            staged_vmem_bytes=0)
+
+
+def test_a_call_past_mosaics_default_limit_says_what_it_asks_for():
+    """Past the 16 MiB a kernel may hold by default the span carries the
+    limit the call asks for (``_staging_params``); traced only, nothing
+    runs: 8192 positions of a 192-wide bf16 key and a 128-wide value."""
+    q = jax.ShapeDtypeStruct((1, 8192, 2, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16)
+    before = len(_flash_calls())
+    jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   q, q, v)
+    (fwd,) = _flash_calls()[before:]
+    staged = 2 * 8192 * (512 + 256)  # 12 MiB
+    assert (fwd["kernel"], fwd["staged_vmem_bytes"]) == ("flash_fwd", staged)
+    assert fwd["vmem_limit_bytes"] == 2 * staged + 6 * 2**20
+    assert (fwd["d_qk"], fwd["d_v"], fwd["group"]) == (192, 128, 1)

@@ -208,3 +208,93 @@ def test_broadcast_optimizer_state_roundtrip(hvd):
 def test_broadcast_object_single_controller(hvd):
     obj = {"step": 7, "note": "hello"}
     assert hvd_mod.broadcast_object(obj) is obj
+
+
+# ------------------------------------------------------ the exchange's plan
+
+
+def _plans():
+    from horovod_tpu.common import tracing
+
+    return [r["tags"] for r in tracing.recorder().spans()
+            if r["name"] == "hvd.exchange.plan"]
+
+
+def _plan_params():
+    # 8 x 16 + 16 + 16 x 4 + 2 x 128 = 464 float32 elements in 4 leaves
+    return {"w": jnp.ones((8, 16)), "b": jnp.zeros((16,)),
+            "v": jnp.ones((16, 4)), "u": jnp.ones((2, 128))}
+
+
+def _plan_loss(p, x):
+    return jnp.mean((jnp.tanh(x @ p["w"] + p["b"]) @ p["v"]) ** 2) + (
+        jnp.sum(p["u"] ** 2))
+
+
+@pytest.mark.parametrize("kwargs, want", [
+    ({}, dict(bytes=464 * 4, wire="float32", buckets=0, collectives=4)),
+    ({"overlap_buckets": 4, "overlap_min_bytes": 0},
+     dict(bytes=464 * 4, wire="float32", buckets=4, collectives=4)),
+    ({"compression": hvd_mod.Compression.bf16},
+     dict(bytes=464 * 2, wire="bfloat16", buckets=0, collectives=4)),
+    ({"compression": hvd_mod.Compression.int8},
+     dict(bytes=464, wire="int8", buckets=0, collectives=4)),
+], ids=["default", "overlap4", "bf16", "int8"])
+def test_the_exchange_leaves_its_plan_and_counts_the_lowered_bytes(
+        hvd, kwargs, want):
+    """``hvd.exchange.plan``: one span a traced exchange, and its
+    ``bytes`` are what the benchmark counts from the lowered step's
+    world-spanning all-reduces (a quantized wire goes by all-to-all and
+    all-gather, which that count does not see)."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark.lib import xtrace
+
+    opt = hvd_mod.DistributedOptimizer(optax.sgd(0.1), **kwargs)
+    params = _plan_params()
+    state = opt.init(params)
+
+    def step(params, state, x):
+        grads = jax.grad(_plan_loss)(params, x[0])
+        updates, state = opt.update(grads, state, params)
+        return optax.apply_updates(params, updates), state
+
+    x = jnp.ones((hvd.size(), 4, 8))
+    before = len(_plans())
+    lowered = spmd(hvd, step, (P(), P(), P(hvd_mod.WORLD_AXIS)),
+                   (P(), P())).lower(params, state, x)
+    (plan,) = _plans()[before:]
+    assert plan == dict(want, world=8, op="average", leaves=4)
+    if plan["wire"] != "int8":
+        assert plan["bytes"] == xtrace.world_allreduce_bytes(
+            lowered.as_text(), 8)
+    # an eager call plans nothing: the span is trace time's
+    assert len(_plans()) == before + 1
+
+
+def test_the_tape_api_and_the_zero_optimizer_leave_plans_too(hvd):
+    params = _plan_params()
+    x = jnp.ones((hvd.size(), 4, 8))
+    before = len(_plans())
+
+    def tape(params, x):
+        return hvd_mod.value_and_grad(_plan_loss)(params, x[0])[1]
+
+    spmd(hvd, tape, (P(), P(hvd_mod.WORLD_AXIS)), P()).lower(params, x)
+    zero = hvd_mod.ShardedDistributedOptimizer(optax.sgd(0.1), wire="bf16")
+    state = zero.init(params)
+
+    def step(params, state, x):
+        grads = jax.grad(_plan_loss)(params, x[0])
+        return zero.update(grads, state, params)
+
+    spmd(hvd, step, (P(), zero.state_spec(), P(hvd_mod.WORLD_AXIS)),
+         (P(), zero.state_spec())).lower(params, state, x)
+    tape_plan, zero_plan = _plans()[before:]
+    assert tape_plan == dict(world=8, op="average", leaves=4, bytes=464 * 4,
+                             wire="float32", buckets=0, collectives=4)
+    assert zero_plan == dict(world=8, op="average", leaves=4, bytes=464 * 2,
+                             wire="bf16", buckets=0, collectives=1)

@@ -515,7 +515,9 @@ def test_the_trace_model_span_says_what_remat_does(
     cfg = _tiny("causal-mha", remat, flash=flash)
     model = T.Transformer(cfg)
     params = model.init(jax.random.PRNGKey(0), batch[0], train=False)
-    assert not ring.spans()  # an eager call opens no span
+    # an eager call opens no such span (its kernels, which JAX traces
+    # even then, leave theirs: hvd.kernels.flash_call)
+    assert "hvd.trainer.trace_model" not in {r["name"] for r in ring.spans()}
     jax.make_jaxpr(lambda p, t: model.apply(p, t, train=True))(
         params, batch[0])
     (span,) = [r for r in ring.spans()

@@ -94,8 +94,10 @@ def test_the_decode_step_span_follows_the_switches(
         assert step["tags"]["active"] >= 1
         if b.engine.paged:
             assert 0 < step["tags"]["live_pages"] <= step["tags"]["pages"]
-    # nothing else of the program's fires per round (the model's span
-    # fires while its programs are traced): every hvd.* span has a reader
+    # nothing else of the program's fires per round (the model's span and
+    # the compile ledger's fire while its programs are traced, lowered and
+    # compiled): every hvd.* span has a reader
     assert {n for n in _names(rec) if n.startswith("hvd.")} <= {
-        "hvd.engine.decode_step", "hvd.trainer.trace_model"}
+        "hvd.engine.decode_step", "hvd.trainer.trace_model",
+        "hvd.init.jit_trace", "hvd.init.jit_lower", "hvd.init.jit_compile"}
     assert b.engine.stats()["decode_compiles"] == 1

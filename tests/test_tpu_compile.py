@@ -48,7 +48,9 @@ def _compiled(fn, *args):
 def _dkv_grids():
     """What the blocked dK/dV calls traced so far said of their grids."""
     return [r["tags"] for r in tracing.recorder().spans()
-            if r["name"] == "hvd.kernels.flash_dkv_grid"]
+            if r["name"] == "hvd.kernels.flash_call"
+            and r["tags"]["kernel"] == "flash_dkv"
+            and r["tags"]["staging"] == "blocked"]
 
 
 @pytest.mark.parametrize("window, tiles", [(2048, 70), (None, 136)],

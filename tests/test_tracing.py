@@ -11,6 +11,7 @@ composition, and skew-corrected assembly ordering.
 import json
 import os
 import threading
+import time
 
 import pytest
 
@@ -361,3 +362,199 @@ class TestExemplars:
         assert s["p95_exemplar"] == ""
         text = "\n".join(rec.render_prometheus_summaries())
         assert "tpot_p95_exemplar" not in text
+
+
+# -------------------------------------------------------- compile ledger
+
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture
+def ledger(monkeypatch):
+    """The compile ledger on a fresh ring, every event a span (the 20 ms
+    floor off; a test that wants it sets it back)."""
+    from horovod_tpu.common import compile_cache
+
+    tracing._reset()
+    compile_cache._ledger.install()
+    monkeypatch.setattr(compile_cache, "SMALL_S", 0.0)
+    yield compile_cache
+    tracing._reset()
+
+
+def _jit_spans():
+    return [r for r in tracing.recorder().spans()
+            if r["name"].startswith("hvd.init.jit_")]
+
+
+def _jax_event(event, seconds, fun):
+    """One event as ``jax._src.dispatch.log_elapsed_time`` reports it."""
+    from jax import monitoring
+
+    start = time.time()
+    monitoring.record_scalar(event, start, fun_name=fun)
+    monitoring.record_event_duration_secs(event, seconds, fun_name=fun)
+    monitoring.record_event_time_span(
+        event, start, start + seconds, fun_name=fun)
+
+
+class TestCompileLedger:
+    def test_a_jit_leaves_one_span_of_each_kind_with_its_name(self, ledger):
+        import jax
+        import jax.numpy as jnp
+
+        def ledger_outer(x):
+            return jnp.sin(x) * 2
+
+        with tracing.span("hvd.init.place_state") as parent:
+            jax.jit(ledger_outer).lower(jnp.ones(7)).compile()
+        mine = [r for r in _jit_spans() if "ledger_outer" in r["tags"]["fun"]]
+        assert [(r["name"], r["tags"]["fun"]) for r in mine] == [
+            ("hvd.init.jit_trace", "ledger_outer"),
+            ("hvd.init.jit_lower", "jit(ledger_outer)"),
+            ("hvd.init.jit_compile", "jit(ledger_outer)")]
+        # live spans: children of the thread's active span, serials in order
+        assert {r["parent_id"] for r in mine} == {parent.ctx.span_id}
+        assert [r["seq"] for r in mine] == sorted(r["seq"] for r in mine)
+        # the suite runs with the persistent cache off
+        assert mine[2]["tags"]["cache"] == "off"
+        assert all(r["dur_ms"] > 0 for r in mine)
+
+    def test_a_jit_inside_a_jit_is_counted_in_the_outer_span(
+            self, ledger, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        @jax.jit
+        def ledger_inner(x):
+            return jnp.cos(x) + 1
+
+        def ledger_outer2(x):
+            return ledger_inner(x) * 2
+
+        jax.jit(ledger_outer2).lower(jnp.ones(5))
+        names = [r["tags"]["fun"] for r in _jit_spans()]
+        assert "ledger_inner" not in names
+        (outer,) = [r for r in _jit_spans()
+                    if r["tags"]["fun"] == "ledger_outer2"]
+        assert outer["tags"]["inner"] >= 1
+        assert 0 < outer["tags"]["inner_s"] <= outer["dur_ms"] / 1e3
+        # an inner event of 100 ms or more is a child span, written when
+        # it ends (the floor lowered to stand for a long one)
+        monkeypatch.setattr(ledger, "INNER_SPAN_S", 0.0)
+
+        def ledger_outer3(x):
+            return ledger_inner(x + 1) * 3  # traced anew: another shape
+
+        jax.jit(ledger_outer3).lower(jnp.ones(6))
+        spans = {r["tags"]["fun"]: r for r in _jit_spans()}
+        inner, outer = spans["ledger_inner"], spans["ledger_outer3"]
+        assert inner["parent_id"] == outer["span_id"]
+        assert inner["tags"]["depth"] == 1 and "depth" not in outer["tags"]
+        assert outer["ts"] <= inner["ts"]
+        assert inner["ts"] + inner["dur_ms"] / 1e3 <= (
+            outer["ts"] + outer["dur_ms"] / 1e3 + 1e-3)
+
+    def test_the_persistent_caches_answer_is_on_the_compile_span(
+            self, ledger, tmp_path):
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from horovod_tpu.common.metrics import registry
+
+        settings = {
+            "jax_enable_compilation_cache": True,
+            "jax_compilation_cache_dir": str(tmp_path),
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": -1,
+        }
+        before = {k: getattr(jax.config, k) for k in settings}
+        counted = registry.snapshot()
+
+        def ledger_cached(x):
+            return jnp.tanh(x) @ x.T
+
+        def compile_span():
+            jax.jit(ledger_cached).lower(jnp.ones((9, 9))).compile()
+            return [r["tags"] for r in _jit_spans()
+                    if r["name"] == "hvd.init.jit_compile"
+                    and r["tags"]["fun"] == "jit(ledger_cached)"][-1]
+
+        try:
+            for k, v in settings.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+            first = compile_span()
+            jax.clear_caches()  # what a second process would start with
+            second = compile_span()
+        finally:
+            for k, v in before.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+        assert first["cache"] == "miss" and "retrieval_s" not in first
+        assert second["cache"] == "hit"
+        assert second["retrieval_s"] >= 0 and "saved_s" in second
+        now = registry.snapshot()
+        delta = {k: now.get(k, 0.0) - counted.get(k, 0.0)
+                 for k in ("jit.compiles", "jit.cache_hits",
+                           "jit.cache_misses", "jit.compile_s")}
+        # (the eager programs that made the inputs count too)
+        assert delta["jit.cache_hits"] >= 1 and delta["jit.cache_misses"] >= 1
+        assert delta["jit.compiles"] >= 2 and delta["jit.compile_s"] > 0
+
+    def test_a_thousand_small_programs_are_a_tally_not_a_thousand_spans(
+            self, ledger, monkeypatch):
+        monkeypatch.setattr(ledger, "SMALL_S", 0.020)
+        _jax_event(COMPILE_EVENT, 0.05, "jit(flush)")  # empties the tally
+        before = len(tracing.recorder())
+        for i in range(1000):
+            _jax_event(TRACE_EVENT, 0.001, "add")
+            _jax_event(COMPILE_EVENT, 0.002, "jit(add)")
+        assert len(tracing.recorder()) == before
+        assert tracing.current() is None  # every live span was left
+        _jax_event(COMPILE_EVENT, 0.05, "jit(step)")
+        _jax_event(TRACE_EVENT, 0.03, "step")
+        compiled, traced = tracing.recorder().spans()[before:]
+        assert compiled["tags"] == {
+            "fun": "jit(step)", "cache": "off", "small": 1000,
+            "small_s": pytest.approx(2.0, abs=1e-3)}
+        assert traced["tags"] == {
+            "fun": "step", "small": 1000, "small_s": pytest.approx(1.0, abs=1e-3)}
+        # the next span of a kind starts a new tally
+        _jax_event(TRACE_EVENT, 0.03, "step2")
+        assert tracing.recorder().spans()[-1]["tags"] == {"fun": "step2"}
+
+    def test_a_ledger_span_is_in_a_running_profiler_session(
+            self, ledger, tmp_path):
+        import glob
+        import sys
+
+        import jax
+        import jax.numpy as jnp
+
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        from benchmark.lib import xtrace
+
+        def ledger_profiled(x):
+            time.sleep(0.02)
+            return x + 1
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            jax.jit(ledger_profiled).lower(jnp.ones(3))
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(
+            str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+        host = xtrace.load(path, span_prefixes=("hvd.",)).host_spans
+        (record,) = [r for r in _jit_spans()
+                     if r["tags"]["fun"] == "ledger_profiled"]
+        (event,) = [(start, end) for name, start, end, idx in host
+                    if name == "hvd.init.jit_trace" and idx == record["seq"]]
+        assert event[1] - event[0] >= 15e6  # the sleep, in nanoseconds
