@@ -47,6 +47,7 @@ is on (default: auto — enabled when no padding mask is passed).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -406,14 +407,76 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
+def _dkv_members(q_ref, do_ref, o_ref, lse_ref, at, ki, k, v, dk, dv, *,
+                 scale, causal, block_q, block_k, i, kv_len, window,
+                 blockdiff, half):
+    """dk/dv plus the part of Q tile ``i`` in K tile ``ki`` (``k``, ``v``:
+    float32) of each query head of the refs' leading dimension in turn (a
+    static Python loop: a group is small). The tile's rows are those of
+    block ``at`` of the refs' second dimension: ``i`` where they hold the
+    whole sequence, 0 where they hold the tile. Both dK/dV kernels' sum."""
+    for gm in range(q_ref.shape[0]):
+        q = q_ref[gm, pl.dslice(at * block_q, block_q), :].astype(
+            jnp.float32
+        )
+        do = do_ref[gm, pl.dslice(at * block_q, block_q), :].astype(
+            jnp.float32
+        )
+        lse = lse_ref[gm, pl.dslice(at * block_q, block_q), :][:, 0:1]
+        delta = jnp.sum(
+            do
+            * o_ref[gm, pl.dslice(at * block_q, block_q), :].astype(
+                jnp.float32
+            ),
+            axis=-1,
+            keepdims=True,
+        )
+        s = scale * jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [BQ, BK]
+        if causal:
+            s = _apply_causal_mask(s, i, ki, block_q, block_k)
+        if kv_len is not None:
+            # Mask key columns past the length so their dk/dv stay 0.
+            s = _apply_length_mask(s, ki, block_k, kv_len)
+        if window is not None:
+            s = _apply_window_mask(s, i, ki, block_q, block_k, window)
+        if blockdiff is not None:
+            s = _apply_blockdiff_mask(
+                s, i, ki, block_q, block_k, half, blockdiff
+            )
+        p = jnp.exp(s - lse)
+        if kv_len is not None:
+            # Same defense-in-depth row zeroing as _dq_kernel (see the
+            # comment there — padded-row lse is finite; this guards
+            # wrapper-bypassing callers, it does not prevent NaNs).
+            rows = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, p.shape, 0
+            )
+            p = jnp.where(rows < kv_len, p, 0.0)
+        dv = dv + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta)
+        dk = dk + scale * jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    return dk, dv
+
+
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                 *rest, scale, causal, block_q, block_k, padded=False,
-                group=1, window=None, blockdiff=None):
-    """dK/dV over one K block. With grouped-query attention
-    (``group`` = q heads per kv head > 1) the q/do/o/lse blocks carry
-    the kv head's whole GROUP of q heads in their leading dim, and
-    dk/dv accumulate over the group (a static Python loop — group is
-    small)."""
+                window=None, blockdiff=None):
+    """dK/dV over one K block. With grouped-query attention the q/do/o/lse
+    blocks carry the kv head's whole GROUP of q heads in their leading
+    dim, and dk/dv accumulate over the group (:func:`_dkv_members`)."""
     if padded:
         len_ref, dk_ref, dv_ref = rest
         kv_len = len_ref[pl.program_id(0)]
@@ -447,66 +510,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         )
     d, d_v = k_ref.shape[-1], v_ref.shape[-1]
 
-    def member_body(gm, i, dk, dv):
-        q = q_ref[gm, pl.dslice(i * block_q, block_q), :].astype(
-            jnp.float32
-        )
-        do = do_ref[gm, pl.dslice(i * block_q, block_q), :].astype(
-            jnp.float32
-        )
-        lse = lse_ref[gm, pl.dslice(i * block_q, block_q), :][:, 0:1]
-        delta = jnp.sum(
-            do
-            * o_ref[gm, pl.dslice(i * block_q, block_q), :].astype(
-                jnp.float32
-            ),
-            axis=-1,
-            keepdims=True,
-        )
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [BQ, BK]
-        if causal:
-            s = _apply_causal_mask(s, i, ki, block_q, block_k)
-        if padded:
-            # Mask key columns past the length so their dk/dv stay 0.
-            s = _apply_length_mask(s, ki, block_k, kv_len)
-        if window is not None:
-            s = _apply_window_mask(s, i, ki, block_q, block_k, window)
-        if blockdiff is not None:
-            s = _apply_blockdiff_mask(
-                s, i, ki, block_q, block_k, seq_q // 2, blockdiff
-            )
-        p = jnp.exp(s - lse)
-        if padded:
-            # Same defense-in-depth row zeroing as _dq_kernel (see the
-            # comment there — padded-row lse is finite; this guards
-            # wrapper-bypassing callers, it does not prevent NaNs).
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, p.shape, 0
-            )
-            p = jnp.where(rows < kv_len, p, 0.0)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        dk = dk + scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk, dv
-
     def body(i, carry):
-        dk, dv = carry
-        for gm in range(group):  # static unroll; group == 1 for MHA
-            dk, dv = member_body(gm, i, dk, dv)
-        return dk, dv
+        return _dkv_members(
+            q_ref, do_ref, o_ref, lse_ref, i, ki, k, v, *carry,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            i=i, kv_len=kv_len, window=window, blockdiff=blockdiff,
+            half=seq_q // 2,
+        )
 
     dk0 = jnp.zeros((block_k, d), jnp.float32)
     dv0 = jnp.zeros((block_k, d_v), jnp.float32)
@@ -673,13 +683,14 @@ def _staging_params(rows: int, *operands):
 
 
 def _call_span(kernel, q, v, grid, *, causal, window, padded, blockdiff,
-               block_q, block_k, group, whole=None, **more):
+               block_q, block_k, group, whole=None, params=None, **more):
     """The span ``hvd.kernels.flash_call`` around one ``pl.pallas_call``
     of this file while JAX traces it (the kernel body's jaxpr is made
     inside the call), else a null context: the call's plan as tags.
     ``whole`` is what the call stages whole-sequence, ``(rows,
     *operands)`` as :func:`staged_vmem_bytes` and
-    :func:`_staging_params` take them; None for a blocked call."""
+    :func:`_staging_params` take them; None for a blocked call, which
+    gives the ``params`` it asks for instead."""
     if blockdiff is not None:
         mask = "block_diffusion"
     else:
@@ -687,13 +698,14 @@ def _call_span(kernel, q, v, grid, *, causal, window, padded, blockdiff,
             "causal" if causal else "none")
         if padded:
             mask = "lengths" if mask == "none" else mask + "+lengths"
-    params = _staging_params(*whole) if whole else None
+    if whole:
+        params = _staging_params(*whole)
     return _tracing.trace_time_span(
         "hvd.kernels.flash_call", q, kernel=kernel,
         staging="whole" if whole else "blocked", mask=mask,
         block_q=block_q, block_k=block_k, seq=q.shape[1], heads=q.shape[0],
         group=group, d_qk=q.shape[2], d_v=v.shape[2],
-        grid_steps=grid[0] * grid[1],
+        grid_steps=math.prod(grid),
         staged_vmem_bytes=staged_vmem_bytes(*whole) if whole else 0,
         vmem_limit_bytes=getattr(params, "vmem_limit_bytes", None) or 0,
         **more,
@@ -930,7 +942,7 @@ def _flash_bwd_impl(
         dk, dv = pl.pallas_call(
             functools.partial(
                 _dkv_kernel, scale=scale, causal=causal,
-                block_q=block_q, block_k=block_k, padded=padded, group=r,
+                block_q=block_q, block_k=block_k, padded=padded,
                 window=window, blockdiff=blockdiff,
             ),
             grid=(kv_rows, n_k),
@@ -1108,9 +1120,10 @@ def flash_attention(
     ring attention for the memory win. The dK/dV kernel stages its q
     group whole-sequence where that fits the budget (:func:`fits_vmem`)
     and block by block where it does not: its grid is then a table, made
-    at trace time, of the (K tile, group member, Q tile) steps that this
-    mask keeps a pair in (:func:`_dkv_steps`), under any of the masks
-    here. Composes with lengths and GQA.
+    at trace time, of the (K tile, Q tile) steps that this mask keeps a
+    pair in (:func:`_dkv_steps`), under any of the masks here, each step
+    taking as many of the group's query heads as fit
+    (:func:`_members_per_step`). Composes with lengths and GQA.
 
     ``block_diffusion`` (int, the block length B): the sequence is a
     noised copy of a row of ``t / 2`` tokens and then the clean row, and a
@@ -1221,37 +1234,42 @@ def flash_attention(
 
 # dK/dV with the q group staged block by block (ROADMAP A9). The
 # whole-sequence kernel above fetches (group, seq, d) blocks of q, do and
-# o: 48 MiB at group 8, seq 8192, d 128. Here the grid is (kv row, step):
-# a step is one (K tile, group member, Q tile) that the mask keeps a pair
-# in, and fetches one (block_q, d) block of q, do, o and lse and adds its
-# part to dk/dv held in VMEM scratch, so the footprint follows block_q and
-# block_k alone. Every mask but ``lens`` is known at trace time, so the
-# steps are a table (:func:`_dkv_steps`) that the index maps and the
-# kernel read from scalar memory (scalar prefetch): a K tile's steps are
-# contiguous, within it group member outermost and Q tiles ascending, the
-# first zeroes the accumulators and the last stores them. The grid holds
-# no step that computes nothing, whatever the bands' widths (under the
-# block-diffusion mask they run from 1 to 32 Q tiles a K tile).
+# o: 48 MiB at group 8, seq 8192, d 128. Here the grid is (kv row, step,
+# part of the group): a step is one (K tile, Q tile) that the mask keeps a
+# pair in, and fetches a (members, block_q, d) block of q, do, o and lse,
+# ``members`` query heads of the kv head's group (all of them where they
+# fit, :func:`_members_per_step`), and adds their parts to dk/dv held in
+# VMEM scratch, so the footprint follows the blocks and not the sequence.
+# Every mask but ``lens`` is known at trace time, so the steps are a table
+# (:func:`_dkv_steps`) that the index maps and the kernel read from scalar
+# memory (scalar prefetch): a K tile's steps are contiguous, Q tiles
+# ascending, the first zeroes the accumulators and the last stores them.
+# The grid holds no step that computes nothing, whatever the bands' widths
+# (under the block-diffusion mask they run from 1 to 32 Q tiles a K tile).
+# The group's parts are the grid's innermost dimension, so that the sums
+# run Q tile by Q tile and within one query head by query head, as in the
+# whole-sequence kernel (:func:`_dkv_members`, the body of both).
 #
-# A step is one int32 word, K tile | Q tile | group member | edge bits: the
-# v5e's scalar memory is 1 MiB (compiled for a described chip, a table of
-# 262,144 words was refused), and four columns of their own would end at
-# 65,000 steps: 46,000 tokens unmasked at group 8, which the forward and
-# dQ kernels' staging still takes.
+# A step is one int32 word, K tile | Q tile | edge bits: the v5e's scalar
+# memory is 1 MiB (compiled for a described chip, a table of 262,144 words
+# was refused), and columns of their own would take it three times over.
 
 _FIRST, _LAST = 1, 2  # the edge bits: a K tile's first step, its last
-_MEMBER_SHIFT, _Q_SHIFT, _K_SHIFT = 2, 8, 20
-_MAX_GROUP = 1 << (_Q_SHIFT - _MEMBER_SHIFT)
-_MAX_TILES = 1 << (31 - _K_SHIFT)  # the K tile stops short of the sign
+_Q_SHIFT, _K_SHIFT = 2, 16
+_MAX_TILES = 1 << (_K_SHIFT - _Q_SHIFT)  # the K tile's field is one wider
+
+# What one step of the blocked kernel may hold: its q group's blocks twice
+# and the room beside them. A quarter of the v5e's 128 MiB of VMEM, so the
+# limit a call asks for (:func:`_staging_params`) stays under half of it.
+_STEP_VMEM = 32 * 2**20
 
 
 def _step_fields(word):
-    """``(K tile, Q tile, group member, edge bits)`` of a step's word (of
-    a whole table's, given an array)."""
+    """``(K tile, Q tile, edge bits)`` of a step's word (of a whole
+    table's, given an array)."""
     return (
         word >> _K_SHIFT,
-        (word >> _Q_SHIFT) & ((1 << (_K_SHIFT - _Q_SHIFT)) - 1),
-        (word >> _MEMBER_SHIFT) & (_MAX_GROUP - 1),
+        (word >> _Q_SHIFT) & (_MAX_TILES - 1),
         word & (_FIRST | _LAST),
     )
 
@@ -1267,19 +1285,18 @@ def _dkv_q_range(ki, block_q, block_k, n_q, causal, window):
     return first, last
 
 
-def _dkv_steps(seq, block_q, block_k, group, causal, window, blockdiff=None):
-    """``(steps, kept)``: the blocked dK/dV kernel's steps a kv row, an
-    int32 word each (:func:`_step_fields`), and how many of them are
-    tiles the mask keeps a pair in (all of them, unless a K tile's band is
-    empty: it gets a step a group member on a tile the mask empties, so
+def _dkv_steps(seq, block_q, block_k, causal, window, blockdiff=None):
+    """``(steps, kept)``: the blocked dK/dV kernel's steps a kv row and
+    part of its group, an int32 word each (:func:`_step_fields`), and how
+    many of them are tiles the mask keeps a pair in (all of them, unless a
+    K tile's band is empty: it gets a step on a tile the mask empties, so
     that its zeros are written)."""
     n_q, n_k = seq // block_q, seq // block_k
-    if max(n_q, n_k) > _MAX_TILES or group > _MAX_GROUP:
+    if max(n_q, n_k) > _MAX_TILES:
         raise ValueError(
             f"the dK/dV kernel's table of steps holds {_MAX_TILES} tiles a "
-            f"sequence and {_MAX_GROUP} query heads a kv head: got "
-            f"{max(n_q, n_k)} tiles (seq {seq}, blocks {block_q} x "
-            f"{block_k}) and {group} heads"
+            f"sequence: got {max(n_q, n_k)} tiles (seq {seq}, blocks "
+            f"{block_q} x {block_k})"
         )
     tiles, kept = [], 0
     for ki in range(n_k):
@@ -1290,17 +1307,27 @@ def _dkv_steps(seq, block_q, block_k, group, causal, window, blockdiff=None):
         else:
             ranges = (_dkv_q_range(ki, block_q, block_k, n_q, causal, window),)
         band = np.concatenate([np.arange(*r) for r in ranges])
-        kept += group * len(band)
+        kept += len(band)
         if not len(band):
             band = np.zeros(1, int)
-        steps = (
-            ki << _K_SHIFT | np.tile(band, group) << _Q_SHIFT
-            | np.repeat(np.arange(group), len(band)) << _MEMBER_SHIFT
-        )
+        steps = ki << _K_SHIFT | band << _Q_SHIFT
         steps[0] |= _FIRST
         steps[-1] |= _LAST
         tiles.append(steps)
     return np.concatenate(tiles).astype(np.int32), kept
+
+
+def _members_per_step(group, block_q, *operands):
+    """How many of a kv head's ``group`` query heads a step of the blocked
+    dK/dV kernel takes: the largest divisor of the group whose blocks of
+    ``operands`` (q, do, o and lse, as :func:`staged_vmem_bytes` takes
+    them), twice buffered, and the room beside them fit ``_STEP_VMEM``."""
+    return max(
+        (m for m in range(1, group + 1) if group % m == 0
+         and staged_vmem_bytes(m * block_q, *operands)
+         + _VMEM_BESIDE_STAGED <= _STEP_VMEM),
+        default=1,
+    )
 
 
 def _dkv_kernel_blocked(steps_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
@@ -1312,55 +1339,24 @@ def _dkv_kernel_blocked(steps_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
         kv_len = None
-    ki, i, _, edge = _step_fields(steps_ref[pl.program_id(1)])
+    ki, i, edge = _step_fields(steps_ref[pl.program_id(1)])
+    part = pl.program_id(2)
 
-    @pl.when(edge & _FIRST != 0)
+    @pl.when((edge & _FIRST != 0) & (part == 0))
     def _zero():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def _accumulate():
-        k = k_ref[0].astype(jnp.float32)  # [BK, D]
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)  # [BQ, D]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, 0:1]
-        delta = jnp.sum(
-            do * o_ref[0].astype(jnp.float32), axis=-1, keepdims=True
+        dk, dv = _dkv_members(
+            q_ref, do_ref, o_ref, lse_ref, 0, ki,
+            k_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
+            dk_acc[...], dv_acc[...], scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, i=i, kv_len=kv_len,
+            window=window, blockdiff=blockdiff, half=n_q * block_q // 2,
         )
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [BQ, BK]
-        if causal:
-            s = _apply_causal_mask(s, i, ki, block_q, block_k)
-        if padded:
-            s = _apply_length_mask(s, ki, block_k, kv_len)
-        if window is not None:
-            s = _apply_window_mask(s, i, ki, block_q, block_k, window)
-        if blockdiff is not None:
-            s = _apply_blockdiff_mask(
-                s, i, ki, block_q, block_k, n_q * block_q // 2, blockdiff
-            )
-        p = jnp.exp(s - lse)
-        if padded:
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, p.shape, 0
-            )
-            p = jnp.where(rows < kv_len, p, 0.0)
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        dk_acc[...] += scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_acc[...] = dk
+        dv_acc[...] = dv
 
     if padded:
         # the one bound the table cannot hold: Q tiles wholly past the
@@ -1369,7 +1365,7 @@ def _dkv_kernel_blocked(steps_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     else:
         _accumulate()
 
-    @pl.when(edge & _LAST != 0)
+    @pl.when((edge & _LAST != 0) & (part == pl.num_programs(2) - 1))
     def _store():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -1383,37 +1379,42 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
     bh, seq, d = q.shape
     d_v = v.shape[-1]
     lanes = lse.shape[-1]
-    r = group
-    steps, kept = _dkv_steps(
-        seq, block_q, block_k, r, causal, window, blockdiff
+    steps, kept = _dkv_steps(seq, block_q, block_k, causal, window, blockdiff)
+    # the q group's operands: q, do, o and the float32 lse
+    group_rows = (
+        (d, q.dtype.itemsize), (d_v, do.dtype.itemsize),
+        (d_v, o.dtype.itemsize), (lanes, 4),
     )
-    grid = (bh // r, len(steps))
+    members = _members_per_step(group, block_q, *group_rows)
+    parts = group // members
+    grid = (bh // group, len(steps), parts)
 
-    def q_block(b, step, steps_ref):
-        _, i, member, _ = _step_fields(steps_ref[step])
-        return b * r + member, i, 0
+    def q_block(b, step, part, steps_ref):
+        return b * parts + part, _step_fields(steps_ref[step])[1], 0
 
-    def kv_block(b, step, steps_ref):
+    def kv_block(b, step, part, steps_ref):
         return b, _step_fields(steps_ref[step])[0], 0
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d), q_block),
+        pl.BlockSpec((members, block_q, d), q_block),
         pl.BlockSpec((1, block_k, d), kv_block),
         pl.BlockSpec((1, block_k, d_v), kv_block),
-        pl.BlockSpec((1, block_q, d_v), q_block),
-        pl.BlockSpec((1, block_q, d_v), q_block),
-        pl.BlockSpec((1, block_q, lanes), q_block),
+        pl.BlockSpec((members, block_q, d_v), q_block),
+        pl.BlockSpec((members, block_q, d_v), q_block),
+        pl.BlockSpec((members, block_q, lanes), q_block),
     ]
     operands = [q, k, v, do, o, lse]
     if lens is not None:
         in_specs.append(_lens_spec())
         operands.append(lens)
+    limit = _staging_params(members * block_q, *group_rows)
     # what the grid holds beside what the mask keeps
     with _call_span(
         "flash_dkv", q, v, grid, causal=causal,
         window=window, padded=lens is not None, blockdiff=blockdiff,
-        block_q=block_q, block_k=block_k, group=r,
-        kept_tiles=grid[0] * kept, kv_rows=grid[0],
+        block_q=block_q, block_k=block_k, group=group, params=limit,
+        kept_tiles=grid[0] * kept * parts, kv_rows=grid[0],
+        members_per_step=members,
     ):
         return pl.pallas_call(
             functools.partial(
@@ -1439,7 +1440,8 @@ def _dkv_blocked(q, k, v, do, o, lse, lens, *, scale, causal, block_q,
                 jax.ShapeDtypeStruct(v.shape, v.dtype),
             ],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=getattr(limit, "vmem_limit_bytes", None),
             ),
             interpret=_interpret(),
             name="flash_dkv",

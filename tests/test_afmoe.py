@@ -333,11 +333,14 @@ def test_the_8k_shape_rides_the_kernels_with_its_q_group_staged_by_block():
     # whole-sequence staging would be 48.8 MiB: the backward stages by block
     assert fa.bwd_vmem_bytes(8192, 128, group, 2, 512) > 48 * 2**20
     assert not fa.fits_vmem(8192, 128, group, 2, 512)
-    # a grid step for each tile the mask keeps and group member: bands of
-    # 5 Q tiles and 4, 3, 2, 1 at the end; 16, 15, ..., 1
+    # a grid step for each tile the mask keeps, taking the group's eight
+    # query heads at once: bands of 5 Q tiles and 4, 3, 2, 1 at the end;
+    # 16, 15, ..., 1
+    assert fa._members_per_step(
+        group, 512, (128, 2), (128, 2), (128, 2), (1, 4)) == group
     for window, tiles in ((2048, 12 * 5 + 10), (None, 136)):
-        steps, kept = fa._dkv_steps(8192, 512, 512, group, True, window)
-        assert kept == len(steps) == tiles * group
+        steps, kept = fa._dkv_steps(8192, 512, 512, True, window)
+        assert kept == len(steps) == tiles
     # GPT-2's and BERT's shapes keep the kernel they had
     assert fa.fits_vmem(512, 64, 1, 2, 512)
 

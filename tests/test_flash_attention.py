@@ -446,46 +446,47 @@ def _keep(mask, seq, arg):
     return (c <= r) & ((r - c < arg) if arg else True)
 
 
-def _steps(mask, seq, block_q, block_k, arg, group):
+def _steps(mask, seq, block_q, block_k, arg):
     return fa._dkv_steps(
-        seq, block_q, block_k, group, mask in ("causal", "window"),
+        seq, block_q, block_k, mask in ("causal", "window"),
         arg if mask == "window" else None,
         arg if mask == "block_diffusion" else None)
 
 
-@pytest.mark.parametrize("mask, seq, block_q, block_k, arg, group, tiles", [
+@pytest.mark.parametrize("mask, seq, block_q, block_k, arg, tiles", [
     # block-diffusion training's shapes (SDAR), and Trinity's two layer
     # kinds: counted, not compared with a 16,384 x 16,384 mask
-    ("block_diffusion", 16384, 512, 512, 4, 8, 288),
-    ("causal", 8192, 512, 512, None, 8, 136),
-    ("window", 8192, 512, 512, 2048, 8, 70),
-    ("block_diffusion", 128, 16, 16, 4, 8, None),
-    ("block_diffusion", 128, 16, 16, 16, 1, None),
-    ("block_diffusion", 128, 32, 16, 4, 2, None),
-    ("block_diffusion", 128, 8, 32, 8, 2, None),
-    ("block_diffusion", 96, 16, 8, 4, 1, None),
-    ("causal", 64, 16, 16, None, 1, None),
-    ("causal", 64, 16, 16, None, 4, None),
-    ("causal", 96, 32, 16, None, 2, None),
-    ("causal", 96, 16, 48, None, 2, None),
-    ("window", 64, 16, 16, 8, 2, None),
-    ("window", 64, 16, 16, 24, 1, None),
-    ("window", 128, 16, 32, 40, 8, None),
-    ("window", 128, 32, 16, 17, 2, None),
-    ("full", 64, 16, 32, None, 2, None),
+    ("block_diffusion", 16384, 512, 512, 4, 288),
+    ("causal", 8192, 512, 512, None, 136),
+    ("window", 8192, 512, 512, 2048, 70),
+    ("block_diffusion", 128, 16, 16, 4, None),
+    ("block_diffusion", 128, 16, 16, 16, None),
+    ("block_diffusion", 128, 32, 16, 4, None),
+    ("block_diffusion", 128, 8, 32, 8, None),
+    ("block_diffusion", 96, 16, 8, 4, None),
+    ("causal", 64, 16, 16, None, None),
+    ("causal", 64, 8, 16, None, None),
+    ("causal", 96, 32, 16, None, None),
+    ("causal", 96, 16, 48, None, None),
+    ("window", 64, 16, 16, 8, None),
+    ("window", 64, 16, 16, 24, None),
+    ("window", 128, 16, 32, 40, None),
+    ("window", 128, 32, 16, 17, None),
+    ("full", 64, 16, 32, None, None),
 ])
 def test_the_dkv_step_table_lists_the_tiles_the_mask_keeps(
-        mask, seq, block_q, block_k, arg, group, tiles):
-    """Exactly the tiles that keep a pair, each once a group member; a K
-    tile's steps contiguous, group member outermost and Q tiles ascending,
-    the first and the last marked: nothing for the grid to skip."""
-    steps, kept = _steps(mask, seq, block_q, block_k, arg, group)
+        mask, seq, block_q, block_k, arg, tiles):
+    """Exactly the tiles that keep a pair, each once (a step takes a Q
+    tile of as many query heads as fit, whatever the group); a K tile's
+    steps contiguous, Q tiles ascending, the first and the last marked:
+    nothing for the grid to skip."""
+    steps, kept = _steps(mask, seq, block_q, block_k, arg)
     assert steps.dtype == np.int32 and steps.shape == (kept,)
-    k_tile, q_tile, member, edge = fa._step_fields(steps)
+    k_tile, q_tile, edge = fa._step_fields(steps)
     n_q, n_k = seq // block_q, seq // block_k
     if tiles is not None:
-        assert kept == tiles * group
-        bands = [int(np.sum(k_tile == ki)) // group for ki in range(n_k)]
+        assert kept == tiles
+        bands = [int(np.sum(k_tile == ki)) for ki in range(n_k)]
         if mask == "block_diffusion":
             assert bands == [1] * 16 + list(range(32, 0, -2))
         elif mask == "causal":
@@ -495,18 +496,15 @@ def test_the_dkv_step_table_lists_the_tiles_the_mask_keeps(
     else:
         keeps = _keep(mask, seq, arg).reshape(
             n_q, block_q, n_k, block_k).any(axis=(1, 3))
-        assert kept == group * int(keeps.sum())
+        assert kept == int(keeps.sum())
     start = 0
     for ki in range(n_k):
         (at,) = np.nonzero(k_tile == ki)
         assert list(at) == list(range(start, start + len(at))), ki
         start += len(at)
-        band = sorted(set(q_tile[at]))
         if tiles is None:
-            assert band == list(np.flatnonzero(keeps[:, ki])), ki
-        assert list(q_tile[at]) == band * group
-        assert list(member[at]) == [
-            m for m in range(group) for _ in band]
+            assert list(q_tile[at]) == list(np.flatnonzero(keeps[:, ki])), ki
+        assert list(q_tile[at]) == sorted(set(q_tile[at])), ki
         want = np.zeros(len(at), int)
         want[0] |= fa._FIRST
         want[-1] |= fa._LAST
@@ -517,8 +515,8 @@ def test_the_dkv_step_table_lists_the_tiles_the_mask_keeps(
 def test_a_k_tile_with_an_empty_band_still_gets_a_step_and_writes_zeros(
         monkeypatch):
     """No mask of today's empties a K tile's band; were one to, the table
-    gives the tile a step a group member on a tile that the mask empties,
-    so the kernel zeroes and stores its accumulators as for any other."""
+    gives the tile one step on a tile that the mask empties, so the kernel
+    zeroes and stores its accumulators as for any other."""
     real = fa._dkv_q_range
 
     def emptied(ki, *args):
@@ -536,12 +534,12 @@ def test_a_k_tile_with_an_empty_band_still_gets_a_step_and_writes_zeros(
     monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET", "1")
     untouched = grads()
     monkeypatch.setattr(fa, "_dkv_q_range", emptied)
-    steps, kept = fa._dkv_steps(t, 16, 16, 2, True, None)
-    k_tile, q_tile, _, edge = fa._step_fields(steps)
-    # bands of 4, 3, (2 ->) 0, 1 Q tiles, and the steps that stand in
-    assert kept == 2 * 8 and len(steps) == kept + 2
+    steps, kept = fa._dkv_steps(t, 16, 16, True, None)
+    k_tile, q_tile, edge = fa._step_fields(steps)
+    # bands of 4, 3, (2 ->) 0, 1 Q tiles, and the step that stands in
+    assert kept == 8 and len(steps) == kept + 1
     (at,) = np.nonzero(k_tile == 2)
-    assert list(edge[at]) == [fa._FIRST, fa._LAST]
+    assert list(edge[at]) == [fa._FIRST | fa._LAST]
     assert (q_tile[at] < 2).all()  # above the diagonal: nothing is kept
     for mine, theirs in zip(grads(), untouched):
         assert float(jnp.abs(mine[:, 32:48]).max()) == 0.0
@@ -550,16 +548,54 @@ def test_a_k_tile_with_an_empty_band_still_gets_a_step_and_writes_zeros(
 
 
 def test_the_step_table_refuses_what_its_words_cannot_hold():
-    # the largest it takes: 2,048 tiles (bands of two under this window,
-    # of one at the end), 64 heads a group
-    steps, kept = fa._dkv_steps(2048 * 8, 8, 8, 64, True, 8)
-    k_tile, q_tile, member, _ = fa._step_fields(steps)
-    assert (steps >= 0).all() and kept == len(steps) == 64 * (2 * 2048 - 1)
-    assert (k_tile.max(), q_tile.max(), member.max()) == (2047, 2047, 63)
-    with pytest.raises(ValueError, match="2049 tiles"):
-        fa._dkv_steps(2049 * 8, 8, 8, 1, True, 8)
-    with pytest.raises(ValueError, match="65 heads"):
-        fa._dkv_steps(64, 16, 16, 65, True, None)
+    # the largest it takes: 16,384 tiles (bands of two under this window,
+    # of one at the end)
+    steps, kept = fa._dkv_steps(16384 * 8, 8, 8, True, 8)
+    k_tile, q_tile, _ = fa._step_fields(steps)
+    assert (steps >= 0).all() and kept == len(steps) == 2 * 16384 - 1
+    assert (k_tile.max(), q_tile.max()) == (16383, 16383)
+    with pytest.raises(ValueError, match="16385 tiles"):
+        fa._dkv_steps(16385 * 8, 8, 8, True, 8)
+
+
+# q, do, o and the width-1 float32 lse of a 128-wide bf16 head, and of a
+# 192-wide key with a 128-wide value
+_HEAD_128 = ((128, 2), (128, 2), (128, 2), (1, 4))
+_KEY_192 = ((192, 2), (128, 2), (128, 2), (1, 4))
+
+
+@pytest.mark.parametrize("group, block_q, operands, members, limit_mib", [
+    # SDAR's and Trinity's calls: the whole group, 10 MiB of blocks, which
+    # compile at Mosaic's default limit (tests/test_tpu_compile.py)
+    (8, 512, _HEAD_128, 8, None),
+    (1, 512, _HEAD_128, 1, None),
+    (4, 512, _HEAD_128, 4, None),
+    # 12 MiB of blocks: the limit is raised to twice that and the room
+    (8, 512, _KEY_192, 8, 30),
+    (16, 512, _HEAD_128, 16, 46),
+    # 40 MiB of blocks would not fit: two steps of 16 a Q tile
+    (32, 512, _HEAD_128, 16, 46),
+    (64, 256, _HEAD_128, 32, 46),
+    # no divisor of 12 but 1, 2, 3, 4 and 6 leaves room
+    (12, 1024, _HEAD_128, 6, 36),
+    (7, 512, _HEAD_128, 7, None),
+])
+def test_the_members_a_step_takes_follow_the_group_and_its_blocks(
+        group, block_q, operands, members, limit_mib):
+    """The largest divisor of the group whose blocks, twice buffered, and
+    the room beside them fit a step's share of VMEM; the limit the call
+    asks Mosaic for is :func:`_staging_params`' for those blocks. Shapes
+    alone, nothing traced."""
+    assert fa._members_per_step(group, block_q, *operands) == members
+    held = fa.staged_vmem_bytes(members * block_q, *operands)
+    assert held + fa._VMEM_BESIDE_STAGED <= fa._STEP_VMEM
+    if members < group:
+        more = min(m for m in range(members + 1, group + 1) if group % m == 0)
+        assert (fa.staged_vmem_bytes(more * block_q, *operands)
+                + fa._VMEM_BESIDE_STAGED > fa._STEP_VMEM)
+    params = fa._staging_params(members * block_q, *operands)
+    assert (None if params is None
+            else params.vmem_limit_bytes / 2**20) == limit_mib
 
 
 def _blocked_case(mask, arg, heads, kv_heads, lengths):
@@ -582,9 +618,23 @@ def _blocked_case(mask, arg, heads, kv_heads, lengths):
 def test_blocked_dkv_equals_the_whole_sequence_kernel(
         mask, arg, heads, kv_heads, lengths, monkeypatch):
     """The two forms of the dK/dV kernel on the same inputs: the same
-    products of the same tiles, summed Q tile by Q tile in one and group
-    member by group member in the other; and both against dense attention
-    under the mask."""
+    products of the same tiles, summed in the same order (Q tile by Q
+    tile, and within one query head by query head, :func:`_dkv_members`),
+    so the same bits; and both against dense attention under the mask."""
+    blocked, whole, want = _dkv_both_ways(
+        mask, arg, heads, kv_heads, lengths, monkeypatch)
+    np.testing.assert_array_equal(blocked[0], whole[0])  # dQ: one kernel
+    for name, mine, theirs, ref in zip("kv", blocked[1:], whole[1:],
+                                       want[1:]):
+        np.testing.assert_array_equal(mine, theirs, err_msg=name)
+        np.testing.assert_allclose(mine, ref, atol=2e-5, rtol=1e-5,
+                                   err_msg=name)
+
+
+def _dkv_both_ways(mask, arg, heads, kv_heads, lengths, monkeypatch):
+    """``(blocked, whole, dense)``: the gradients of q, k and v with the
+    dK/dV kernel staged block by block (``fits_vmem`` made to refuse),
+    whole-sequence, and by dense attention under the mask."""
     t, d = 96, 16
     q = _rand((2, t, heads, d), 60)
     k, v = _rand((2, t, kv_heads, d), 61), _rand((2, t, kv_heads, d), 62)
@@ -597,12 +647,11 @@ def test_blocked_dkv_equals_the_whole_sequence_kernel(
                       window=arg if mask == "window" else None)
     lens = None if lengths is None else jnp.asarray(lengths, jnp.int32)
 
-    def grads(budget):
-        monkeypatch.setenv("HOROVOD_FLASH_VMEM_BUDGET", budget)
-        assert fa.fits_vmem(t, d, heads // kv_heads, 4, 16) == (
-            budget != "1")
-        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, lengths=lens, **kwargs) * w), (0, 1, 2))(q, k, v)
+    def grads(fits):
+        monkeypatch.setattr(fa, "fits_vmem", lambda *a, **k: fits)
+        # jitted, so that the calls leave their spans
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, lengths=lens, **kwargs) * w), (0, 1, 2)))(q, k, v)
 
     def dense(q, k, v):
         group = heads // kv_heads
@@ -617,16 +666,36 @@ def test_blocked_dkv_equals_the_whole_sequence_kernel(
                           o, 0.0)
         return jnp.sum(o * w)
 
-    blocked, whole = grads("1"), grads(str(2**30))
-    want = jax.grad(dense, (0, 1, 2))(q, k, v)
-    np.testing.assert_array_equal(blocked[0], whole[0])  # dQ: one kernel
-    for name, mine, theirs, ref in zip("kv", blocked[1:], whole[1:],
-                                       want[1:]):
-        # float32 sums of up to 8 x 6 tiles' products in two orders
-        np.testing.assert_allclose(mine, theirs, atol=2e-6, rtol=2e-6,
-                                   err_msg=name)
-        np.testing.assert_allclose(mine, ref, atol=2e-5, rtol=1e-5,
-                                   err_msg=name)
+    blocked, whole = grads(False), grads(True)
+    return blocked, whole, jax.grad(dense, (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("mask, arg, lengths, members", [
+    ("causal", None, None, 1),
+    ("window", 24, [96, 17], 2),
+    ("block_diffusion", 4, None, 1),
+    ("full", None, [50, 96], 2),
+])
+def test_a_group_split_over_steps_sums_to_the_same_bits(
+        mask, arg, lengths, members, monkeypatch):
+    """Where a step's share of VMEM holds fewer than the group's four
+    query heads, the group's parts are the grid's innermost dimension: the
+    sums keep their order, and the gradients their bits."""
+    # one member's blocks at these shapes: 16 rows of four 512-byte rows
+    # of float32, twice
+    monkeypatch.setattr(fa, "_STEP_VMEM", fa._VMEM_BESIDE_STAGED
+                        + members * 2 * 16 * 2048)
+    assert fa._members_per_step(4, 16, *[(16, 4)] * 3, (1, 4)) == members
+    before = len(_flash_calls())
+    blocked, whole, _ = _dkv_both_ways(
+        mask, arg, 4, 1, lengths, monkeypatch)
+    (span,) = [t for t in _flash_calls()[before:]
+               if t["kernel"] == "flash_dkv" and t["staging"] == "blocked"]
+    assert span["members_per_step"] == members
+    assert span["grid_steps"] == span["kept_tiles"] == (
+        2 * len(_steps(mask, 96, 16, 16, arg)[0]) * 4 // members)
+    for name, mine, theirs in zip("qkv", blocked, whole):
+        np.testing.assert_array_equal(mine, theirs, err_msg=name)
 
 
 def _flash_calls():
@@ -654,18 +723,20 @@ def test_a_traced_blocked_call_records_what_its_grid_holds(monkeypatch):
     (span,) = [t for t in _flash_calls()[before:]
                if t["kernel"] == "flash_dkv"]
     # 4 noised K tiles of 1 Q tile, clean bands of 8, 6, 4, 2: 24 tiles
-    # for each of 2 group members and 4 kv rows (the rectangle held 8
-    # steps a K tile: 4 x 8 x 2 x 8 = 512)
+    # for each of 4 kv rows, a step taking both query heads of the group
+    # (the rectangle held 8 steps a K tile and member: 4 x 8 x 2 x 8 = 512)
     assert span == {
         "kernel": "flash_dkv", "staging": "blocked",
         "mask": "block_diffusion", "block_q": 16, "block_k": 16,
         "seq": 128, "heads": 8, "group": 2, "d_qk": 8, "d_v": 8,
-        "grid_steps": 192, "kept_tiles": 192, "kv_rows": 4,
-        "staged_vmem_bytes": 0, "vmem_limit_bytes": 0}
+        "grid_steps": 96, "kept_tiles": 96, "kv_rows": 4,
+        "members_per_step": 2, "staged_vmem_bytes": 0,
+        "vmem_limit_bytes": 0}
 
 
 # seq 128 in tiles of 16: 8 x 8 tiles. What the blocked dK/dV grid keeps of
-# them a kv row and group member: the lower triangle; the band of a
+# them a kv row (a step takes the group's two query heads): the lower
+# triangle; the band of a
 # 40-wide window (a K tile is seen by its own Q tile and the three after
 # it: 5 x 4, then 3, 2, 1); all of them (a length bound is a ``pl.when``
 # inside a kept step); the block-diffusion mask's 24 (the test above).
@@ -721,11 +792,11 @@ def test_every_kernel_call_leaves_its_plan(mask, staging, monkeypatch):
             shared, kernel="flash_dkv", staging="whole", grid_steps=4 * 8,
             staged_vmem_bytes=2 * 256 * 2048)
     else:
-        steps = 4 * 2 * _KEPT[mask]
+        steps = 4 * _KEPT[mask]
         assert dkv == dict(
             shared, kernel="flash_dkv", staging="blocked",
             grid_steps=steps, kept_tiles=steps, kv_rows=4,
-            staged_vmem_bytes=0)
+            members_per_step=2, staged_vmem_bytes=0)
 
 
 def test_a_call_past_mosaics_default_limit_says_what_it_asks_for():

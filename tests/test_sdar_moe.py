@@ -136,11 +136,12 @@ def test_the_loops_visit_the_tiles_the_mask_leaves_anything_in(
         # 16 noised tiles on themselves, and twice the 136 of a
         # block-causal row of 16 tiles: not the 1,024 of the square
         assert fwd == dkv == 16 + 2 * 136 == 288
-        # and the blocked dK/dV kernel's grid holds just those, a group
-        # member: 2,304 steps where the widest band's rectangle held 8,192
-        steps, kept = fa._dkv_steps(2 * length, block_q, block_k, 8, False,
+        # and the blocked dK/dV kernel's grid holds just those, each step
+        # a Q tile of the group's 8 query heads: 288 steps where the widest
+        # band's rectangle held 8,192 of one query head each
+        steps, kept = fa._dkv_steps(2 * length, block_q, block_k, False,
                                     None, block)
-        assert kept == len(steps) == 288 * 8
+        assert kept == len(steps) == 288
         return
     keep = _mask(length, block).reshape(n_q, block_q, n_k, block_k)
     kept = keep.any(axis=(1, 3))
@@ -153,18 +154,17 @@ def test_the_loops_visit_the_tiles_the_mask_leaves_anything_in(
         visited = {i for first, last in fa._blockdiff_q_ranges(
             ki, block_q, block_k, length, block) for i in range(first, last)}
         assert visited == set(np.flatnonzero(kept[:, ki])), ki
-    # the blocked dK/dV kernel's grid: a step for each of those tiles and
-    # group member, a K tile's together, and no step beside them
+    # the blocked dK/dV kernel's grid: a step for each of those tiles, a K
+    # tile's together, and no step beside them
     steps, tiles = fa._dkv_steps(
-        2 * length, block_q, block_k, 2, False, None, block)
-    assert tiles == len(steps) == 2 * int(kept.sum())
-    k_tile, q_tile, member, _ = fa._step_fields(steps)
+        2 * length, block_q, block_k, False, None, block)
+    assert tiles == len(steps) == int(kept.sum())
+    k_tile, q_tile, _ = fa._step_fields(steps)
     for ki in range(n_k):
         band = list(np.flatnonzero(kept[:, ki]))
         (at,) = np.nonzero(k_tile == ki)
-        assert list(at) == list(range(at[0], at[0] + 2 * len(band)))
-        assert list(q_tile[at]) == band + band
-        assert list(member[at]) == [0] * len(band) + [1] * len(band)
+        assert list(at) == list(range(at[0], at[0] + len(band)))
+        assert list(q_tile[at]) == band
 
 
 def test_the_mask_is_refused_beside_another_and_off_its_tiles():

@@ -70,10 +70,13 @@ def test_flash_kernels_compile_at_b2_s8192_gqa8_head128(
     traced = len(_dkv_grids())
     assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
     # the dK/dV grid (its steps' table in scalar memory): the kept tiles of
-    # 8 kv rows and 8 group members, where the widest band's rectangle
-    # held 8 x 16 x 8 x (5 | 16) = 5,120 | 16,384
+    # 8 kv rows, each step a Q tile of the group's 8 query heads, where the
+    # widest band's rectangle held 8 x 16 x 8 x (5 | 16) = 5,120 | 16,384
+    # steps of one head each; their 10 MiB of blocks compile at Mosaic's
+    # default limit
     (grid,) = _dkv_grids()[traced:]
-    assert grid["grid_steps"] == grid["kept_tiles"] == 8 * 8 * tiles
+    assert grid["grid_steps"] == grid["kept_tiles"] == 8 * tiles
+    assert (grid["members_per_step"], grid["vmem_limit_bytes"]) == (8, 0)
 
 
 def test_flash_kernels_compile_at_s16384_gqa8_head128_block_diffusion(
@@ -83,9 +86,10 @@ def test_flash_kernels_compile_at_s16384_gqa8_head128_block_diffusion(
     whole-sequence, twice, are 16 MiB, so the forward and dQ kernels
     compile with Mosaic's scoped limit raised (``_staging_params``); the
     dK/dV kernel stages by block, a grid step for each of the 288 tiles
-    the mask keeps and group member (a table of 2,304 int32 in scalar
-    memory). The mask's vectors of one column and of one row, the integer
-    division by the block length and the scalar prefetch are what
+    the mask keeps, taking the group's 8 query heads (a table of 288 int32
+    in scalar memory; 10 MiB of q, dO, o and lse blocks, at Mosaic's
+    default limit). The mask's vectors of one column and of one row, the
+    integer division by the block length and the scalar prefetch are what
     interpret mode cannot vouch for."""
     shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
                               sharding=one_chip)
@@ -100,10 +104,35 @@ def test_flash_kernels_compile_at_s16384_gqa8_head128_block_diffusion(
     # forward, dQ, dK/dV
     traced = len(_dkv_grids())
     assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
-    # 4 kv rows x 8 group members x 288 tiles, where the rectangle of the
-    # widest band (32 Q tiles) held 4 x 32 x 8 x 32 = 32,768 steps
+    # 4 kv rows x 288 tiles of 8 query heads, where a step of one head
+    # each made 9,216 and the rectangle of the widest band (32 Q tiles)
+    # 4 x 32 x 8 x 32 = 32,768
     (grid,) = _dkv_grids()[traced:]
-    assert grid["grid_steps"] == grid["kept_tiles"] == 9216
+    assert grid["grid_steps"] == grid["kept_tiles"] == 1152
+    assert (grid["members_per_step"], grid["vmem_limit_bytes"]) == (8, 0)
+
+
+def test_a_group_past_a_steps_share_of_vmem_compiles_in_two_parts(
+        one_chip, as_on_the_chip):
+    """32 query heads on one kv head of 128: the group's blocks would be 40
+    MiB, so a step takes 16 query heads (20 MiB, the limit raised to 46) and
+    the grid's innermost dimension the group's two parts."""
+    shape = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                              sharding=one_chip)
+    q, kv = shape((1, 8192, 32, 128)), shape((1, 8192, 1, 128))
+    assert not fa.fits_vmem(8192, 128, 32, 2, 512)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, window=1024).astype(jnp.float32))
+
+    traced = len(_dkv_grids())
+    assert _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv) == 3
+    # bands of 3 Q tiles, then 2 and 1: 45 steps, each twice
+    (grid,) = _dkv_grids()[traced:]
+    assert grid["grid_steps"] == grid["kept_tiles"] == 2 * 45
+    assert grid["members_per_step"] == 16
+    assert grid["vmem_limit_bytes"] == 46 * 2**20
 
 
 @pytest.mark.parametrize("staging", ["whole-sequence", "by-block"])
